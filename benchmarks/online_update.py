@@ -110,7 +110,7 @@ def run_policy(policy: str, *, rate: float, n: int,
         art = current["compiled"]
         if name == "dense":
             return jax.jit(lambda xw: compiler.run_compiled(
-                art, xw, engine="dense", interpret=True).argmax(-1))
+                art, xw, engine="dense").argmax(-1))
         return jax.jit(lambda xw: compiler.run_compiled(
             art, xw, engine="oracle").argmax(-1))
 
@@ -236,7 +236,7 @@ def write_report(rows: list, path: str = "BENCH_online.json") -> None:
     report = dict(
         benchmark="online_update",
         backend=jax.default_backend(),
-        interpret_mode=True,           # the dense ladder level interprets
+        interpret_mode=jax.default_backend() != "tpu",
         jax_version=jax.__version__,
         platform=platform.platform(),
         rows=rows,

@@ -88,13 +88,14 @@ def _build_compiled(arch: str = "tm-tiny"):
 def _build_runner(compiled, bucket: int, W: int, warm: bool = True):
     """Zoo-wrapped gateway runner over a dense-kernel -> oracle ladder.
 
-    The dense engine runs the Pallas kernel in interpret mode so the
-    ``kernel.dense`` chaos fault exercises the REAL demotion path; the
-    oracle level keeps every bucket answerable after the demotion.
+    The dense engine runs the Pallas kernel (compiled on a TPU, in
+    interpret mode elsewhere) so the ``kernel.dense`` chaos fault
+    exercises the REAL demotion path; the oracle level keeps every bucket
+    answerable after the demotion.
     """
     ladder = ops.EngineLadder([
         ("dense", lambda: jax.jit(lambda xw: compiler.run_compiled(
-            compiled, xw, engine="dense", interpret=True).argmax(-1))),
+            compiled, xw, engine="dense").argmax(-1))),
         ("oracle", lambda: jax.jit(lambda xw: compiler.run_compiled(
             compiled, xw, engine="oracle").argmax(-1))),
     ])
@@ -374,7 +375,7 @@ def _build_anytime_runner(compiled, xp, base_service: float):
     for q in levels:
         lvl = q["level"]
         sums = compiler.run_compiled(compiled, lit, engine="sparse",
-                                     quality=lvl, interpret=True,
+                                     quality=lvl,
                                      **_OVERLOAD_BLOCKS)
         preds[lvl] = np.asarray(sums.argmax(-1))
         frac[lvl] = q["n_tiles"] / n_full
@@ -509,7 +510,7 @@ def write_report(rows: list, path: str = "BENCH_serve.json") -> None:
     report = dict(
         benchmark="serve_gateway",
         backend=jax.default_backend(),
-        interpret_mode=True,           # the dense ladder level interprets
+        interpret_mode=jax.default_backend() != "tpu",
         jax_version=jax.__version__,
         platform=platform.platform(),
         rows=rows,

@@ -44,10 +44,11 @@ def main() -> None:
     assert (pred_dense == pred_comp).all(), "verification FAILED"
     print("verification: compiled artifact == dense model on 1000 samples OK")
 
-    # 3b. the same datapath through the fused Pallas kernel (interpret on CPU)
+    # 3b. the same datapath through the fused Pallas kernel (compiled on a
+    # TPU, interpret mode elsewhere)
     pred_kernel = np.asarray(
         compiler.predict_compiled(compiled, jnp.asarray(Xte[:64]),
-                                  engine="dense", interpret=True))
+                                  engine="dense"))
     assert (pred_kernel == pred_dense[:64]).all()
     print("verification: fused Pallas inference kernel path OK")
 

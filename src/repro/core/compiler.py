@@ -916,11 +916,12 @@ def run_compiled(
     The engine is selected by ``engine=`` — an ``ops.EngineSpec`` or one
     of the :class:`ops.EngineLadder` level names ``"auto"`` (default) /
     ``"factorized"`` / ``"sparse"`` / ``"dense"`` / ``"oracle"``.
-    ``"auto"`` defers to ``kernels/ops`` ambient resolution
-    (``REPRO_USE_PALLAS``; ``interpret=None`` compiles on TPU and
-    interprets elsewhere) and, on the kernel path, picks the two-level
-    FACTORIZED schedule kernel (``kernels/term_infer.py``: each unique
-    AND term evaluated once per sample slab) when the artifact's
+    ``"auto"`` defers to ``kernels/ops`` ambient resolution (kernels
+    on a TPU, or on CPU under ``REPRO_USE_PALLAS=1``; ``interpret=None``
+    compiles on TPU and interprets elsewhere) and, on the kernel path,
+    picks the two-level FACTORIZED schedule kernel
+    (``kernels/term_infer.py``: each unique AND term evaluated once per
+    sample slab) when the artifact's
     ``partial_term_sharing`` clears ``FACTORIZE_SHARING_THRESHOLD``, else
     the flat block-sparse chain kernel (``kernels/sparse_infer.py``); the
     named engines pin the choice.  All engines are bit-identical.

@@ -39,7 +39,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro import jax_compat
 from repro.core import tm
 
 
@@ -115,7 +114,7 @@ def sharded_forward_fn(mesh: Mesh, *, engine=None,
         )
         return jax.lax.psum(sums, "model")
 
-    fwd = jax_compat.shard_map(
+    fwd = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P("model", None), P("model", None), P("model"), P(d, None)),
         out_specs=P(d, None),
@@ -163,7 +162,7 @@ def sharded_schedule_forward_fn(mesh: Mesh, *,
             sums = sparse_infer.schedule_class_sums_ref(lw_loc, chain, vt)
         return jax.lax.psum(sums, "model")
 
-    fwd = jax_compat.shard_map(
+    fwd = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P("model", None, None), P("model", None, None),
                   P("model", None, None), P(d, None)),
@@ -215,7 +214,7 @@ def sharded_factorized_forward_fn(mesh: Mesh, *,
             sums = term_infer.factorized_class_sums_ref(lw_loc, term, chain, vt)
         return jax.lax.psum(sums, "model")
 
-    fwd = jax_compat.shard_map(
+    fwd = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P("model", None, None), P("model", None, None),
                   P("model", None, None), P("model", None, None),
@@ -337,7 +336,7 @@ def sharded_train_step_fn(config: tm.TMConfig, mesh: Mesh,
             # psums + psum_scatter (see EXPERIMENTS.md §Perf, TM cell)
             data_ax = d[-1] if d else "data"
 
-            return jax_compat.shard_map(
+            return jax.shard_map(
                 lambda ta, xx, yy: ops.tm_train_step_matmul_local(
                     config, ta, xx, yy, seed
                 ),
@@ -358,7 +357,7 @@ def sharded_train_step_fn(config: tm.TMConfig, mesh: Mesh,
                          * jnp.uint32(C_loc))
                 b_off = jnp.uint32(0)
                 for ax in d:   # row-major global id of this data shard
-                    b_off = (b_off * jnp.uint32(jax_compat.axis_size(ax))
+                    b_off = (b_off * jnp.uint32(jax.lax.axis_size(ax))
                              + jax.lax.axis_index(ax).astype(jnp.uint32))
                 b_off = b_off * jnp.uint32(B_loc)
                 _, delta = ops.tm_train_step_kernel(
@@ -376,7 +375,7 @@ def sharded_train_step_fn(config: tm.TMConfig, mesh: Mesh,
                     -config.n_states, config.n_states - 1,
                 ).astype(jnp.int8)
 
-            return jax_compat.shard_map(
+            return jax.shard_map(
                 body, mesh=mesh,
                 in_specs=(P("model", None), P(d, None), P(d)),
                 out_specs=P("model", None),

@@ -70,7 +70,10 @@ POLICIES = ("sweep", "verify", "predict")
 TIMING_RUNS = 0
 
 # candidate tilings: a deliberately small grid — the sweep is paid once per
-# shape and cached, but each candidate costs a kernel compile.
+# shape and cached, but each candidate costs a kernel compile.  Every
+# candidate must lower on the TPU (tests/test_tpu_lowering.py compiles
+# them all): the word chain unrolls block_w steps over a (block_c,
+# block_b) tile, so block_w stays <= 64 and the tile <= 512 x 512.
 _DEFAULT_CANDIDATES = (
     (128, 128, 64),   # clause_eval.py's defaults (VMEM-lean)
     (128, 256, 64),   # wider clause bank: fewer adder-fold steps
@@ -78,8 +81,12 @@ _DEFAULT_CANDIDATES = (
     (256, 256, 32),
     (512, 512, 16),   # few big tiles: minimal grid overhead (small models)
     (64, 512, 64),
-    (512, 1024, 256),  # whole word chain per step (wide-literal shapes)
 )
+
+# Chain-id words the schedule kernels' SMEM tiles may hold: each tile is
+# double-buffered in v5e's 1 MiB SMEM next to the scalar-prefetched tile
+# tables, so block_c * block_j (+ block_t * term_w) stays within this.
+SMEM_TILE_WORDS = 96 * 1024
 
 # sparse (chain-schedule) kernel candidates: (block_c, block_j, block_s) —
 # clause bank x chain-tile bits x sample-word slab.  The schedule is
@@ -92,23 +99,21 @@ _SPARSE_CANDIDATES = (
     (256, 32, 16),
     (1024, 64, 8),
     (512, 16, 16),
-    (2048, 128, 16),  # long-chain trained banks: few big whole-chain tiles
-    (4096, 128, 16),
 )
 
 # factorized (two-level term-schedule) kernel candidates: (block_c,
 # block_j, block_t, block_s, term_w) — clause bank x term-chain tile x
 # stage-1 term tile x sample-word slab x term bit-chain width (0 = the
 # artifact's auto width).  Schedules are rebuilt per candidate: term table
-# size and tile counts depend on the tiling.
+# size and tile counts depend on the tiling.  With term_w <= 32, every
+# candidate's two SMEM chain tiles fit SMEM_TILE_WORDS.
 _TERM_CANDIDATES = (
-    (1024, 64, 32768, 16, 0),   # term_infer.py defaults, auto width
-    (1024, 64, 32768, 16, 2),   # narrowest rows: fat terms split to pieces
-    (1024, 128, 32768, 16, 2),
-    (2048, 128, 32768, 16, 2),
-    (4096, 64, 32768, 16, 2),
-    (1024, 32, 16384, 16, 0),
-    (512, 32, 4096, 16, 0),     # small-artifact shapes clip here
+    (1024, 64, 512, 16, 0),     # term_infer.py defaults, auto width
+    (1024, 64, 512, 16, 2),     # narrowest rows: fat terms split to pieces
+    (512, 128, 512, 16, 2),
+    (2048, 32, 512, 16, 2),
+    (1024, 32, 1024, 16, 0),
+    (512, 32, 1024, 16, 0),     # small-artifact shapes clip here
 )
 
 # training kernel candidates: the delta accumulator block is (block_c, L),

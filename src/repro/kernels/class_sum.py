@@ -16,7 +16,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import pallas_compat
 
 
 def _class_sum_kernel(fired_ref, votes_ref, out_ref):
@@ -62,7 +61,7 @@ def class_sum(
         ],
         out_specs=pl.BlockSpec((block_b, Kp), lambda b, c: (b, 0)),
         out_shape=jax.ShapeDtypeStruct((Bp, Kp), jnp.int32),
-        compiler_params=pallas_compat.CompilerParams(dimension_semantics=("parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(f, v)
     return out[:B, :K]

@@ -28,7 +28,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import pallas_compat
 
 
 def _clause_fire_kernel(lit_ref, inc_ref, out_ref, *, block_w: int):
@@ -90,7 +89,7 @@ def clause_fire(
         ],
         out_specs=pl.BlockSpec((block_b, block_c), lambda b, c, w: (b, c)),
         out_shape=jax.ShapeDtypeStruct((Bp, Cp), jnp.int8),
-        compiler_params=pallas_compat.CompilerParams(dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(lit, inc)
     return out[:B, :C]

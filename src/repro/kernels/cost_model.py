@@ -128,15 +128,13 @@ def hlo_forward_features(U: int, Wa: int, K: int,
     the one engine every backend can lower, so its post-optimization HLO
     is a backend-honest measure of the workload's intrinsic arithmetic and
     memory traffic — the quantity the roofline terms divide.  Extraction
-    goes through ``jax_compat.lower_compiled`` (the modern AOT idiom; the
-    retired ``jax.xla_computation`` path rotted here once) and
+    is an AOT ``jit(...).lower(...).compile()`` read by
     ``launch/hlo_analysis.analyze``.  Memoized per shape: one lowering per
     (U, Wa, K), shared by every candidate and every batch bucket.
     """
     import jax
     import jax.numpy as jnp
 
-    from repro import jax_compat
     from repro.kernels import ref
     from repro.launch import hlo_analysis
     from repro.launch.mesh import HBM_BW, PEAK_FLOPS_BF16
@@ -145,14 +143,13 @@ def hlo_forward_features(U: int, Wa: int, K: int,
         fired = ref.clause_fire_ref(lit_words, inc_words)
         return ref.class_sum_ref(fired, votes)
 
-    compiled = jax_compat.lower_compiled(
-        fwd,
+    compiled = jax.jit(fwd).lower(
         jax.ShapeDtypeStruct((batch, Wa), jnp.uint32),
         jax.ShapeDtypeStruct((U, Wa), jnp.uint32),
         jax.ShapeDtypeStruct((U, K), jnp.int32),
-    )
+    ).compile()
     cost = hlo_analysis.analyze(compiled.as_text())
-    ca = jax_compat.cost_analysis(compiled) or {}
+    ca = compiled.cost_analysis() or {}
     flops = cost.flops / batch
     hbm = cost.bytes / batch
     return dict(

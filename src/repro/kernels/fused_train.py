@@ -31,10 +31,16 @@ On the last chain step the finished fire block is turned into feedback
 types and folded into the delta.  TM feedback is *sparse by construction*
 (per sample only the target class and one sampled negative class receive
 feedback — 2/K of all clauses, further thinned by the clause-selection
-probability), so the per-sample delta fold is guarded by a
-``lax.cond`` that skips the hash-field evaluation for (sample, clause
-block) pairs with no feedback at all.  The skip is bit-exact: a zero
-``ftype`` row contributes exactly zero delta.
+probability), so the per-sample delta fold is guarded by a ``pl.when``
+that skips the hash-field evaluation for (sample, clause block) pairs
+with no feedback at all.  The skip is bit-exact: a zero ``ftype`` row
+contributes exactly zero delta.
+
+TPU layout: the clause state is ``(block_c, block_b)`` as in
+``fused_infer.py``; the per-sample fold selects sample ``i``'s clause
+column with a lane mask and a lane reduction, and reads its literal row
+with a dynamic sublane index — Mosaic slices lanes only at static
+offsets.
 
 Per-sample scalars (target class, sampled negative class, Type I/II
 selection probabilities) are computed by the caller from the class sums of
@@ -53,9 +59,9 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import pallas_compat
 from repro.kernels import ref as kref
-from repro.kernels.fused_infer import _pad2, _rup
+from repro.kernels.fused_infer import (_pad2, _rup, chain_blocks,
+                                       hcb_violations, vmem_limit_bytes)
 
 # hash-stream constants — MUST match ops.feedback_select / ops.feedback_plan
 _SEL_MIX = np.uint32(0x9E3779B1)
@@ -63,20 +69,19 @@ _SEL_XOR = np.uint32(0x85EBCA6B)
 
 
 def _fused_train_kernel(
-    scal_ref,   # (1, 3) uint32: [seed, b_offset, c_offset]
-    lit_ref,    # (block_b, block_w) uint32 packed literal words
+    scal_ref,   # SMEM (3,) int32: uint32 bits of [seed, b_offset, c_offset]
+    lit_ref,    # (block_w, block_b) uint32 word-major literal words
     inc_ref,    # (block_c, block_w) uint32 packed include words
-    lits_ref,   # (block_b, Lp) uint8 unpacked literals
+    lits_ref,   # (block_b, Lp) int32 unpacked literals
     ta_ref,     # (block_c, Lp) int8 automata states
     yk_ref,     # (2, block_b) int32: [target class; sampled negative class]
     pp_ref,     # (2, block_b) float32: [p_type1; p_type2] selection probs
-    cm_ref,     # (2, block_c) int32: [clause class; clause polarity]
+    cm_ref,     # (block_c, 2) int32: [clause class, clause polarity]
     out_ref,    # (block_c, Lp) int32 delta accumulator
-    ok_ref,     # VMEM scratch (block_b, block_c) int32 carried clause state
+    viol_ref,   # VMEM scratch (block_c, block_b) uint32 carried violations
     *,
     block_b: int,
     block_c: int,
-    block_w: int,
     c_dim: int,
     l_dim: int,
     t_act,
@@ -96,55 +101,41 @@ def _fused_train_kernel(
         out_ref[...] = jnp.zeros_like(out_ref)
 
     @pl.when(w == 0)
-    def _init_ok():  # HCB 0: all clauses start at 1 (training semantics)
-        ok_ref[...] = jnp.ones_like(ok_ref)
+    def _init_chain():  # HCB 0: no violation yet (training semantics)
+        viol_ref[...] = jnp.zeros_like(viol_ref)
 
-    lit = lit_ref[...]
-    inc = inc_ref[...]
-
-    def chain(i, ok):
-        l_w = jax.lax.dynamic_slice_in_dim(lit, i, 1, axis=1)   # (bb, 1)
-        i_w = jax.lax.dynamic_slice_in_dim(inc, i, 1, axis=1)   # (bc, 1)
-        viol = jnp.bitwise_and(i_w.reshape(1, -1), ~l_w)        # (bb, bc)
-        return ok & (viol == 0)
-
-    ok = jax.lax.fori_loop(0, block_w, chain, ok_ref[...] != 0, unroll=True)
-
-    @pl.when(w < nw - 1)
-    def _carry():  # Clause Out -> next HCB's Clause In
-        ok_ref[...] = ok.astype(ok_ref.dtype)
+    viol_ref[...] = hcb_violations(viol_ref[...], inc_ref[...], lit_ref[...])
 
     @pl.when(w == nw - 1)
     def _feedback():
-        seed = scal_ref[0, 0]
-        b_off = scal_ref[0, 1]
-        c_off = scal_ref[0, 2]
+        seed = scal_ref[0].astype(jnp.uint32)
+        b_off = scal_ref[1].astype(jnp.uint32)
+        c_off = scal_ref[2].astype(jnp.uint32)
+        fire = viol_ref[...] == 0                        # (block_c, block_b)
 
         # ---- inline feedback plan: bit-identical to ops.feedback_select.
         # Clause-selection randomness is hashed on GLOBAL (sample, clause)
         # ids (b_offset / c_offset) so chunked and sharded callers reproduce
         # the unsharded stream exactly.
-        bg = b0 + b_off + jax.lax.broadcasted_iota(
-            jnp.uint32, (block_b, block_c), 0)
-        cg = c0 + c_off + jax.lax.broadcasted_iota(
-            jnp.uint32, (block_b, block_c), 1)
-        r_sel = kref.hash_u32(bg * _SEL_MIX + cg, seed ^ _SEL_XOR)
-        r_sel = r_sel.astype(jnp.float32) / jnp.float32(2**32)
+        bg = b0 + b_off + jax.lax.broadcasted_iota(jnp.uint32, fire.shape, 1)
+        cg = c0 + c_off + jax.lax.broadcasted_iota(jnp.uint32, fire.shape, 0)
+        r_sel = kref.hash_unit(kref.hash_u32(bg * _SEL_MIX + cg,
+                                             seed ^ _SEL_XOR))
 
-        yv = yk_ref[0, :][:, None]       # (block_b, 1)
-        knv = yk_ref[1, :][:, None]
-        cls = cm_ref[0, :][None, :]      # (1, block_c)
-        pol = cm_ref[1, :][None, :]
-        is_t = cls == yv
-        is_n = cls == knv
-        p = jnp.where(is_t, pp_ref[0, :][:, None],
-                      jnp.where(is_n, pp_ref[1, :][:, None], 0.0))
-        sel = r_sel < p
+        cls = cm_ref[:, 0:1]             # (block_c, 1)
+        pol = cm_ref[:, 1:2]
+        is_t = cls == yk_ref[0:1, :]     # (block_c, block_b)
+        is_n = cls == yk_ref[1:2, :]
+        p = jnp.where(is_t, pp_ref[0:1, :],
+                      jnp.where(is_n, pp_ref[1:2, :], 0.0))
         pos = pol > 0
         neg = pol < 0
         ftype = jnp.where(is_t & pos, 1, jnp.where(is_t & neg, 2,
                 jnp.where(is_n & pos, 2, jnp.where(is_n & neg, 1, 0))))
-        ft = jnp.where(sel, ftype, 0).astype(jnp.int32)   # (block_b, block_c)
+        ft = jnp.where(r_sel < p, ftype, 0)
+        # per (clause, sample): 2 * ftype + fire, 0 where no feedback
+        code = jnp.where(ft != 0, 2 * ft + fire.astype(jnp.int32), 0)
+        lane = jax.lax.broadcasted_iota(jnp.int32, code.shape, 1)
 
         # ---- TA delta fold: bit-identical to ref.ta_delta_ref.  The
         # per-automaton hash is indexed by LOCAL (c, l) — matching the
@@ -157,34 +148,35 @@ def _fused_train_kernel(
         if global_clause:
             c_idx = c_idx + c_off
         l_idx = jax.lax.broadcasted_iota(jnp.uint32, shape, 1)
-        excl = ta_ref[...] < 0
-        lits_all = lits_ref[...]
 
-        def fold(i, acc):
-            ft_b = jax.lax.dynamic_slice_in_dim(ft, i, 1, 0)   # (1, bc)
+        def fold(i, carry):
+            # sample i's clause column, selected and lane-reduced (Mosaic
+            # slices lanes only at static offsets)
+            col = jnp.sum(jnp.where(lane == i, code, 0), axis=1,
+                          keepdims=True)                   # (block_c, 1)
 
-            def dense(a):
-                bu = b0 + b_off + jnp.uint32(i)
+            # feedback sparsity skip (bit-exact: ftype == 0 -> delta == 0)
+            @pl.when(jnp.any(col != 0))
+            def _dense():
+                ft_c = col >> 1
+                fire_c = (col & 1) == 1
+                bu = b0 + b_off + i.astype(jnp.uint32)
                 gidx = (bu * jnp.uint32(c_dim) + c_idx) \
                     * jnp.uint32(l_dim) + l_idx
                 r = kref.hash_u32(gidx, seed)
                 act = (r < t_act).astype(jnp.int32)
                 inact = (r < t_inact).astype(jnp.int32)
-                lit_on = jax.lax.dynamic_slice_in_dim(lits_all, i, 1, 0) == 1
-                fire_c = jax.lax.dynamic_slice_in_dim(ok, i, 1, 0) \
-                    .reshape(block_c, 1)
-                ft_c = ft_b.reshape(block_c, 1)
+                lit_on = lits_ref[pl.ds(i, 1), :] == 1     # (1, Lp)
                 d1 = jnp.where(fire_c,
                                jnp.where(lit_on, act, -inact), -inact)
+                excl = ta_ref[...].astype(jnp.int32) < 0
                 d2 = (fire_c & ~lit_on & excl).astype(jnp.int32)
-                return a + jnp.where(ft_c == 1, d1,
-                                     jnp.where(ft_c == 2, d2, 0))
+                out_ref[...] += jnp.where(ft_c == 1, d1,
+                                          jnp.where(ft_c == 2, d2, 0))
 
-            # feedback sparsity skip (bit-exact: ftype == 0 -> delta == 0)
-            return jax.lax.cond(jnp.any(ft_b != 0), dense, lambda a: a, acc)
+            return carry
 
-        out_ref[...] += jax.lax.fori_loop(
-            0, block_b, fold, jnp.zeros(shape, jnp.int32))
+        jax.lax.fori_loop(0, block_b, fold, 0)
 
 
 @functools.partial(
@@ -244,12 +236,13 @@ def fused_tm_train_delta(
     block_c = min(block_c, _rup(C, 128))
     block_w = min(block_w, W)
 
-    Bp, Cp, Wp = _rup(B, block_b), _rup(C, block_c), _rup(W, block_w)
+    Bp, Cp = _rup(B, block_b), _rup(C, block_c)
     Lp = _rup(L, 128)
+    nb = Bp // block_b
 
-    lit_p = _pad2(lit_words, Bp, Wp)    # zero literal words: harmless
-    inc_p = _pad2(inc_words, Cp, Wp)    # zero include words never violate
-    lits_p = _pad2(lits, Bp, Lp)
+    lit_t, inc = chain_blocks(lit_words, inc_words, Bp=Bp, Cp=Cp,
+                              block_b=block_b, block_w=block_w)
+    lits_p = _pad2(lits.astype(jnp.int32), Bp, Lp)
     ta_p = jnp.pad(ta, ((0, Cp - C), (0, Lp - L)), constant_values=-1)
     # padded samples get class -1, padded clauses class -1 / polarity 0:
     # any (padded, padded) class match still yields ftype 0 via polarity 0,
@@ -257,27 +250,27 @@ def fused_tm_train_delta(
     yk = jnp.stack([
         jnp.pad(y.astype(jnp.int32), (0, Bp - B), constant_values=-1),
         jnp.pad(kn.astype(jnp.int32), (0, Bp - B), constant_values=-1),
-    ])
+    ]).reshape(2, nb, block_b).transpose(1, 0, 2)
     pp = jnp.stack([
         jnp.pad(p_t.astype(jnp.float32), (0, Bp - B)),
         jnp.pad(p_n.astype(jnp.float32), (0, Bp - B)),
-    ])
+    ]).reshape(2, nb, block_b).transpose(1, 0, 2)
     cm = jnp.stack([
         jnp.pad(clause_class.astype(jnp.int32), (0, Cp - C),
                 constant_values=-1),
         jnp.pad(clause_pol.astype(jnp.int32), (0, Cp - C)),
-    ])
-    scal = jnp.stack([
+    ], axis=1)
+    scal = jax.lax.bitcast_convert_type(jnp.stack([
         jnp.asarray(seed).astype(jnp.uint32),
         jnp.asarray(b_offset).astype(jnp.uint32),
         jnp.asarray(c_offset).astype(jnp.uint32),
-    ]).reshape(1, 3)
+    ]), jnp.int32)
 
-    grid = (Cp // block_c, Bp // block_b, Wp // block_w)
+    grid = (Cp // block_c, nb, inc.shape[0])
     out = pl.pallas_call(
         functools.partial(
             _fused_train_kernel,
-            block_b=block_b, block_c=block_c, block_w=block_w,
+            block_b=block_b, block_c=block_c,
             c_dim=C if c_total is None else c_total, l_dim=L,
             t_act=kref.prob_to_u32(p_act),
             t_inact=kref.prob_to_u32(p_inact),
@@ -285,21 +278,28 @@ def fused_tm_train_delta(
         ),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, 3), lambda c, b, w: (0, 0)),            # scal
-            pl.BlockSpec((block_b, block_w), lambda c, b, w: (b, w)),  # lit
-            pl.BlockSpec((block_c, block_w), lambda c, b, w: (c, w)),  # inc
+            pl.BlockSpec(memory_space=pltpu.SMEM),                   # scal
+            pl.BlockSpec((None, block_w, block_b),
+                         lambda c, b, w: (b, w, 0)),                 # lit
+            pl.BlockSpec((None, block_c, block_w),
+                         lambda c, b, w: (w, c, 0)),                 # inc
             pl.BlockSpec((block_b, Lp), lambda c, b, w: (b, 0)),     # lits
             pl.BlockSpec((block_c, Lp), lambda c, b, w: (c, 0)),     # ta
-            pl.BlockSpec((2, block_b), lambda c, b, w: (0, b)),      # y/kn
-            pl.BlockSpec((2, block_b), lambda c, b, w: (0, b)),      # probs
-            pl.BlockSpec((2, block_c), lambda c, b, w: (0, c)),      # cls/pol
+            pl.BlockSpec((None, 2, block_b),
+                         lambda c, b, w: (b, 0, 0)),                 # y/kn
+            pl.BlockSpec((None, 2, block_b),
+                         lambda c, b, w: (b, 0, 0)),                 # probs
+            pl.BlockSpec((block_c, 2), lambda c, b, w: (c, 0)),      # cls/pol
         ],
         out_specs=pl.BlockSpec((block_c, Lp), lambda c, b, w: (c, 0)),
         out_shape=jax.ShapeDtypeStruct((Cp, Lp), jnp.int32),
-        scratch_shapes=[pltpu.VMEM((block_b, block_c), jnp.int32)],
-        compiler_params=pallas_compat.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary")
+        scratch_shapes=[pltpu.VMEM((block_c, block_b), jnp.uint32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=vmem_limit_bytes(
+                2 * 4 * (block_b * Lp + 2 * block_c * Lp)
+                + 4 * block_c * block_b),
         ),
         interpret=interpret,
-    )(scal, lit_p, inc_p, lits_p, ta_p, yk, pp, cm)
+    )(scal, lit_t, inc, lits_p, ta_p, yk, pp, cm)
     return out[:C, :L]

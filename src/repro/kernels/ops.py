@@ -1,11 +1,12 @@
 """Jit'd dispatch wrappers over the Pallas kernels (with oracle fallback).
 
-Every op takes ``use_kernel``/``interpret`` switches: on a real TPU the
-kernels run compiled (``interpret=False``); in this CPU container they are
-validated in interpret mode against the ``ref.py`` oracles, and the oracle
-path is the default execution engine (it is XLA-compiled and fast on CPU).
+Every op takes ``use_kernel``/``interpret`` switches whose defaults follow
+the platform: on a TPU the kernels are the default engine and run compiled
+(``interpret=False``); on CPU the ``ref.py`` oracle path is the default
+(XLA-compiled, fast), and the kernels run in Pallas interpret mode, where
+the tests validate them bit-exactly against those oracles.
 
-``REPRO_USE_PALLAS=1`` flips the default to the kernels (interpret on CPU).
+``REPRO_USE_PALLAS=1`` makes the kernels the default on CPU too.
 """
 
 from __future__ import annotations
@@ -17,7 +18,6 @@ import os
 import jax
 import jax.numpy as jnp
 
-from repro import jax_compat
 from repro.runtime import faults
 
 from repro.kernels import class_sum as _class_sum_kernel
@@ -30,8 +30,9 @@ from repro.kernels import ta_update as _ta_update_kernel
 from repro.kernels import term_infer as _term_infer_kernel
 from repro.kernels import xnor_popcount as _xnor_kernel
 
-_DEFAULT_USE_KERNEL = os.environ.get("REPRO_USE_PALLAS", "0") == "1"
 _ON_TPU = jax.default_backend() == "tpu"
+_DEFAULT_USE_KERNEL = (_ON_TPU
+                       or os.environ.get("REPRO_USE_PALLAS", "0") == "1")
 
 
 def _resolve(use_kernel, interpret):
@@ -59,9 +60,9 @@ class EngineSpec:
     ``name`` uses the :class:`EngineLadder` level vocabulary — serve's
     degradation ladder and the library share one set of words:
 
-    * ``"auto"`` — ambient dispatch (``REPRO_USE_PALLAS`` via
-      :func:`kernel_dispatch`); on the kernel path the schedule heuristics
-      pick factorized vs sparse exactly as before.
+    * ``"auto"`` — ambient dispatch (:func:`kernel_dispatch`: kernels on
+      a TPU, or on CPU under ``REPRO_USE_PALLAS=1``); on the kernel path
+      the schedule heuristics pick factorized vs sparse exactly as before.
     * ``"factorized"`` — the two-level shared-term schedule kernel.
     * ``"sparse"`` — the flat block-sparse chain schedule kernel.
     * ``"dense"`` — the fused dense kernel (``fuse=False`` for the legacy
@@ -596,10 +597,10 @@ def feedback_select(
     c_idx = (jnp.arange(C, dtype=jnp.uint32) + jnp.uint32(c_offset))[None, :]
     # hash indexed by global (b, c) via an offset-consistent mixing
     # (identical for sharded and unsharded callers)
-    r_sel = ref.hash_u32(
+    r_sel = ref.hash_unit(ref.hash_u32(
         b_idx[:, None] * jnp.uint32(0x9E3779B1) + c_idx,
         seed ^ jnp.uint32(0x85EBCA6B),
-    ).astype(jnp.float32) / jnp.float32(2**32)
+    ))
 
     is_t = clause_class[None, :] == y[:, None]                 # (B, C)
     is_n = clause_class[None, :] == kn[:, None]
@@ -929,7 +930,7 @@ def tm_train_step_matmul_local(
 
     di = jax.lax.axis_index("data")
     mi = jax.lax.axis_index("model")
-    n_data = jax_compat.axis_size("data")
+    n_data = jax.lax.axis_size("data")
     C_loc, L_loc = ta_loc.shape
     B_loc = x_loc.shape[0]
     b_off = di * B_loc

@@ -35,6 +35,18 @@ def hash_u32(idx: jax.Array, seed: jax.Array) -> jax.Array:
     return x
 
 
+def hash_unit(r: jax.Array) -> jax.Array:
+    """uint32 hash -> float32 in [0, 1]: ``float32(r) / 2**32``.
+
+    Built from the two exact 16-bit halves, so the only rounding is the one
+    in the final add — the same correctly rounded value as a direct cast,
+    which Mosaic cannot lower (it has no uint32 -> float32 conversion).
+    """
+    hi = (r >> 16).astype(jnp.int32).astype(jnp.float32)
+    lo = (r & np.uint32(0xFFFF)).astype(jnp.int32).astype(jnp.float32)
+    return (hi * np.float32(65536.0) + lo) / np.float32(2**32)
+
+
 def prob_to_u32(p: float) -> np.uint32:
     """Threshold such that P[hash < t] == p (up to 2^-32)."""
     return np.uint32(min(int(round(p * 2**32)), 2**32 - 1))

@@ -24,15 +24,17 @@ accelerators the paper cites): literals are bit-transposed so row ``l`` of
 ``litT`` packs literal ``l`` of 32 consecutive datapoints into one uint32.
 The carried clause state (``Clause In``/``Clause Out`` of paper Fig. 5) is
 then a (block_c, block_s) bitvector in VMEM scratch, and one chain step is
-``ok &= litT[chain_id]`` — work scales with the number of INCLUDE BITS in
-the artifact, not with ``C x W``.  An ``lax.cond`` early-exit skips a
-tile's gather+AND chain entirely once its carried clause state is all-zero
-(every clause in the block already dead for every sample in the slab).
+``ok[c] &= litT[chain_id]`` — work scales with the number of INCLUDE BITS
+in the artifact, not with ``C x W``.  The tile's chain ids ride in SMEM, so
+every step is a scalar-indexed single-row read of the literal slab (Mosaic
+lowers no vector gather).  A tile whose carried clause state is already
+all-zero (every clause in the block dead for every sample in the slab)
+skips its chain entirely.
 
-On the last tile of a block the finished clause bits are unpacked and
-folded into the int32 class sums through the deduped multiplicity x
-polarity vote matrix — dedup fan-out stays in the kernel, and the fired
-matrix never exists in HBM.
+On the last tile of a block the finished clause bits are folded, one
+sample bit at a time, into the int32 class sums through the deduped
+multiplicity x polarity vote matrix (int8 MXU dots, exact) — dedup fan-out
+stays in the kernel, and the fired matrix never exists in HBM.
 
 Correctness contract: all-zero include rows (clause-padding and the
 degenerate all-empty artifact) FIRE under this kernel (vacuous AND), so
@@ -40,9 +42,9 @@ their vote rows must be zero — true for every ``compile_tm`` artifact
 (empty clauses are dropped at compile time).  Do not point this kernel at
 a raw (uncompiled) model whose empty clauses carry votes.
 
-Like the other kernels in this package the schedule path is validated
-bit-exactly against the jnp oracle in Pallas interpret mode; compiled TPU
-lowering of the in-kernel row gather is tracked in ROADMAP "Next".
+The schedule path is validated bit-exactly against the jnp oracle in
+Pallas interpret mode, and compiled for a described TPU v5e by
+``tests/test_tpu_lowering.py``.
 """
 
 from __future__ import annotations
@@ -57,7 +59,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import packetizer
-from repro.kernels.fused_infer import _rup
+from repro.kernels.fused_infer import _rup, fold_votes, vote_limbs
 
 # default chain tiling: 512-clause banks, 32-bit chain tiles, 16-word
 # (512-sample) slabs — see kernels/autotune.py for the swept alternatives
@@ -380,42 +382,108 @@ _NEG_SUM = -(2 ** 28)
 
 
 def _slab_lead_margin(sums, n_classes):
-    """Per-sample top1 - top2 over the real class columns; ties -> 0."""
-    col = jax.lax.broadcasted_iota(jnp.int32, sums.shape, 1)
+    """Per-sample top1 - top2 over the real class columns (the last axis);
+    ties -> 0."""
+    col = jax.lax.broadcasted_iota(jnp.int32, sums.shape, sums.ndim - 1)
     masked = jnp.where(col < n_classes, sums, jnp.int32(_NEG_SUM))
-    top1 = jnp.max(masked, axis=1)
-    is_top = masked == top1[:, None]
-    second = jnp.max(jnp.where(is_top, jnp.int32(_NEG_SUM), masked), axis=1)
-    tied = jnp.sum(is_top.astype(jnp.int32), axis=1) > 1
+    top1 = jnp.max(masked, axis=-1)
+    is_top = masked == top1[..., None]
+    second = jnp.max(jnp.where(is_top, jnp.int32(_NEG_SUM), masked), axis=-1)
+    tied = jnp.sum(is_top.astype(jnp.int32), axis=-1) > 1
     return jnp.where(tied, jnp.int32(0), top1 - second)
+
+
+def literal_slabs(lit_words: jax.Array, block_s: int):
+    """Bit-transposed literals cut into sample slabs: ``(Swp // block_s,
+    L + 1, block_s)`` uint32, so a slab block spans the whole trailing two
+    dims (Mosaic's block-shape rule holds for any ``block_s``)."""
+    B, W = lit_words.shape
+    litT = bit_transpose_literals(lit_words, W * 32)
+    Swp = _rup(litT.shape[1], block_s)
+    litT = jnp.pad(litT, ((0, 0), (0, Swp - litT.shape[1])))
+    return litT.reshape(W * 32 + 1, Swp // block_s, block_s).transpose(1, 0, 2)
+
+
+def smem_tiles(ids: jax.Array, rows: int, cols: int) -> jax.Array:
+    """``(R, J)`` int32 id table -> ``(R // rows, J // cols, 1, rows *
+    cols)``: one flat row per ``(rows, cols)`` tile, row-major within the
+    tile — the layout a tile's SMEM block takes whole."""
+    R, J = ids.shape
+    t = ids.reshape(R // rows, rows, J // cols, cols).transpose(0, 2, 1, 3)
+    return t.reshape(R // rows, J // cols, 1, rows * cols)
+
+
+def chain_and(row, src_ref, ids_ref, base: int, width: int):
+    """AND ``width`` rows of ``src_ref`` into ``row``: one HCB chain step
+    per id ``ids_ref[0, base + j]``.  Ids are SMEM scalars, so each step
+    is a dynamic single-row (sublane) read — the form of gather Mosaic
+    lowers."""
+    for j in range(width):
+        row = row & src_ref[pl.ds(ids_ref[0, base + j], 1), :]
+    return row
+
+
+def walk_chains(ok_ref, src_ref, ids_ref, width: int):
+    """``ok[r] &= AND_j src[ids[r, j]]`` for every row of ``ok_ref``."""
+    def body(r, carry):
+        ok_ref[pl.ds(r, 1), :] = chain_and(
+            ok_ref[pl.ds(r, 1), :], src_ref, ids_ref, r * width, width)
+        return carry
+
+    jax.lax.fori_loop(0, ok_ref.shape[0], body, 0)
+
+
+def fold_sample_bits(out_ref, ok, hi, lo):
+    """Adder bank: ``out[i, s] += votes^T @ bit_i(ok[:, s])`` for each of
+    the 32 sample bits packed in a word — ``out_ref`` is ``(32, block_s,
+    Kp)``, sample ``32 * word + i`` at ``[i, word]``."""
+    for i in range(32):
+        out_ref[i] += fold_votes((ok >> i) & 1, hi, lo)
+
+
+def certify_slab(out_ref, done_ref, margin, slab, n_classes, n_samples):
+    """Exact early exit: mark the slab done once every real sample's lead
+    STRICTLY beats ``margin`` (the residual swing) — no remaining tile can
+    change any argmax in it.  Padding sample slots sum to 0 forever and
+    count as certified."""
+    lead = _slab_lead_margin(out_ref[...], n_classes)       # (32, block_s)
+    bit = jax.lax.broadcasted_iota(jnp.int32, lead.shape, 0)
+    word = jax.lax.broadcasted_iota(jnp.int32, lead.shape, 1)
+    row = (slab * lead.shape[1] + word) * 32 + bit
+    lead = jnp.where(row < n_samples, lead, jnp.int32(-_NEG_SUM))
+    done_ref[0] = jnp.where(jnp.all(lead > margin), 1, done_ref[0])
+
+
+def unslab_sums(out, B: int, K: int):
+    """``(n_slabs, 32, block_s, Kp)`` kernel output -> ``(B, K)``."""
+    n, _, bs, Kp = out.shape
+    return out.transpose(0, 2, 1, 3).reshape(n * bs * 32, Kp)[:B, :K]
 
 
 def _sparse_infer_kernel(
     *refs,
     # positional refs: tcb, tjb, tfirst, tlast, [tmargin,] litT, chain,
-    # votes -> out, ok scratch [, done scratch]
+    # hi, lo -> out, ok scratch [, done scratch]
     #   tcb/tjb     (T,) scalar-prefetch: clause-/chain-block id per tile
     #   tfirst/tlast (T,) scalar-prefetch: first/last tile of its clause block
     #   tmargin     (T,) scalar-prefetch: residual vote swing after tile t
-    #   litT        (L + 1, block_s) uint32 bit-transposed literals
-    #   chain       (block_c, block_j) int32 literal ids of this chain tile
-    #   votes       (block_c, Kp) int32 multiplicity x polarity votes
-    #   out         (block_s * 32, Kp) int32 class sums
+    #   litT        (L + 1, block_s) uint32 bit-transposed literal slab
+    #   chain       SMEM (1, block_c * block_j) int32 literal ids of the tile
+    #   hi/lo       (block_c, Kp) int8 vote limbs (fused_infer.vote_limbs)
+    #   out         (32, block_s, Kp) int32 class sums (sample-bit major)
     #   ok          VMEM scratch (block_c, block_s) uint32 carried clause bits
     #   done        SMEM scratch (1,) int32 — slab certified, skip tiles
-    block_c: int,
     block_j: int,
-    block_s: int,
     n_classes: int = 0,
     n_samples: int = 0,
     early_exit: bool = False,
 ):
     if early_exit:
         (tcb_ref, tjb_ref, tfirst_ref, tlast_ref, tmargin_ref,
-         litT_ref, chain_ref, votes_ref, out_ref, ok_ref, done_ref) = refs
+         litT_ref, chain_ref, hi_ref, lo_ref, out_ref, ok_ref, done_ref) = refs
     else:
         (tcb_ref, tjb_ref, tfirst_ref, tlast_ref,
-         litT_ref, chain_ref, votes_ref, out_ref, ok_ref) = refs
+         litT_ref, chain_ref, hi_ref, lo_ref, out_ref, ok_ref) = refs
         tmargin_ref = done_ref = None
     t = pl.program_id(1)
     slab = pl.program_id(0)   # hoisted: program_id can't lower inside pl.when
@@ -426,61 +494,32 @@ def _sparse_infer_kernel(
         if early_exit:
             done_ref[0] = 0
 
-    active = jnp.logical_not(done_ref[0]) if early_exit else True
+    active = done_ref[0] == 0 if early_exit else True
 
     @pl.when(tfirst_ref[t] == 1)
     def _init_ok():   # chain start: every clause alive for every sample
         ok_ref[...] = jnp.full_like(ok_ref, 0xFFFFFFFF)
 
-    ok0 = ok_ref[...]
-
-    def chain(ok):
-        # one gather for the whole tile's chain, then a tree-AND over the
-        # block_j bit positions (log2 ops instead of block_j — the chain
-        # is associative); sentinel ids land on the all-ones row
-        ids = chain_ref[...].reshape(-1)                      # (bc * bj,)
-        g = jnp.take(litT_ref[...], ids, axis=0)
-        g = g.reshape(block_c, block_j, block_s)
-        while g.shape[1] > 1:
-            half = g.shape[1] // 2
-            lo = g[:, :half, :] & g[:, half:2 * half, :]
-            g = (jnp.concatenate([lo, g[:, 2 * half:, :]], axis=1)
-                 if g.shape[1] % 2 else lo)
-        return ok & g[:, 0, :]
-
     # early exit: the whole slab of clauses is already dead — skip the
-    # gather and the AND chain (Clause-Out all zero propagates unchanged);
-    # in exact early-exit mode a certified slab skips every remaining tile
-    live = jnp.any(ok0 != 0)
-    ok = jax.lax.cond(jnp.logical_and(live, active) if early_exit else live,
-                      chain, lambda o: o, ok0)
+    # chain (Clause-Out all zero propagates unchanged); in exact early-exit
+    # mode a certified slab skips every remaining tile.  Sentinel ids land
+    # on the all-ones row.
+    live = jnp.any(ok_ref[...] != 0)
 
-    @pl.when(tlast_ref[t] == 0)
-    def _carry():   # Clause Out -> next chain tile's Clause In
-        ok_ref[...] = ok
+    @pl.when(jnp.logical_and(live, active) if early_exit else live)
+    def _chain():
+        walk_chains(ok_ref, litT_ref, chain_ref, block_j)
 
     fold_pred = tlast_ref[t] == 1
     if early_exit:
         fold_pred = jnp.logical_and(fold_pred, active)
 
     @pl.when(fold_pred)
-    def _fold():    # adder bank: unpack sample bits, fold multiplicity votes
-        shifts = jnp.arange(32, dtype=jnp.uint32)
-        fired = ((ok[:, :, None] >> shifts) & jnp.uint32(1)).astype(jnp.int32)
-        fired = fired.reshape(block_c, block_s * 32)          # (bc, samples)
-        out_ref[...] += jax.lax.dot_general(
-            fired.T, votes_ref[...], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.int32,
-        )
+    def _fold():    # adder bank: fold each sample bit's multiplicity votes
+        fold_sample_bits(out_ref, ok_ref[...], hi_ref[...], lo_ref[...])
         if early_exit:
-            # certify: every sample's lead STRICTLY beats the residual
-            # swing -> no remaining tile can change any argmax in the slab
-            # (padding sample slots sum to 0 forever; count them certified)
-            lead = _slab_lead_margin(out_ref[...], n_classes)
-            row = slab * (block_s * 32) + jax.lax.iota(jnp.int32, block_s * 32)
-            lead = jnp.where(row < n_samples, lead, jnp.int32(-_NEG_SUM))
-            certified = jnp.all(lead > tmargin_ref[t])
-            done_ref[0] = jnp.where(certified, 1, done_ref[0])
+            certify_slab(out_ref, done_ref, tmargin_ref[t], slab,
+                         n_classes, n_samples)
 
 
 @functools.partial(
@@ -602,35 +641,36 @@ def sparse_tm_forward_tables(
     bodies: the chain/tile tables arrive as (sharded) arrays instead of a
     static schedule, so one jit serves every shard."""
     B, W = lit_words.shape
-    Cp, Jp = chain_ids.shape
+    Cp = chain_ids.shape[0]
     K = votes.shape[1]
-    T = tiles.shape[1]
     Kp = _rup(K, 128)
-    Sw = packetizer.n_words(B)
-    block_s = max(min(block_s, Sw), 1)
-    Swp = _rup(Sw, block_s)
+    block_s = max(min(block_s, packetizer.n_words(B)), 1)
 
-    litT = bit_transpose_literals(lit_words, W * 32)
-    litT = jnp.pad(litT, ((0, 0), (0, Swp - litT.shape[1])))
-    vts = jnp.pad(votes.astype(jnp.int32), ((0, 0), (0, Kp - K)))
+    litT = literal_slabs(lit_words, block_s)
+    chain = smem_tiles(chain_ids, block_c, block_j)
+    hi, lo = vote_limbs(votes, Cp, Kp)
 
     early_exit = tile_margin is not None
-    n_prefetch = 5 if early_exit else 4
     scratch = [pltpu.VMEM((block_c, block_s), jnp.uint32)]
     if early_exit:
         scratch.append(pltpu.SMEM((1,), jnp.int32))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=n_prefetch,
-        grid=(Swp // block_s, T),
+        num_scalar_prefetch=5 if early_exit else 4,
+        grid=(litT.shape[0], tiles.shape[1]),
         in_specs=[
-            pl.BlockSpec((W * 32 + 1, block_s), lambda s, t, *refs: (0, s)),
-            pl.BlockSpec((block_c, block_j),
-                         lambda s, t, cb, jb, *refs: (cb[t], jb[t])),
+            pl.BlockSpec((None, W * 32 + 1, block_s),
+                         lambda s, t, *refs: (s, 0, 0)),
+            pl.BlockSpec((None, None, 1, block_c * block_j),
+                         lambda s, t, cb, jb, *refs: (cb[t], jb[t], 0, 0),
+                         memory_space=pltpu.SMEM),
+            pl.BlockSpec((block_c, Kp),
+                         lambda s, t, cb, jb, *refs: (cb[t], 0)),
             pl.BlockSpec((block_c, Kp),
                          lambda s, t, cb, jb, *refs: (cb[t], 0)),
         ],
-        out_specs=pl.BlockSpec((block_s * 32, Kp), lambda s, t, *refs: (s, 0)),
+        out_specs=pl.BlockSpec((None, 32, block_s, Kp),
+                               lambda s, t, *refs: (s, 0, 0, 0)),
         scratch_shapes=scratch,
     )
     prefetch = [tiles[0], tiles[1], tiles[2], tiles[3]]
@@ -638,15 +678,15 @@ def sparse_tm_forward_tables(
         prefetch.append(jnp.asarray(tile_margin, jnp.int32))
     out = pl.pallas_call(
         functools.partial(
-            _sparse_infer_kernel,
-            block_c=block_c, block_j=block_j, block_s=block_s,
+            _sparse_infer_kernel, block_j=block_j,
             n_classes=K, n_samples=B, early_exit=early_exit,
         ),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((Swp * 32, Kp), jnp.int32),
+        out_shape=jax.ShapeDtypeStruct((litT.shape[0], 32, block_s, Kp),
+                                       jnp.int32),
         interpret=interpret,
-    )(*prefetch, litT, chain_ids, vts)
-    return out[:B, :K]
+    )(*prefetch, litT, chain, hi, lo)
+    return unslab_sums(out, B, K)
 
 
 def schedule_class_sums_ref(

@@ -20,7 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import pallas_compat
 
 from repro.kernels import ref as kref
 
@@ -127,7 +126,7 @@ def ta_delta(
         ],
         out_specs=pl.BlockSpec((block_c, block_l), lambda c, l: (c, l)),
         out_shape=jax.ShapeDtypeStruct((Cp, Lp), jnp.int32),
-        compiler_params=pallas_compat.CompilerParams(dimension_semantics=("parallel", "parallel")),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(scal, ta_p, lit_p, fire_p, ft_p)
     return out[:C, :L]

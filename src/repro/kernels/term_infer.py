@@ -21,15 +21,18 @@ tile table (``tile_stage`` flags term vs clause tiles; term tiles come
 first so the flat tile walk is stage 1 then stage 2):
 
   * **stage 1** (term tiles): each unique term is evaluated ONCE against
-    the bit-transposed literals — gather the term's literal rows, tree-AND
-    them — into a ``(Tp, block_s)`` uint32 bitvector scratch (row ``t`` =
-    term ``t`` of 32 samples per word, the same sample-parallel layout as
-    the clause state);
+    the bit-transposed literals — AND the term's literal rows — into a
+    ``(Tp, block_s)`` uint32 bitvector scratch (row ``t`` = term ``t`` of
+    32 samples per word, the same sample-parallel layout as the clause
+    state);
   * **stage 2** (clause tiles): the carried ``(block_c, block_s)`` clause
-    state gathers TERM rows from the scratch and tree-ANDs them — one step
-    per *active word*, not per include bit — then the last tile of each
-    clause block unpacks the fired bits and folds the multiplicity x
-    polarity votes through one MXU dot.
+    state ANDs in TERM rows from the scratch — one step per *active word*,
+    not per include bit — then the last tile of each clause block folds
+    the fired bits into the multiplicity x polarity votes (int8 MXU dots).
+
+Both stages read rows by scalar id: the tiles' chain ids ride in SMEM
+blocks, and each chain step is a dynamic single-row read (the sparse
+kernel's :func:`~repro.kernels.sparse_infer.chain_and`).
 
 Work therefore scales with the artifact's UNIQUE include structure: a term
 shared by ``n`` clauses costs its bit chain once plus ``n`` single-row
@@ -40,8 +43,8 @@ all-zero clause rows fire vacuously, and their votes must be zero (true
 for every ``compile_tm`` artifact).
 
 Validated bit-exactly against the jnp oracle in Pallas interpret mode
-(tests/test_term_infer.py); compiled-TPU lowering of the in-kernel row
-gather shares the ROADMAP "Next" item with the sparse kernel.
+(tests/test_term_infer.py) and compiled for a described TPU v5e by
+``tests/test_tpu_lowering.py``.
 """
 
 from __future__ import annotations
@@ -56,17 +59,19 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import packetizer
-from repro.kernels.fused_infer import _rup
-from repro.kernels.sparse_infer import (_NEG_SUM, _slab_lead_margin,
-                                        artifact_tag, bit_transpose_literals)
+from repro.kernels.fused_infer import _rup, vmem_limit_bytes, vote_limbs
+from repro.kernels.sparse_infer import (artifact_tag, certify_slab, chain_and,
+                                        fold_sample_bits, literal_slabs,
+                                        smem_tiles, unslab_sums, walk_chains)
 
-# default factorized tiling: 1024-clause banks, 64-term chain tiles, one
-# big 32768-term stage-1 tile (term evaluation is the cheap stage — fewer,
-# larger tiles beat grid overhead), 16-word (512-sample) slabs — see
-# kernels/autotune.py for the swept alternatives; small artifacts clip
+# default factorized tiling: 1024-clause banks, 64-term chain tiles,
+# 512-term stage-1 tiles, 16-word (512-sample) slabs — see
+# kernels/autotune.py for the swept alternatives; small artifacts clip.
+# Both chain tiles ride in SMEM (1 MiB on v5e, double-buffered), which
+# bounds block_c * block_j + block_t * term_w (autotune.SMEM_TILE_WORDS).
 DEFAULT_BLOCK_C = 1024
 DEFAULT_BLOCK_J = 64
-DEFAULT_BLOCK_T = 32768
+DEFAULT_BLOCK_T = 512
 DEFAULT_BLOCK_S = 16
 
 
@@ -334,25 +339,23 @@ def build_factorized_schedule_cached(
 def _term_infer_kernel(
     *refs,
     # positional refs: tstage, ttb, tcb, tjb, tfirst, tlast, [tmargin,]
-    # litT, tchain, cchain, votes -> out, term scratch, ok scratch
+    # litT, tchain, cchain, hi, lo -> out, term scratch, ok scratch
     # [, done scratch]
     #   tstage       (T,) scalar-prefetch: 0 = term tile, 1 = clause tile
     #   ttb          (T,) scalar-prefetch: term-block id per stage-1 tile
     #   tcb/tjb      (T,) scalar-prefetch: clause-/chain-block id (stage 2)
     #   tfirst/tlast (T,) scalar-prefetch: first/last clause tile of block
     #   tmargin      (T,) scalar-prefetch: residual vote swing after tile t
-    #   litT         (L + 1, block_s) uint32 bit-transposed literals
-    #   tchain       (block_t, term_w) int32 literal ids of this term tile
-    #   cchain       (block_c, block_j) int32 term ids of this clause tile
-    #   votes        (block_c, Kp) int32 multiplicity x polarity votes
-    #   out          (block_s * 32, Kp) int32 class sums
+    #   litT         (L + 1, block_s) uint32 bit-transposed literal slab
+    #   tchain       SMEM (1, block_t * term_w) literal ids of the term tile
+    #   cchain       SMEM (1, block_c * block_j) term ids of the clause tile
+    #   hi/lo        (block_c, Kp) int8 vote limbs (fused_infer.vote_limbs)
+    #   out          (32, block_s, Kp) int32 class sums (sample-bit major)
     #   term         VMEM scratch (Tp, block_s) uint32 term bitvectors
     #   ok           VMEM scratch (block_c, block_s) uint32 carried bits
     #   done         SMEM scratch (1,) int32 — slab certified, skip tiles
     block_t: int,
-    block_c: int,
     block_j: int,
-    block_s: int,
     term_w: int,
     n_classes: int = 0,
     n_samples: int = 0,
@@ -360,11 +363,11 @@ def _term_infer_kernel(
 ):
     if early_exit:
         (tstage_ref, ttb_ref, tcb_ref, tjb_ref, tfirst_ref, tlast_ref,
-         tmargin_ref, litT_ref, tchain_ref, cchain_ref, votes_ref,
+         tmargin_ref, litT_ref, tchain_ref, cchain_ref, hi_ref, lo_ref,
          out_ref, term_ref, ok_ref, done_ref) = refs
     else:
         (tstage_ref, ttb_ref, tcb_ref, tjb_ref, tfirst_ref, tlast_ref,
-         litT_ref, tchain_ref, cchain_ref, votes_ref,
+         litT_ref, tchain_ref, cchain_ref, hi_ref, lo_ref,
          out_ref, term_ref, ok_ref) = refs
         tmargin_ref = done_ref = None
     t = pl.program_id(1)
@@ -376,16 +379,7 @@ def _term_infer_kernel(
         if early_exit:
             done_ref[0] = 0
 
-    active = jnp.logical_not(done_ref[0]) if early_exit else True
-
-    def _tree_and(g):
-        # tree-AND over the chain axis (log2 ops — the chain is associative)
-        while g.shape[1] > 1:
-            half = g.shape[1] // 2
-            lo = g[:, :half, :] & g[:, half:2 * half, :]
-            g = (jnp.concatenate([lo, g[:, 2 * half:, :]], axis=1)
-                 if g.shape[1] % 2 else lo)
-        return g[:, 0, :]
+    active = done_ref[0] == 0 if early_exit else True
 
     stage0 = tstage_ref[t] == 0
     if early_exit:   # a certified slab skips every remaining tile
@@ -393,13 +387,19 @@ def _term_infer_kernel(
 
     @pl.when(stage0)
     def _eval_terms():
-        # stage 1: one gather + tree-AND evaluates block_t unique terms for
-        # the whole sample slab; sentinel ids land on the all-ones row, so
-        # padding terms come out constant 1 (the clause-chain AND identity)
-        ids = tchain_ref[...].reshape(-1)
-        g = jnp.take(litT_ref[...], ids, axis=0)
-        g = g.reshape(block_t, term_w, block_s)
-        term_ref[pl.ds(ttb_ref[t] * block_t, block_t), :] = _tree_and(g)
+        # stage 1: each of the tile's block_t unique terms is evaluated
+        # once for the whole sample slab; sentinel ids land on the
+        # all-ones row, so padding terms come out constant 1 (the
+        # clause-chain AND identity)
+        base = ttb_ref[t] * block_t
+        ones = jnp.full((1, term_ref.shape[1]), 0xFFFFFFFF, jnp.uint32)
+
+        def body(r, carry):
+            term_ref[pl.ds(base + r, 1), :] = chain_and(
+                ones, litT_ref, tchain_ref, r * term_w, term_w)
+            return carry
+
+        jax.lax.fori_loop(0, block_t, body, 0)
 
     stage1 = tstage_ref[t] == 1
     if early_exit:
@@ -411,41 +411,19 @@ def _term_infer_kernel(
         def _init_ok():   # chain start: every clause alive for every sample
             ok_ref[...] = jnp.full_like(ok_ref, 0xFFFFFFFF)
 
-        ok0 = ok_ref[...]
-
-        def chain(ok):
-            # stage 2: one chain step per ACTIVE WORD — a single-row gather
-            # of the term's precomputed bitvector instead of its bit chain
-            ids = cchain_ref[...].reshape(-1)
-            g = jnp.take(term_ref[...], ids, axis=0)
-            return ok & _tree_and(g.reshape(block_c, block_j, block_s))
-
-        # early exit: the whole slab of clauses is already dead
-        ok = jax.lax.cond(jnp.any(ok0 != 0), chain, lambda o: o, ok0)
-
-        @pl.when(tlast_ref[t] == 0)
-        def _carry():   # Clause Out -> next chain tile's Clause In
-            ok_ref[...] = ok
+        # stage 2: one chain step per ACTIVE WORD — a single-row read of
+        # the term's precomputed bitvector instead of its bit chain; a
+        # slab whose clauses are all dead already skips the chain
+        @pl.when(jnp.any(ok_ref[...] != 0))
+        def _chain():
+            walk_chains(ok_ref, term_ref, cchain_ref, block_j)
 
         @pl.when(tlast_ref[t] == 1)
-        def _fold():    # adder bank: unpack sample bits, fold votes
-            shifts = jnp.arange(32, dtype=jnp.uint32)
-            fired = ((ok[:, :, None] >> shifts) & jnp.uint32(1)).astype(
-                jnp.int32)
-            fired = fired.reshape(block_c, block_s * 32)
-            out_ref[...] += jax.lax.dot_general(
-                fired.T, votes_ref[...], (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.int32,
-            )
+        def _fold():    # adder bank: fold each sample bit's votes
+            fold_sample_bits(out_ref, ok_ref[...], hi_ref[...], lo_ref[...])
             if early_exit:
-                # certify: every real sample's lead STRICTLY beats the
-                # residual swing (padding sample slots stay certified)
-                lead = _slab_lead_margin(out_ref[...], n_classes)
-                row = (slab * (block_s * 32)
-                       + jax.lax.iota(jnp.int32, block_s * 32))
-                lead = jnp.where(row < n_samples, lead, jnp.int32(-_NEG_SUM))
-                certified = jnp.all(lead > tmargin_ref[t])
-                done_ref[0] = jnp.where(certified, 1, done_ref[0])
+                certify_slab(out_ref, done_ref, tmargin_ref[t], slab,
+                             n_classes, n_samples)
 
 
 @functools.partial(
@@ -510,17 +488,15 @@ def factorized_tm_forward_tables(
     static schedule, so one jit serves every shard."""
     B, W = lit_words.shape
     Tp, term_w = term_chain.shape
-    Cp, Jp = clause_chain.shape
+    Cp = clause_chain.shape[0]
     K = votes.shape[1]
-    T = tiles.shape[1]
     Kp = _rup(K, 128)
-    Sw = packetizer.n_words(B)
-    block_s = max(min(block_s, Sw), 1)
-    Swp = _rup(Sw, block_s)
+    block_s = max(min(block_s, packetizer.n_words(B)), 1)
 
-    litT = bit_transpose_literals(lit_words, W * 32)
-    litT = jnp.pad(litT, ((0, 0), (0, Swp - litT.shape[1])))
-    vts = jnp.pad(votes.astype(jnp.int32), ((0, 0), (0, Kp - K)))
+    litT = literal_slabs(lit_words, block_s)
+    tchain = smem_tiles(term_chain, block_t, term_w)
+    cchain = smem_tiles(clause_chain, block_c, block_j)
+    hi, lo = vote_limbs(votes, Cp, Kp)
 
     early_exit = tile_margin is not None
     scratch = [
@@ -532,17 +508,24 @@ def factorized_tm_forward_tables(
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=7 if early_exit else 6,
-        grid=(Swp // block_s, T),
+        grid=(litT.shape[0], tiles.shape[1]),
         in_specs=[
-            pl.BlockSpec((W * 32 + 1, block_s), lambda s, t, *refs: (0, s)),
-            pl.BlockSpec((block_t, term_w),
-                         lambda s, t, stg, tb, cb, jb, *refs: (tb[t], 0)),
-            pl.BlockSpec((block_c, block_j),
-                         lambda s, t, stg, tb, cb, jb, *refs: (cb[t], jb[t])),
+            pl.BlockSpec((None, W * 32 + 1, block_s),
+                         lambda s, t, *refs: (s, 0, 0)),
+            pl.BlockSpec((None, None, 1, block_t * term_w),
+                         lambda s, t, stg, tb, *refs: (tb[t], 0, 0, 0),
+                         memory_space=pltpu.SMEM),
+            pl.BlockSpec((None, None, 1, block_c * block_j),
+                         lambda s, t, stg, tb, cb, jb, *refs:
+                         (cb[t], jb[t], 0, 0),
+                         memory_space=pltpu.SMEM),
+            pl.BlockSpec((block_c, Kp),
+                         lambda s, t, stg, tb, cb, jb, *refs: (cb[t], 0)),
             pl.BlockSpec((block_c, Kp),
                          lambda s, t, stg, tb, cb, jb, *refs: (cb[t], 0)),
         ],
-        out_specs=pl.BlockSpec((block_s * 32, Kp), lambda s, t, *refs: (s, 0)),
+        out_specs=pl.BlockSpec((None, 32, block_s, Kp),
+                               lambda s, t, *refs: (s, 0, 0, 0)),
         scratch_shapes=scratch,
     )
     prefetch = [tiles[0], tiles[1], tiles[2], tiles[3], tiles[4], tiles[5]]
@@ -551,15 +534,21 @@ def factorized_tm_forward_tables(
     out = pl.pallas_call(
         functools.partial(
             _term_infer_kernel,
-            block_t=block_t, block_c=block_c, block_j=block_j,
-            block_s=block_s, term_w=term_w,
+            block_t=block_t, block_j=block_j, term_w=term_w,
             n_classes=K, n_samples=B, early_exit=early_exit,
         ),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((Swp * 32, Kp), jnp.int32),
+        out_shape=jax.ShapeDtypeStruct((litT.shape[0], 32, block_s, Kp),
+                                       jnp.int32),
+        # the whole-table term scratch grows with the artifact (its
+        # block_s lanes pad to 128), so a large bank outgrows the default
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=vmem_limit_bytes(
+                4 * _rup(block_s, 128) * (Tp + block_c + 2 * (W * 32 + 1))
+                + 2 * 2 * block_c * Kp + 2 * 4 * 32 * block_s * Kp)),
         interpret=interpret,
-    )(*prefetch, litT, term_chain, clause_chain, vts)
-    return out[:B, :K]
+    )(*prefetch, litT, tchain, cchain, hi, lo)
+    return unslab_sums(out, B, K)
 
 
 def factorized_class_sums_ref(

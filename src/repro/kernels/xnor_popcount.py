@@ -16,7 +16,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import pallas_compat
 
 
 def _xnor_kernel(a_ref, w_ref, out_ref, *, block_w: int):
@@ -74,7 +73,7 @@ def xnor_popcount(
         ],
         out_specs=pl.BlockSpec((block_b, block_o), lambda b, o, w: (b, o)),
         out_shape=jax.ShapeDtypeStruct((Bp, Op), jnp.int32),
-        compiler_params=pallas_compat.CompilerParams(dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(a, w)[:B, :O]
 

@@ -30,7 +30,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import jax_compat
 from repro.configs import ARCH_IDS, get_config
 from repro.configs.matador_tm import TM_CONFIGS
 from repro.launch import roofline, specs
@@ -213,7 +212,7 @@ def run_cell(arch: str, shape_name: str, mesh_name: str, *, smoke: bool = False)
     compiled = lowered.compile()
     t_compile = time.time() - t0
 
-    mem = jax_compat.memory_analysis(compiled)
+    mem = compiled.memory_analysis()
     report = roofline.build_report(
         arch=arch,
         shape=shape_name,
@@ -226,8 +225,8 @@ def run_cell(arch: str, shape_name: str, mesh_name: str, *, smoke: bool = False)
     )
     rec = report.as_dict()
     rec["lower_seconds"] = t_lower
-    ca = jax_compat.cost_analysis(compiled)
-    rec["xla_cost_flops"] = float(ca.get("flops", 0.0)) if ca else 0.0
+    rec["xla_cost_flops"] = float(
+        (compiled.cost_analysis() or {}).get("flops", 0.0))
     return rec
 
 

@@ -23,13 +23,14 @@ import jax.numpy as jnp
 import numpy as np
 
 
-def serve_tm(args) -> None:
+def serve_tm(args) -> dict:
     """Chunked streaming TM serve loop with an engine degradation ladder.
 
     Requests stream through fixed-size buckets of ``--bucket`` datapoints:
     one jit trace (bucket-shaped input, donated on accelerators) serves any
     request count — the last bucket is zero-padded, never retraced.  With
-    the kernel path active (``REPRO_USE_PALLAS=1`` / TPU) each bucket runs
+    the kernel path active (the TPU default; ``REPRO_USE_PALLAS=1`` on
+    CPU) each bucket runs
     the schedule/fused kernels; ``--autotune`` picks block sizes via
     ``kernels/autotune.tune`` under ``--tune-policy``: ``predict`` trusts
     the analytical cost model (zero timing runs — the zoo cold-start
@@ -75,6 +76,9 @@ def serve_tm(args) -> None:
     serving exact — serving better than requested is always allowed.
     ``SERVE_HEALTH``/``GATEWAY_HEALTH`` report the quality-tier
     distribution.
+
+    Returns ``{"serve": SERVE_HEALTH, "gateway": GATEWAY_HEALTH, "preds":
+    per-request predicted class in request order (-1 = shed)}``.
     """
     import json
     import os
@@ -245,11 +249,18 @@ def serve_tm(args) -> None:
         # block-sparse tile table on the sparse path — and one (B, K)
         # class-sum psum completes the adder bank; requests shard over the
         # data axes.
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
         from repro.core import sharding as tm_sharding
         from repro.launch.mesh import parse_mesh_spec
 
         mesh = parse_mesh_spec(args.mesh)
         n_model = mesh.shape["model"]
+
+        def place(stack):
+            # each shard's stack slice lands on its own device, once
+            return jax.device_put(stack, NamedSharding(
+                mesh, P("model", *[None] * (np.ndim(stack) - 1))))
         U = compiled.n_unique
         if args.autotune:
             # ROADMAP "Next": seed the per-shard C_loc cache entries for
@@ -278,10 +289,8 @@ def serve_tm(args) -> None:
                 block_c=schedules[0].block_c, block_j=schedules[0].block_j,
                 block_s=fblocks.get("block_s"),
             )
-            terms_sh = jnp.asarray(term_stack)
-            chains = jnp.asarray(chain_stack)
-            votes_sh = jnp.asarray(votes_stack)
-            tiles = jnp.asarray(tile_stack)
+            terms_sh, chains, votes_sh, tiles = map(
+                place, (term_stack, chain_stack, votes_stack, tile_stack))
             print(f"mesh {dict(mesh.shape)}: {C_loc * n_model} unique "
                   f"clauses sharded over model={n_model} ({C_loc}/shard, "
                   f"{tile_stack.shape[-1]} tiles/shard, "
@@ -310,9 +319,8 @@ def serve_tm(args) -> None:
                 block_c=schedules[0].block_c, block_j=schedules[0].block_j,
                 block_s=sblocks.get("block_s"),
             )
-            chains = jnp.asarray(chain_stack)
-            votes_sh = jnp.asarray(votes_stack)
-            tiles = jnp.asarray(tile_stack)
+            chains, votes_sh, tiles = map(
+                place, (chain_stack, votes_stack, tile_stack))
             print(f"mesh {dict(mesh.shape)}: {C_loc * n_model} unique "
                   f"clauses sharded over model={n_model} ({C_loc}/shard, "
                   f"{tile_stack.shape[-1]} chain tiles/shard)")
@@ -326,11 +334,10 @@ def serve_tm(args) -> None:
             blocks = tuned_blocks(Up // n_model)
             # zero include words never violate -> padded clauses fire but
             # carry zero votes, so the class sums are unchanged.
-            inc_sh = jnp.asarray(np.pad(compiled.include_words,
-                                        ((0, Up - U), (0, 0))))
-            votes_sh = jnp.asarray(np.pad(compiled.votes,
-                                          ((0, Up - U), (0, 0))))
-            ne_sh = jnp.asarray(np.ones((Up,), np.uint8))
+            inc_sh = place(np.pad(compiled.include_words,
+                                  ((0, Up - U), (0, 0))))
+            votes_sh = place(np.pad(compiled.votes, ((0, Up - U), (0, 0))))
+            ne_sh = place(np.ones((Up,), np.uint8))
             fwd = tm_sharding.sharded_forward_fn(mesh, blocks=blocks or None)
             print(f"mesh {dict(mesh.shape)}: {Up} unique clauses sharded "
                   f"over model={n_model} ({Up // n_model}/shard)")
@@ -671,9 +678,10 @@ def serve_tm(args) -> None:
         raise SystemExit(
             f"gateway accounting violated: {gw_health['unaccounted']} "
             f"of {gw_health['offered']} requests unaccounted for")
-    preds = np.asarray([r.pred for r in responses if r.ok], np.int64)
-    hist = np.bincount(preds, minlength=config.n_classes)
+    preds = np.asarray([r.pred if r.ok else -1 for r in responses], np.int64)
+    hist = np.bincount(preds[preds >= 0], minlength=config.n_classes)
     print("pred class histogram:", hist.tolist())
+    return dict(serve=health, gateway=gw_health, preds=preds)
 
 
 def serve_lm(args) -> None:
@@ -716,7 +724,7 @@ def serve_lm(args) -> None:
           f"({B * n_new / t_decode:,.0f} tok/s)")
 
 
-def main() -> None:
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--arch", required=True)
     ap.add_argument("--requests", type=int, default=4096)
@@ -824,7 +832,14 @@ def main() -> None:
     ap.add_argument("--seq-len", type=int, default=64)
     ap.add_argument("--new-tokens", type=int, default=16)
     ap.add_argument("--smoke", action="store_true")
-    args = ap.parse_args()
+    return ap
+
+
+def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+
+    args = build_parser().parse_args()
+    enable_compile_cache()
     if args.arch.startswith("tm-"):
         serve_tm(args)
     else:

@@ -27,7 +27,9 @@ from repro.runtime import (RESUME_EXIT_CODE, PreemptionHandler,
                            StragglerMonitor, faults)
 
 
-def train_tm(args) -> None:
+def train_tm(args) -> dict:
+    """TM training loop; returns ``{"health": TRAIN_HEALTH, "ta": the
+    final automata}``."""
     from repro.configs.matador_tm import TM_CONFIGS
     from repro.core import tm
     from repro.kernels import ops
@@ -167,8 +169,10 @@ def train_tm(args) -> None:
         mgr.wait()
     import json as _json
 
-    print("TRAIN_HEALTH " + _json.dumps(dict(
-        steps=args.steps, resumed_from=start_step, stragglers=mon.events)))
+    health = dict(steps=args.steps, resumed_from=start_step,
+                  stragglers=mon.events)
+    print("TRAIN_HEALTH " + _json.dumps(health))
+    return dict(health=health, ta=ta)
 
 
 def train_lm(args) -> None:
@@ -223,7 +227,7 @@ def train_lm(args) -> None:
         mgr.wait()
 
 
-def main() -> None:
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--arch", required=True)
     ap.add_argument("--steps", type=int, default=100)
@@ -253,7 +257,14 @@ def main() -> None:
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--ckpt-every", type=int, default=50)
     ap.add_argument("--log-every", type=int, default=20)
-    args = ap.parse_args()
+    return ap
+
+
+def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+
+    args = build_parser().parse_args()
+    enable_compile_cache()
     if args.arch.startswith("tm-"):
         train_tm(args)
     else:

@@ -22,7 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro import jax_compat
 from repro.models import layers
 from repro.models.config import ModelConfig
 
@@ -86,7 +85,7 @@ def _moe_local(cfg: ModelConfig, params: dict, x: jax.Array, axis: Optional[str]
     if axis is None:
         gates_loc = gates
     else:
-        n_shards = jax_compat.axis_size(axis)
+        n_shards = jax.lax.axis_size(axis)
         e_loc = cfg.n_experts // n_shards
         e0 = jax.lax.axis_index(axis) * e_loc
         gates_loc = jax.lax.dynamic_slice_in_dim(gates, e0, e_loc, axis=1)
@@ -108,7 +107,7 @@ def moe_ff(
 ) -> jax.Array:
     """(B, S, d) -> (B, S, d) MoE feed-forward (+ shared experts)."""
     if mesh is not None and "model" in mesh.axis_names:
-        routed = jax_compat.shard_map(
+        routed = jax.shard_map(
             lambda p, xx: _moe_local(cfg, p, xx, "model"),
             mesh=mesh,
             in_specs=(

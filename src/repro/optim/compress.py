@@ -15,7 +15,6 @@ from typing import Any, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro import jax_compat
 
 
 def quantize_psum(g: jax.Array, err: jax.Array, axes) -> Tuple[jax.Array, jax.Array]:
@@ -28,7 +27,7 @@ def quantize_psum(g: jax.Array, err: jax.Array, axes) -> Tuple[jax.Array, jax.Ar
     new_err = g - q * scale                       # local residual, carried
     n = 1
     for a in (axes if isinstance(axes, tuple) else (axes,)):
-        n = n * jax_compat.axis_size(a)
+        n = n * jax.lax.axis_size(a)
     summed = jax.lax.psum(q.astype(jnp.int32), axes)
     return (summed.astype(jnp.float32) * scale) / n, new_err
 

@@ -13,6 +13,7 @@ import sys
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 from repro.core import compiler, tm
@@ -90,7 +91,6 @@ def test_hlo_and_roofline_smoke():
     """launch/hlo_analysis + launch/roofline drive the feature pipeline on
     the pinned jax — an import-and-run smoke so version drift fails here,
     not deep inside a tuning run."""
-    from repro import jax_compat
     from repro.launch import hlo_analysis, roofline  # noqa: F401
 
     feats = cost_model.hlo_forward_features(16, 2, 3, batch=8)
@@ -101,12 +101,11 @@ def test_hlo_and_roofline_smoke():
     def f(a, b):
         return a @ b
 
-    compiled = jax_compat.lower_compiled(
-        f, jnp.ones((4, 4), jnp.float32), jnp.ones((4, 4), jnp.float32))
+    compiled = jax.jit(f).lower(
+        jnp.ones((4, 4), jnp.float32), jnp.ones((4, 4), jnp.float32)).compile()
     cost = hlo_analysis.analyze(compiled.as_text())
     assert cost.flops > 0
-    ca = jax_compat.cost_analysis(compiled)
-    assert ca is None or isinstance(ca, dict)
+    assert isinstance(compiled.cost_analysis(), dict)
 
 
 # ---------------------------------------------------------------------------

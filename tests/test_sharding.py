@@ -127,8 +127,7 @@ def f(g, e):
     out, ne = compress.quantize_psum(g[0], e[0], "data")
     return out[None], ne[None]
 
-from repro import jax_compat
-out, err = jax.jit(jax_compat.shard_map(f, mesh=mesh, in_specs=(P("data"), P("data")),
+out, err = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=(P("data"), P("data")),
                    out_specs=(P("data"), P("data")), check_vma=False))(
     g_all, jnp.zeros_like(g_all))
 exact = np.asarray(g_all).mean(0)

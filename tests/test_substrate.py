@@ -65,11 +65,9 @@ def test_compression_error_feedback_roundtrip():
 
     from jax.sharding import PartitionSpec as P
 
-    from repro import jax_compat
-
     out, new_err = jax.jit(
-        jax_compat.shard_map(f, mesh=mesh, in_specs=(P(), P()),
-                             out_specs=(P(), P()), check_vma=False)
+        jax.shard_map(f, mesh=mesh, in_specs=(P(), P()),
+                      out_specs=(P(), P()), check_vma=False)
     )(g, err)
     # quantized value + residual reconstructs the original exactly
     np.testing.assert_allclose(
