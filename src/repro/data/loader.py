@@ -13,6 +13,7 @@ import threading
 from typing import Iterator, Optional
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 
 class ShardedBatcher:
@@ -106,7 +107,8 @@ class ShardedBatcher:
         t.start()
         try:
             while True:
-                state, b = q.get()
+                with TraceAnnotation("repro.data.wait"):   # for the prefetch
+                    state, b = q.get()
                 self._consumed = state
                 yield b
         finally:

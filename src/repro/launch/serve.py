@@ -21,6 +21,41 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
+
+
+def make_runner(ladder, bucket: int, width: int, dtype, *, on_bucket=None):
+    """The runner of one gateway bucket: ``run_rows(rows, quality=0)``.
+
+    It zero-pads ``rows`` to the one jit shape ``(bucket, width)`` (a
+    partial age or drain flush never retraces), runs ``ladder`` (an
+    ``ops.EngineLadder``) on it, copies the answers out, and returns
+    ``(preds, info)``, ``info`` holding the quality the ladder served.
+    Spans ``repro.runner.pad`` and ``repro.runner.copy_out`` time the host
+    work here; the ladder adds ``copy_in``, ``dispatch`` and ``wait``.
+
+    ``on_bucket(i)``, when given, is called before bucket ``i`` runs and
+    returns a callable that takes the bucket's ``info`` once its answers
+    are out and returns the ``info`` to answer with.  The serve loop's
+    straggler deadline, fault drill and latency watch live there, so a
+    runner built without it runs none of them.
+    """
+    count = itertools.count()
+
+    def run_rows(rows, quality=0):
+        i = next(count)
+        after = on_bucket(i) if on_bucket is not None else None
+        with TraceAnnotation("repro.runner.pad"):
+            padded = np.zeros((bucket, width), dtype)
+            padded[:len(rows)] = rows
+        out = ladder.run(lambda: jnp.asarray(padded), bucket=i,
+                         quality=quality)
+        with TraceAnnotation("repro.runner.copy_out"):
+            preds = np.asarray(out)[:len(rows)]
+        info = dict(quality=ladder.last_quality, err_bound=None)
+        return preds, (info if after is None else after(info))
+
+    return run_rows
 
 
 def serve_tm(args) -> dict:
@@ -446,43 +481,40 @@ def serve_tm(args) -> dict:
     # stream starts on an engine that actually runs
     ladder.run(lambda: jnp.asarray(xp[:bucket]), bucket="warm", count=False)
 
-    bucket_i = itertools.count()
     online_hooks = {"latency": None}   # filled when --online wires the updater
 
-    def run_rows(rows, quality=0):
-        # one gateway bucket: zero-pad to the fixed jit trace shape (a
-        # partial age/drain flush never retraces), run the engine ladder,
-        # and keep the straggler/deadline accounting of the old sync loop.
-        # ``quality`` is the brownout controller's level; only engines
-        # that opt in (supports_quality) ever degrade, and the returned
-        # info records what was ACTUALLY served plus its error bound.
-        i = next(bucket_i)
+    def on_bucket(i):
+        # the straggler/deadline accounting of the old sync loop around
+        # one gateway bucket.  ``quality`` is the brownout controller's
+        # level; only engines that opt in (supports_quality) ever degrade,
+        # and the info records what was ACTUALLY served plus its bound.
         t_b = time.perf_counter()
         mon.start_step()
         faults.sleep_if("serve.slow_bucket", step=i)    # deadline drill site
-        padded = np.zeros((bucket, W), xp.dtype)
-        padded[:len(rows)] = rows
-        out = ladder.run(lambda: jnp.asarray(padded), bucket=i,
-                         quality=quality)
-        preds = np.asarray(out)[:len(rows)]
-        q = ladder.last_quality
-        quality_served[q] = quality_served.get(q, 0) + 1
-        info = dict(quality=q,
-                    err_bound=quality_bounds.get(
-                        ladder.engine, {}).get(q) if q else None)
-        flag = mon.end_step(i)
-        # an engine's FIRST bucket pays its jit trace — exempting it from
-        # the deadline stops one slow bucket cascading down the ladder
-        if flag and args.bucket_deadline and ladder.counts[ladder.engine] > 1:
-            ladder.demote(
-                f"bucket deadline: {flag['seconds'] * 1e3:.1f} ms > "
-                f"{args.bucket_deadline:g}x EWMA {flag['ewma'] * 1e3:.1f} ms",
-                bucket=i)
-        if online_hooks["latency"] is not None:
-            # post-swap latency watch: a promoted artifact that blows up
-            # bucket wall-time gets rolled back by the updater
-            online_hooks["latency"](time.perf_counter() - t_b)
-        return preds, info
+
+        def after(info):
+            q = info["quality"]
+            quality_served[q] = quality_served.get(q, 0) + 1
+            info["err_bound"] = (quality_bounds.get(ladder.engine, {}).get(q)
+                                 if q else None)
+            flag = mon.end_step(i)
+            # an engine's FIRST bucket pays its jit trace — exempting it
+            # from the deadline stops one slow bucket cascading down
+            if (flag and args.bucket_deadline
+                    and ladder.counts[ladder.engine] > 1):
+                ladder.demote(
+                    f"bucket deadline: {flag['seconds'] * 1e3:.1f} ms > "
+                    f"{args.bucket_deadline:g}x EWMA "
+                    f"{flag['ewma'] * 1e3:.1f} ms", bucket=i)
+            if online_hooks["latency"] is not None:
+                # post-swap latency watch: a promoted artifact that blows
+                # up bucket wall-time gets rolled back by the updater
+                online_hooks["latency"](time.perf_counter() - t_b)
+            return info
+
+        return after
+
+    run_rows = make_runner(ladder, bucket, W, xp.dtype, on_bucket=on_bucket)
 
     zoo = None
     updater = None

@@ -68,6 +68,17 @@ Execution is serialized through a single worker thread: the engines are
 jit'd callables whose per-bucket wall-time is the unit of straggler/
 deadline attribution, and the event loop stays free to admit, age-flush,
 and shed while a bucket is on the accelerator.
+
+Instrumentation, per bucket or per wait of the dispatcher and never per
+request: profiler spans ``repro.gateway.wait`` (the dispatcher waiting
+for a wake or an age deadline: the designed batching wait),
+``repro.gateway.flush`` (pop and brownout decision of a bucket) and
+``repro.gateway.resolve`` (answering its requests), all on the event
+loop; and two cumulative counters in seconds beside ``buckets``, so
+that a window's value is its end minus its start: ``flush_delay_s`` (a
+bucket ready, by the offer that filled it or its oldest request's age
+deadline, until the dispatcher takes it) and ``handoff_s`` (the bucket's
+two crossings between the event loop and the worker thread).
 """
 
 from __future__ import annotations
@@ -81,6 +92,7 @@ from concurrent.futures import ThreadPoolExecutor
 from typing import Callable, Dict, List, Optional
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.runtime import faults
 
@@ -269,6 +281,8 @@ class Gateway:
         self.shed: Dict[str, int] = {}
         self.buckets = 0
         self.flushes = {"full": 0, "age": 0, "drain": 0}
+        self.flush_delay_s = 0.0    # ready -> taken by the dispatcher
+        self.handoff_s = 0.0        # event loop <-> worker, both ways
         self.tenants: Dict[str, dict] = {}
         self._latencies: List[float] = []
 
@@ -416,6 +430,15 @@ class Gateway:
                 self.mirror_failures += 1
         return preds, info
 
+    def _run_stamped(self, stamps: list, *args):
+        """:meth:`_run_bucket` on the worker, with the gateway clock's
+        readings on entry and on exit put in ``stamps``."""
+        stamps[0] = self._clock()
+        try:
+            return self._run_bucket(*args)
+        finally:
+            stamps[1] = self._clock()
+
     async def _dispatch_loop(self) -> None:
         loop = asyncio.get_running_loop()
         while True:
@@ -427,41 +450,66 @@ class Gateway:
                     self._idle.set()
                 self._wake.clear()
                 timeout = None if cause is None else max(cause - now, 0.0)
-                try:
-                    await asyncio.wait_for(self._wake.wait(), timeout)
-                except asyncio.TimeoutError:
-                    pass
+                with TraceAnnotation("repro.gateway.wait"):
+                    try:
+                        await asyncio.wait_for(self._wake.wait(), timeout)
+                    except asyncio.TimeoutError:
+                        pass
                 continue
-            q = self._queues[tenant]
-            reqs = [q.popleft() for _ in range(min(self.bucket, len(q)))]
-            self._pending -= len(reqs)
-            self._inflight += len(reqs)
-            self.flushes[cause] += 1
-            self.buckets += 1
-            quality = self._brownout_level(reqs, now)
+            with TraceAnnotation("repro.gateway.flush"):
+                q = self._queues[tenant]
+                reqs = [q.popleft() for _ in range(min(self.bucket, len(q)))]
+                self._pending -= len(reqs)
+                self._inflight += len(reqs)
+                self.flushes[cause] += 1
+                self.buckets += 1
+                # ready: the offer that filled the bucket, or its oldest
+                # request's age deadline; a drain takes what is left at once
+                if cause == "full":
+                    ready = reqs[-1].t_submit
+                elif cause == "age":
+                    ready = reqs[0].t_submit + self.max_wait
+                else:
+                    ready = now
+                self.flush_delay_s += max(now - ready, 0.0)
+                quality = self._brownout_level(reqs, now)
+                rows = [r.x for r in reqs]
+                stamps = [None, None]
+                t_sub = self._clock()
             try:
                 preds, info = await loop.run_in_executor(
-                    self._pool, self._run_bucket, tenant,
-                    [r.x for r in reqs], quality)
+                    self._pool, self._run_stamped, stamps, tenant, rows,
+                    quality)
             except Exception as e:  # noqa: BLE001 — typed bucket rejection
                 reason = getattr(e, "shed_reason", ENGINE_FAILED)
                 end = self._clock()
-                for r in reqs:
-                    self._resolve(r, Response(
-                        tenant=tenant, ok=False, reason=reason,
-                        latency_s=end - r.t_submit))
+                self._add_handoff(t_sub, stamps, end)
+                with TraceAnnotation("repro.gateway.resolve"):
+                    for r in reqs:
+                        self._resolve(r, Response(
+                            tenant=tenant, ok=False, reason=reason,
+                            latency_s=end - r.t_submit))
             else:
                 preds = np.asarray(preds)
                 end = self._clock()
+                self._add_handoff(t_sub, stamps, end)
                 served_q = info["quality"]
                 bound = info["err_bound"] if served_q else None
-                for i, r in enumerate(reqs):
-                    self._resolve(r, Response(
-                        tenant=tenant, ok=True, pred=int(preds[i]),
-                        latency_s=end - r.t_submit,
-                        quality=served_q, err_bound=bound))
+                with TraceAnnotation("repro.gateway.resolve"):
+                    for i, r in enumerate(reqs):
+                        self._resolve(r, Response(
+                            tenant=tenant, ok=True, pred=int(preds[i]),
+                            latency_s=end - r.t_submit,
+                            quality=served_q, err_bound=bound))
             finally:
                 self._inflight -= len(reqs)
+
+    def _add_handoff(self, t_sub: float, stamps, end: float) -> None:
+        """Add a bucket's two thread crossings: submission to the worker's
+        start, and the worker's return to the dispatcher resuming."""
+        t_in, t_out = stamps
+        if t_in is not None:
+            self.handoff_s += max(t_in - t_sub, 0.0) + max(end - t_out, 0.0)
 
     def _brownout_level(self, reqs, now: float) -> int:
         """Quality level for the bucket about to run (0 when disabled)."""
@@ -544,6 +592,7 @@ class Gateway:
                          - self.answered_degraded - shed_total),
             buckets=self.buckets, bucket_size=self.bucket,
             flushes=dict(self.flushes),
+            flush_delay_s=self.flush_delay_s, handoff_s=self.handoff_s,
             queue_depth=self._pending, draining=self._draining,
             mirrored=self.mirrored, mirror_failures=self.mirror_failures,
             latency_ms=dict(p50=pct(50), p99=pct(99)),
