@@ -15,6 +15,7 @@ import contextlib
 import dataclasses
 import functools
 import os
+import threading
 
 import jax
 import jax.numpy as jnp
@@ -549,13 +550,6 @@ def tm_forward_factorized(
 # Kernel-path TM training step (hash-RNG; matches ref.py bit-for-bit)
 # ---------------------------------------------------------------------------
 
-def _span_if(on: bool):
-    """``span(name)``: a profiler span when ``on``, else a no-op context."""
-    if on:
-        return TraceAnnotation
-    return lambda name: contextlib.nullcontext()
-
-
 def feedback_probs(
     sums: jax.Array,       # (B, K) int32 CLAMPED class sums
     y: jax.Array,          # (B,) int32 targets (-1 = padded/invalid sample)
@@ -658,6 +652,24 @@ def feedback_plan(
     return ftype, sums
 
 
+_step_calls_lock = threading.Lock()
+_step_calls = {"compiled": 0, "inlined": 0}
+
+
+def train_step_counts() -> dict:
+    """Process-wide count of training steps since import: ``compiled``,
+    eager calls run as the one cached program :func:`tm_train_step`;
+    ``inlined``, step bodies traced into a caller's own ``jit`` or
+    ``shard_map`` (once per trace, not per call of the caller's program)."""
+    with _step_calls_lock:
+        return dict(_step_calls)
+
+
+def _frozen(d: dict | None) -> tuple:
+    """A dict of static arguments as a hashable jit cache key."""
+    return tuple(sorted((d or {}).items()))
+
+
 def tm_train_step_kernel(
     config,
     ta_state: jax.Array,     # (C, L) int8 — the full bank OR a clause shard
@@ -686,6 +698,14 @@ def tm_train_step_kernel(
     three-dispatch pipeline; off the kernel path the ``ref.py`` oracles run.
     All engines are bit-identical.
 
+    An eager call (concrete arrays) runs the step as ONE compiled program,
+    :func:`tm_train_step` under a cached ``jax.jit``: the configuration,
+    tilings and engine switches are static, the bank, batch, seed and
+    offsets traced, so a new seed or batch of the same shape never
+    retraces.  ``ta_state`` is not donated: the caller keeps the pre-step
+    bank.  Under a caller's trace (``jit``, ``shard_map``) the body is
+    inlined into the caller's program instead.
+
     ``batch_chunk`` scans the batch in slices, accumulating the int32 delta —
     bit-identical to unchunked (the hash RNG is indexed by global sample id)
     but with O(chunk) working set instead of O(batch).  A ragged tail
@@ -710,144 +730,161 @@ def tm_train_step_kernel(
     LOCAL batch's delta — a data-sharded caller must ``psum`` the returned
     delta over its data axes and apply it to the shard itself.
     """
+    args = (ta_state, x, y, seed, b_offset, c_offset)
+    traced = any(isinstance(a, jax.core.Tracer) for a in args)
+    # Spans time the eager call only: under a caller's trace the body runs
+    # once, while tracing, and a span there would time the trace.
+    with (contextlib.nullcontext() if traced
+          else TraceAnnotation("repro.train.step")):
+        use_kernel, interpret = _resolve(kw.pop("use_kernel", None),
+                                         kw.pop("interpret", None))
+        fused = bool(fuse and use_kernel)
+        infer_blocks = {}
+        if fused and autotune:   # timed eagerly, outside the step's own jit
+            from repro.core import packetizer
+            from repro.kernels import autotune as _autotune
+
+            B = x.shape[0]
+            chunk_b = batch_chunk if (batch_chunk and B > batch_chunk) else B
+            C_tot, L = ta_state.shape
+            W = packetizer.n_words(config.n_literals)
+            if blocks is None:
+                blocks = _autotune.autotune_fused_train_blocks(
+                    chunk_b, C_tot, W, L, config.n_classes,
+                    interpret=interpret)
+            infer_blocks = _autotune.autotune_fused_blocks(
+                chunk_b, C_tot, W, config.n_classes, interpret=interpret)
+        static = dict(
+            config=config, batch_chunk=batch_chunk, fused=fused,
+            blocks=_frozen(blocks), infer_blocks=_frozen(infer_blocks),
+            c_total=c_total, sums_reduce=sums_reduce,
+            engine=_frozen(dict(kw, use_kernel=use_kernel,
+                                interpret=interpret)))
+        with _step_calls_lock:
+            _step_calls["inlined" if traced else "compiled"] += 1
+        if traced:
+            return tm_train_step(*args, **static)
+        with TraceAnnotation("repro.train.dispatch"):
+            return _compiled_train_step(*args, **static)
+
+
+def tm_train_step(ta_state, x, y, seed, b_offset, c_offset, *, config,
+                  batch_chunk, fused, blocks, infer_blocks, c_total,
+                  sums_reduce, engine):
+    """The body of :func:`tm_train_step_kernel`, always traced: inlined
+    into a caller's program, or compiled alone as the eager step.
+    ``blocks``/``infer_blocks`` are the fused kernels' tilings and
+    ``engine`` the resolved ``use_kernel``/``interpret`` with the unfused
+    kernels' tilings, each as sorted items."""
     from repro.core import packetizer, tm
 
-    # Spans time the eager body only.  Under ``jit`` or ``shard_map``
-    # (core/train.py:online_step, core/sharding.py) the body runs once,
-    # while tracing, and a span there would time the trace, not the step;
-    # so they open only when the inputs are concrete arrays.
-    span = _span_if(not any(isinstance(a, jax.core.Tracer)
-                            for a in (ta_state, x, y, seed)))
-    with span("repro.train.step"):
-        with span("repro.train.prep"):   # include masks, votes, metadata
-            use_kernel, interpret = _resolve(kw.get("use_kernel"),
-                                             kw.get("interpret"))
-            fused = bool(fuse and use_kernel)
-            inc_words = packetizer.pack_include_masks(ta_state)
-            C_loc = ta_state.shape[0]
-            votes = tm.vote_matrix(config)
-            c = jnp.arange(config.n_clauses_total)
-            clause_class = jnp.clip(c // config.clauses_per_class, 0,
-                                    config.n_classes - 1)
-            pol = tm.polarity(config)
-            if c_total is not None:   # clause shard: local slices of the metadata
-                assert c_total == config.n_clauses_total, (c_total, config)
-                votes = jax.lax.dynamic_slice_in_dim(votes, c_offset, C_loc, 0)
-                clause_class = jax.lax.dynamic_slice_in_dim(
-                    clause_class, c_offset, C_loc, 0)
-                pol = jax.lax.dynamic_slice_in_dim(pol, c_offset, C_loc, 0)
-            p_act = (1.0 if config.boost_true_positive
-                     else (config.s - 1.0) / config.s)
-            T = config.threshold
-            B = x.shape[0]
-            b_base = jnp.asarray(b_offset).astype(jnp.uint32)
+    kw = dict(engine)
+    interpret = kw["interpret"]
+    inc_words = packetizer.pack_include_masks(ta_state)
+    C_loc = ta_state.shape[0]
+    votes = tm.vote_matrix(config)
+    c = jnp.arange(config.n_clauses_total)
+    clause_class = jnp.clip(c // config.clauses_per_class, 0,
+                            config.n_classes - 1)
+    pol = tm.polarity(config)
+    if c_total is not None:   # clause shard: local slices of the metadata
+        assert c_total == config.n_clauses_total, (c_total, config)
+        votes = jax.lax.dynamic_slice_in_dim(votes, c_offset, C_loc, 0)
+        clause_class = jax.lax.dynamic_slice_in_dim(
+            clause_class, c_offset, C_loc, 0)
+        pol = jax.lax.dynamic_slice_in_dim(pol, c_offset, C_loc, 0)
+    p_act = (1.0 if config.boost_true_positive
+             else (config.s - 1.0) / config.s)
+    T = config.threshold
+    B = x.shape[0]
+    b_base = jnp.asarray(b_offset).astype(jnp.uint32)
 
-            infer_blocks = {}
-            if fused and autotune:
-                from repro.kernels import autotune as _autotune
-
-                chunk_b = batch_chunk if (batch_chunk and B > batch_chunk) else B
-                C_tot, L = ta_state.shape
-                W = packetizer.n_words(config.n_literals)
-                if blocks is None:
-                    blocks = _autotune.autotune_fused_train_blocks(
-                        chunk_b, C_tot, W, L, config.n_classes,
-                        interpret=interpret)
-                infer_blocks = _autotune.autotune_fused_blocks(
-                    chunk_b, C_tot, W, config.n_classes, interpret=interpret)
-
-        chunked = bool(batch_chunk and B > batch_chunk)
-        # a chunk's body runs traced under ``lax.scan``: no spans inside it
-        phase = _span_if(False) if chunked else span
-
-        def chunk_delta(xc, yc, b_off, valid):
-            lits = tm.literals(xc)
-            lit_words = packetizer.pack_bits(lits)
-            if fused:
-                with phase("repro.train.class_sums"):
-                    # launch 1: class sums via the fused-inference
-                    # accumulator (training semantics: no nonempty mask) —
-                    # bit-identical ints to fire @ votes.  On a clause shard
-                    # these are PARTIAL sums over the local bank;
-                    # ``sums_reduce`` (a psum over the clause-shard axis)
-                    # completes them exactly (int32 addition).
-                    sums = _fused_infer_kernel.fused_tm_forward(
-                        lit_words, inc_words, votes, None,
-                        interpret=interpret, **infer_blocks,
-                    )
-                    if sums_reduce is not None:
-                        sums = sums_reduce(sums)
-                with phase("repro.train.plan"):
-                    kn, p_t, p_n = feedback_probs(
-                        jnp.clip(sums, -T, T), yc, config.n_classes, T, seed,
-                        b_offset=b_off,
-                    )
-                    if valid is not None:   # padded tail samples select nothing
-                        p_t = jnp.where(valid, p_t, 0.0)
-                        p_n = jnp.where(valid, p_n, 0.0)
-                with phase("repro.train.delta"):
-                    # launch 2: fire -> ftype -> delta, all in VMEM
-                    return _fused_train_kernel.fused_tm_train_delta(
-                        ta_state, lits, lit_words, inc_words, yc, kn, p_t,
-                        p_n, clause_class, pol, seed,
-                        p_act=p_act, p_inact=1.0 / config.s, b_offset=b_off,
-                        c_offset=c_offset, c_total=c_total,
-                        interpret=interpret, **(blocks or {}),
-                    )
-            with phase("repro.train.fire"):
-                fire = clause_fire(lit_words, inc_words, **kw).astype(jnp.uint8)
-                sums = None
-                if sums_reduce is not None:   # clause shard: complete partials
-                    sums = jnp.clip(
-                        sums_reduce(fire.astype(jnp.int32) @ votes), -T, T
-                    )
-            with phase("repro.train.plan"):
-                ftype, _ = feedback_plan(
-                    fire, yc, votes, clause_class, pol, T, seed, b_offset=b_off,
-                    c_offset=c_offset, sums=sums,
-                )
-                if valid is not None:
-                    ftype = jnp.where(valid[:, None], ftype, jnp.uint8(0))
-            with phase("repro.train.delta"):
-                return ta_delta(
-                    ta_state, lits, fire, ftype, seed,
-                    p_act=p_act, p_inact=1.0 / config.s, b_offset=b_off,
-                    c_offset=c_offset, c_total=c_total, **kw,
-                )
-
-        if chunked:
-            n = -(-B // batch_chunk)
-            Bp = n * batch_chunk
-            xs, ys = x, y
-            if Bp != B:   # ragged tail: zero-pad samples, mask their feedback
-                xs = jnp.pad(x, ((0, Bp - B), (0, 0)))
-                ys = jnp.pad(y, (0, Bp - B), constant_values=-1)
-            xs = xs.reshape(n, batch_chunk, *x.shape[1:])
-            ys = ys.reshape(n, batch_chunk)
-            need_mask = Bp != B
-
-            def body(acc, inp):
-                i, xc, yc = inp
-                local_off = i * jnp.uint32(batch_chunk)
-                valid = (
-                    (jnp.arange(batch_chunk, dtype=jnp.uint32) + local_off)
-                    < jnp.uint32(B)
-                ) if need_mask else None
-                return acc + chunk_delta(xc, yc, b_base + local_off, valid), None
-
-            delta, _ = jax.lax.scan(
-                body,
-                jnp.zeros(ta_state.shape, jnp.int32),
-                (jnp.arange(n, dtype=jnp.uint32), xs, ys),
+    def chunk_delta(xc, yc, b_off, valid):
+        lits = tm.literals(xc)
+        lit_words = packetizer.pack_bits(lits)
+        if fused:
+            # launch 1: class sums via the fused-inference accumulator
+            # (training semantics: no nonempty mask) — bit-identical ints
+            # to fire @ votes.  On a clause shard these are PARTIAL sums
+            # over the local bank; ``sums_reduce`` (a psum over the
+            # clause-shard axis) completes them exactly (int32 addition).
+            sums = _fused_infer_kernel.fused_tm_forward(
+                lit_words, inc_words, votes, None,
+                interpret=interpret, **dict(infer_blocks),
             )
-        else:
-            delta = chunk_delta(x, y, b_base, None)
-        with span("repro.train.apply"):
-            new_ta = jnp.clip(
-                ta_state.astype(jnp.int32) + delta,
-                -config.n_states, config.n_states - 1,
-            ).astype(jnp.int8)
+            if sums_reduce is not None:
+                sums = sums_reduce(sums)
+            kn, p_t, p_n = feedback_probs(
+                jnp.clip(sums, -T, T), yc, config.n_classes, T, seed,
+                b_offset=b_off,
+            )
+            if valid is not None:   # padded tail samples select nothing
+                p_t = jnp.where(valid, p_t, 0.0)
+                p_n = jnp.where(valid, p_n, 0.0)
+            # launch 2: fire -> ftype -> delta, all in VMEM
+            return _fused_train_kernel.fused_tm_train_delta(
+                ta_state, lits, lit_words, inc_words, yc, kn, p_t,
+                p_n, clause_class, pol, seed,
+                p_act=p_act, p_inact=1.0 / config.s, b_offset=b_off,
+                c_offset=c_offset, c_total=c_total,
+                interpret=interpret, **dict(blocks),
+            )
+        fire = clause_fire(lit_words, inc_words, **kw).astype(jnp.uint8)
+        sums = None
+        if sums_reduce is not None:   # clause shard: complete partials
+            sums = jnp.clip(
+                sums_reduce(fire.astype(jnp.int32) @ votes), -T, T
+            )
+        ftype, _ = feedback_plan(
+            fire, yc, votes, clause_class, pol, T, seed, b_offset=b_off,
+            c_offset=c_offset, sums=sums,
+        )
+        if valid is not None:
+            ftype = jnp.where(valid[:, None], ftype, jnp.uint8(0))
+        return ta_delta(
+            ta_state, lits, fire, ftype, seed,
+            p_act=p_act, p_inact=1.0 / config.s, b_offset=b_off,
+            c_offset=c_offset, c_total=c_total, **kw,
+        )
+
+    if batch_chunk and B > batch_chunk:
+        n = -(-B // batch_chunk)
+        Bp = n * batch_chunk
+        xs, ys = x, y
+        if Bp != B:   # ragged tail: zero-pad samples, mask their feedback
+            xs = jnp.pad(x, ((0, Bp - B), (0, 0)))
+            ys = jnp.pad(y, (0, Bp - B), constant_values=-1)
+        xs = xs.reshape(n, batch_chunk, *x.shape[1:])
+        ys = ys.reshape(n, batch_chunk)
+        need_mask = Bp != B
+
+        def body(acc, inp):
+            i, xc, yc = inp
+            local_off = i * jnp.uint32(batch_chunk)
+            valid = (
+                (jnp.arange(batch_chunk, dtype=jnp.uint32) + local_off)
+                < jnp.uint32(B)
+            ) if need_mask else None
+            return acc + chunk_delta(xc, yc, b_base + local_off, valid), None
+
+        delta, _ = jax.lax.scan(
+            body,
+            jnp.zeros(ta_state.shape, jnp.int32),
+            (jnp.arange(n, dtype=jnp.uint32), xs, ys),
+        )
+    else:
+        delta = chunk_delta(x, y, b_base, None)
+    new_ta = jnp.clip(
+        ta_state.astype(jnp.int32) + delta,
+        -config.n_states, config.n_states - 1,
+    ).astype(jnp.int8)
     return new_ta, delta
+
+
+_compiled_train_step = jax.jit(
+    tm_train_step,
+    static_argnames=("config", "batch_chunk", "fused", "blocks",
+                     "infer_blocks", "c_total", "sums_reduce", "engine"))
 
 
 # ---------------------------------------------------------------------------

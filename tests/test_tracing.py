@@ -26,12 +26,9 @@ from repro.runtime.gateway import Gateway
 RUNNER = ("repro.runner.pad", "repro.runner.copy_in",
           "repro.runner.dispatch", "repro.runner.wait",
           "repro.runner.copy_out")
-# the children of ``repro.train.step``: the fused kernel path launches the
-# class sums first, the unfused path the clause outputs
-TRAIN = {True: ("repro.train.prep", "repro.train.class_sums",
-                "repro.train.plan", "repro.train.delta", "repro.train.apply"),
-         False: ("repro.train.prep", "repro.train.fire", "repro.train.plan",
-                 "repro.train.delta", "repro.train.apply")}
+# the children of ``repro.train.step``: an eager step, on the fused kernel
+# path and the oracle path alike, is one compiled program dispatched whole
+TRAIN = {True: ("repro.train.dispatch",), False: ("repro.train.dispatch",)}
 CONFIG = tm.TMConfig(n_features=32, n_classes=3, clauses_per_class=8)
 BUCKET = 32
 
@@ -159,6 +156,7 @@ def test_train_spans_nest_inside_the_eager_step(tmp_path, kernel):
     xb, yb = next(feed)
     ops.tm_train_step_kernel(CONFIG, ta, jnp.asarray(xb), jnp.asarray(yb),
                              jnp.uint32(1), use_kernel=kernel)   # compile
+    calls = ops.train_step_counts()
     jax.profiler.start_trace(str(tmp_path))
     for s in range(2):
         xb, yb = next(feed)
@@ -167,6 +165,8 @@ def test_train_spans_nest_inside_the_eager_step(tmp_path, kernel):
                                          use_kernel=kernel)
     jax.block_until_ready(ta)
     jax.profiler.stop_trace()
+    assert ops.train_step_counts() == dict(
+        compiled=calls["compiled"] + 2, inlined=calls["inlined"])
     spans = _spans(tmp_path)
     steps = [s for s in spans if s[0] == "repro.train.step"]
     assert len(steps) == 2
