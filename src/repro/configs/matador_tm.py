@@ -6,7 +6,7 @@ binarized images, KWS6 377-bit MFCC booleans, CIFAR-2 1024-bit.
 axis (padded clauses are permanently empty and vote 0 — DESIGN.md §4).
 """
 
-from repro.core.tm import TMConfig
+from repro.core.tm import ConvTMConfig, TMConfig
 
 TM_MNIST = TMConfig(n_features=784, n_classes=10, clauses_per_class=200,
                     threshold=50, s=10.0, clause_pad_multiple=256)
@@ -30,8 +30,15 @@ TM_EDGE_XL = TMConfig(n_features=4096, n_classes=32, clauses_per_class=2048,
 TM_TINY = TMConfig(n_features=32, n_classes=3, clauses_per_class=8,
                    threshold=8, s=4.0)
 
+# The convolutional coalesced TM of the 65-nm accelerator (Tunheim et al.,
+# arXiv:2501.19347): 28x28 booleanized images, a 10x10 window at stride 1
+# (19 x 19 = 361 positions, 136 features / 272 literals a patch), 128
+# clauses shared by 10 classes with signed int8 weights.
+CONVCOTM_MNIST = ConvTMConfig(image_h=28, image_w=28, window=10,
+                              n_clauses=128, n_classes=10)
+
 TM_CONFIGS = {
     "tm-mnist": TM_MNIST, "tm-kmnist": TM_KMNIST, "tm-fmnist": TM_FMNIST,
     "tm-cifar2": TM_CIFAR2, "tm-kws6": TM_KWS6, "tm-edge-xl": TM_EDGE_XL,
-    "tm-tiny": TM_TINY,
+    "tm-tiny": TM_TINY, "convcotm-mnist": CONVCOTM_MNIST,
 }

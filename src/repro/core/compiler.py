@@ -52,6 +52,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import packetizer, tm
+from repro.kernels.conv_infer import Geometry
 from repro.runtime import faults
 
 # kernel-path default: serve the factorized (two-level) schedule when at
@@ -134,6 +135,12 @@ class CompileStats:
     # on FPGA the synthesis absorbs these; we quantify the opportunity)
     n_partial_terms_dense: int = 0
     n_partial_terms_unique: int = 0
+    # convolutional artifacts: patch positions, literals per patch, and the
+    # range of the served (clause, class) weights after dedup
+    n_positions: int = 0
+    n_patch_literals: int = 0
+    weight_min: int = 0
+    weight_max: int = 0
 
     @property
     def include_sparsity(self) -> float:
@@ -242,6 +249,12 @@ class CompiledTM:
     n_features: int
     n_classes: int
     stats: CompileStats
+    # a convolutional (ConvCoTM) artifact: its patches' geometry.  Its
+    # include rows are over one patch's literals, its votes the summed
+    # signed weights of each unique clause; None for a vanilla TM
+    geometry: Optional[Geometry] = None
+    _conv_ops: Optional[tuple] = dataclasses.field(default=None,
+                                                   repr=False)
     _schedules: dict = dataclasses.field(default_factory=dict, repr=False)
     _fschedules: dict = dataclasses.field(default_factory=dict, repr=False)
     # anytime-inference metadata (kernels/anytime.py): per-tile-prefix
@@ -264,6 +277,17 @@ class CompiledTM:
     @property
     def n_unique(self) -> int:
         return self.include_words.shape[0]
+
+    def conv_operands(self):
+        """The convolutional kernel's banked operands (``conv_infer.
+        conv_operands``), built once per artifact."""
+        from repro.kernels import conv_infer
+
+        if self._conv_ops is None:
+            g = self.geometry
+            inc = packetizer.unpack_bits_np(self.include_words, g.literals)
+            self._conv_ops = conv_infer.conv_operands(inc, self.votes, g)
+        return self._conv_ops
 
     @property
     def n_words_active(self) -> int:
@@ -458,6 +482,16 @@ class CompiledTM:
         Returns the final path (``.npz`` is appended when missing, the
         same normalization ``np.savez`` applies).
         """
+        if self.geometry is not None:
+            # a convolutional artifact runs no schedule: its bank is all
+            arrays = dict(include_words=self.include_words,
+                          word_ids=self.word_ids, votes=self.votes)
+            meta = dict(schema=ARTIFACT_SCHEMA_VERSION,
+                        n_features=self.n_features,
+                        n_classes=self.n_classes,
+                        stats=self.stats.as_dict(),
+                        geometry=list(self.geometry), tuned=self.tuned)
+            return self._write(path, arrays, meta)
         sched = self.default_schedule
         fsched = self.default_factorized_schedule
         arrays = dict(
@@ -502,6 +536,12 @@ class CompiledTM:
             tuned=self.tuned,
             features=self.extract_features(),
         )
+        return self._write(path, arrays, meta)
+
+    @staticmethod
+    def _write(path: str, arrays: dict, meta: dict) -> str:
+        """Write ``arrays`` and ``meta`` under the checksum envelope, by a
+        tmp file and an atomic replace; the final path."""
         meta["checksum"] = _artifact_checksum(arrays, meta)
         final = path if path.endswith(".npz") else path + ".npz"
         tmp = f"{final}.tmp-{os.getpid()}"
@@ -559,6 +599,8 @@ class CompiledTM:
                 "n_clauses_dense", "n_clauses_nonempty", "n_clauses_unique",
                 "n_words_dense", "n_words_active", "n_includes", "n_literals",
                 "n_partial_terms_dense", "n_partial_terms_unique",
+                "n_positions", "n_patch_literals", "weight_min",
+                "weight_max",
             ) if k in st}
         )
         compiled = CompiledTM(
@@ -568,6 +610,8 @@ class CompiledTM:
             n_features=meta["n_features"],
             n_classes=meta["n_classes"],
             stats=stats,
+            geometry=(Geometry(*meta["geometry"]) if "geometry" in meta
+                      else None),
         )
         if "schedule" in meta:   # pre-schedule artifacts rebuild lazily
             sm = meta["schedule"]
@@ -657,6 +701,9 @@ def validate_artifact(compiled: CompiledTM) -> None:
     U, Wa = inc.shape
     if votes.shape != (U, compiled.n_classes):
         fail(f"votes shape {votes.shape} != ({U}, {compiled.n_classes})")
+    if compiled.geometry is not None:
+        _validate_conv(compiled, fail)
+        return
     if wid.shape != (Wa,):
         fail(f"word_ids shape {wid.shape} != ({Wa},)")
     if Wa and (int(wid[0]) < 0 or (Wa > 1 and np.any(np.diff(wid) <= 0))):
@@ -749,15 +796,105 @@ def validate_artifact(compiled: CompiledTM) -> None:
                       anytime.factorized_tile_margins)
 
 
+def _validate_conv(compiled: CompiledTM, fail) -> None:
+    """Invariants of a convolutional artifact: a geometry that makes
+    patches, include rows exactly one patch's literals wide with no bit
+    past them, every word kept, weights within the kernel's exact fold."""
+    from repro.kernels.fused_infer import VOTE_BOUND
+
+    g = compiled.geometry
+    if any(int(v) <= 0 for v in g):
+        fail(f"geometry {g} is not three positive sizes (H, W, window)")
+    if g.win > min(g.H, g.W):
+        fail(f"window {g.win} exceeds the image {g.H}x{g.W}")
+    if compiled.n_features != g.H * g.W:
+        fail(f"n_features {compiled.n_features} != {g.H}x{g.W} pixels")
+    inc, wid = compiled.include_words, compiled.word_ids
+    Wl = packetizer.n_words(g.literals)
+    if inc.shape[1] != Wl:
+        fail(f"include rows have {inc.shape[1]} words; {g.literals} patch "
+             f"literals take {Wl}")
+    if not np.array_equal(wid, np.arange(Wl)):
+        fail("a convolutional artifact keeps every include word")
+    tail = g.literals - 32 * (Wl - 1)
+    if tail < 32 and inc.size and np.any(inc[:, -1] >> np.uint32(tail)):
+        fail("include bits past the last patch literal")
+    if compiled.votes.size and int(np.abs(compiled.votes).max()) >= VOTE_BOUND:
+        fail(f"|weights| reach {int(np.abs(compiled.votes).max())}, the "
+             f"kernel's fold is exact below {VOTE_BOUND}")
+    st = compiled.stats
+    if (st.n_positions, st.n_patch_literals) != (g.positions, g.literals):
+        fail(f"stats count {st.n_positions} positions x "
+             f"{st.n_patch_literals} literals; the geometry gives "
+             f"{g.positions} x {g.literals}")
+
+
+def _compile_conv(config: tm.ConvTMConfig, ta_state, weights, *,
+                  dedup: bool) -> CompiledTM:
+    """Empty clauses dropped, identical include rows merged (their weights
+    summed); every include word kept (the kernel reads a whole patch)."""
+    g = config.geometry
+    ta, w = np.asarray(ta_state), np.asarray(weights)
+    C, K = config.n_clauses, config.n_classes
+    if ta.shape != (C, g.literals) or w.shape != (C, K):
+        raise ValueError(
+            f"a {C}-clause ConvCoTM over {g.literals} patch literals and "
+            f"{K} classes needs automata ({C}, {g.literals}) and weights "
+            f"({C}, {K}); got {ta.shape} and {w.shape}")
+    inc = (ta >= 0).astype(np.uint8)
+    nonempty = inc.any(axis=1)
+    Wl = packetizer.n_words(g.literals)
+    words = (packetizer.pack_bits_np(inc[nonempty]) if nonempty.any()
+             else np.zeros((0, Wl), np.uint32))
+    if dedup and words.shape[0]:
+        uniq, inv = np.unique(words, axis=0, return_inverse=True)
+        inv = inv.reshape(-1)
+    else:
+        uniq, inv = words, np.arange(words.shape[0])
+    U = uniq.shape[0]
+    votes = np.zeros((max(U, 1), K), np.int32)
+    np.add.at(votes, inv, w[nonempty].astype(np.int32))
+    if U == 0:
+        uniq = np.zeros((1, Wl), np.uint32)   # degenerate all-empty model
+    stats = CompileStats(
+        n_clauses_dense=C,
+        n_clauses_nonempty=int(nonempty.sum()),
+        n_clauses_unique=int(uniq.shape[0]),
+        n_words_dense=Wl,
+        n_words_active=Wl,
+        n_includes=int(inc.sum()),
+        n_literals=g.literals,
+        n_positions=g.positions,
+        n_patch_literals=g.literals,
+        weight_min=int(votes.min()),
+        weight_max=int(votes.max()),
+    )
+    return CompiledTM(
+        include_words=uniq.astype(np.uint32),
+        word_ids=np.arange(Wl, dtype=np.int32),
+        votes=votes,
+        n_features=config.n_features,
+        n_classes=K,
+        stats=stats,
+        geometry=g,
+    )
+
+
 def compile_tm(
-    config: tm.TMConfig,
+    config,
     ta_state,
     *,
+    weights=None,
     dedup: bool = True,
     prune_words: bool = True,
     cluster: bool = True,
 ) -> CompiledTM:
     """Compile a trained automata bank into a :class:`CompiledTM`.
+
+    A :class:`tm.ConvTMConfig` compiles a convolutional artifact from its
+    ``(C, Lp)`` automata over one patch's literals and its ``(C, K)``
+    signed ``weights``; the word and row options below are for a vanilla
+    :class:`tm.TMConfig`.
 
     ``cluster`` reorders the surviving unique clauses by (chain length,
     active-word signature) — the row order the block-sparse schedule wants;
@@ -766,6 +903,10 @@ def compile_tm(
     DON'T-TOUCH-pragma analog used by benchmarks/logic_sharing.py to
     measure the savings (paper Fig. 8).
     """
+    if isinstance(config, tm.ConvTMConfig):
+        if weights is None:
+            raise TypeError("compile_tm: a ConvCoTM needs its weights=")
+        return _compile_conv(config, ta_state, weights, dedup=dedup)
     ta = np.asarray(ta_state)
     C_raw = config.n_clauses_raw
     inc = (ta[:C_raw] >= 0).astype(np.uint8)               # (C, L)
@@ -962,6 +1103,9 @@ def run_compiled(
         # their tuning applied
         raise TypeError(f"run_compiled: unknown block kwargs {sorted(unknown)}; "
                         f"expected a subset of {sorted(known)}")
+    if compiled.geometry is not None:
+        return _run_conv(compiled, x_packed, engine, interpret, blocks,
+                         legacy=(use_kernel, fuse, sparse, factorize))
 
     legacy = {name: v for name, v in (
         ("use_kernel", use_kernel), ("fuse", fuse),
@@ -982,6 +1126,9 @@ def run_compiled(
         uk, it = ops.kernel_dispatch(use_kernel, interpret)
     else:
         spec = ops.EngineSpec.coerce(engine)
+        if spec.name == "conv":
+            raise TypeError("run_compiled: engine 'conv' runs convolutional "
+                            "artifacts; this one is a vanilla TM")
         use_kernel, interpret, fuse, sparse, factorize = (
             spec.resolve(interpret))
         if spec.name == "auto":
@@ -1065,9 +1212,41 @@ def run_compiled(
     )
 
 
+def _run_conv(compiled: CompiledTM, x_packed, engine, interpret, blocks,
+              legacy) -> jnp.ndarray:
+    """A convolutional artifact: (B, Wr) packed images -> (B, K) class
+    sums on the ``"conv"`` kernel or the ``"oracle"`` (``"auto"`` follows
+    the ambient dispatch).  Always exact: there is no anytime prefix."""
+    from repro.kernels import ops
+
+    if any(v is not _UNSET for v in legacy):
+        raise TypeError("run_compiled: the deprecated engine kwargs do not "
+                        "apply to a convolutional artifact; pass engine=")
+    spec = ops.EngineSpec.coerce(engine)
+    if spec.name not in ("auto", "conv", "oracle"):
+        raise TypeError(
+            f"run_compiled: engine {spec.name!r} runs vanilla TM artifacts; "
+            "a convolutional one runs on 'conv' or 'oracle'")
+    extra = blocks.keys() - {"block_b"}
+    if extra:
+        raise TypeError(f"run_compiled: the conv kernel tiles only "
+                        f"block_b; got {sorted(extra)}")
+    use_kernel = {"conv": True, "oracle": False}.get(spec.name,
+                                                     spec.use_kernel)
+    uk, it = ops.kernel_dispatch(
+        use_kernel, spec.interpret if interpret is None else interpret)
+    return ops.conv_tm_forward_packed(
+        x_packed, compiled.include_words, compiled.votes,
+        geom=compiled.geometry,
+        operands=compiled.conv_operands() if uk else None,
+        use_kernel=uk, interpret=it, **blocks)
+
+
 def predict_compiled(compiled: CompiledTM, x: jnp.ndarray, **kw) -> jnp.ndarray:
-    """(B, F) raw boolean features -> predicted class ids."""
-    xp = packetizer.pack_literals(x)
+    """(B, F) raw boolean features (a convolutional artifact: the images'
+    pixels, row-major) -> predicted class ids."""
+    xp = (packetizer.pack_bits(x) if compiled.geometry is not None
+          else packetizer.pack_literals(x))
     return jnp.argmax(run_compiled(compiled, xp, **kw), axis=-1)
 
 

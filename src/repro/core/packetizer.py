@@ -62,6 +62,31 @@ def pack_include_masks(ta_state: jax.Array, word_bits: int = WORD_BITS) -> jax.A
     return pack_bits(inc, word_bits)
 
 
+def patch_literals(img_words: jax.Array, geom) -> jax.Array:
+    """(B, Wr) packed images -> (B, P, Lp) uint8 patch literals of a
+    convolutional TM (``kernels/conv_infer.Geometry``), made on the device.
+
+    Patch ``p = py * Pw + px`` reads its ``win x win`` pixels (row-major),
+    then ``py`` and ``px`` thermometer-coded in ``H - win`` and ``W - win``
+    bits (bit ``i`` is 1 iff the coordinate exceeds ``i``), then the
+    negations of all of them.  The serving kernel never forms this array;
+    the oracle rung and the tests do."""
+    from repro.kernels import conv_infer
+
+    g = geom
+    x = unpack_bits(img_words, g.H * g.W)                     # (B, H*W)
+    py, px = np.divmod(np.arange(g.positions), g.Pw)
+    dy, dx = np.divmod(np.arange(g.win * g.win), g.win)
+    idx = (py[:, None] + dy) * g.W + px[:, None] + dx           # (P, win^2)
+    where = np.concatenate([conv_infer.thermometer(g.Ph, g.H - g.win)[py],
+                            conv_infer.thermometer(g.Pw, g.W - g.win)[px]],
+                           axis=1)                              # (P, bits)
+    feats = jnp.concatenate(
+        [x[:, idx], jnp.broadcast_to(where, (x.shape[0],) + where.shape)],
+        axis=-1)
+    return jnp.concatenate([feats, 1 - feats], axis=-1)
+
+
 # -- numpy twins (host-side "Packetizer" used by the offline compiler) -------
 
 def pack_bits_np(bits: np.ndarray, word_bits: int = WORD_BITS) -> np.ndarray:

@@ -16,6 +16,7 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 
 @dataclasses.dataclass(frozen=True)
@@ -53,6 +54,73 @@ class TMConfig:
 
     def replace(self, **kw: Any) -> "TMConfig":
         return dataclasses.replace(self, **kw)
+
+
+@dataclasses.dataclass(frozen=True)
+class ConvTMConfig:
+    """A convolutional coalesced TM (ConvCoTM; Tunheim et al.,
+    arXiv:2501.19347): a ``window x window`` patch slides with stride 1
+    over an ``image_h x image_w`` boolean image, ``n_clauses`` clauses are
+    shared by all classes, each with a signed integer weight per class.
+
+    Patch literals (``core/packetizer.patch_literals``): the patch's
+    pixels, then its row and column thermometer-coded in ``image_h -
+    window`` and ``image_w - window`` bits, then the negations of all of
+    them.  A clause fires on the image iff it fires on some patch; an empty
+    clause never fires.
+    """
+
+    image_h: int
+    image_w: int
+    window: int
+    n_clauses: int
+    n_classes: int
+
+    @property
+    def geometry(self):
+        from repro.kernels.conv_infer import Geometry
+
+        return Geometry(self.image_h, self.image_w, self.window)
+
+    @property
+    def n_features(self) -> int:
+        """Features of one request: the image's pixels."""
+        return self.image_h * self.image_w
+
+
+# ConvCoTM weights are signed int8, symmetric
+CONV_WEIGHT_MAX = 127
+
+
+def conv_bank(config: ConvTMConfig, img_words, seed: int):
+    """A seeded ConvCoTM bank, ``(ta_state (C, Lp) int8, weights (C, K)
+    int32)``.  Clause ``j`` includes ``k`` in [8, 24] of the true
+    literals of one seeded image of ``img_words`` (packed rows) at one
+    seeded position, so it fires on that image: three tenths of them
+    (rounded up) from the patch's lit pixels, the rest from its other true
+    literals.  Fewer lit pixels give clauses that fire on nearly every
+    image; half of them, clauses that fire on so few that most images fire
+    none.  Weights are uniform over the signed range.  Automata read 0
+    (include) or -1 (exclude)."""
+    from repro.core import packetizer
+
+    rng = np.random.default_rng(seed)
+    C, g = config.n_clauses, config.geometry
+    img = rng.integers(0, img_words.shape[0], C)
+    pos = rng.integers(0, g.positions, C)
+    lits = np.asarray(packetizer.patch_literals(
+        jnp.asarray(np.asarray(img_words)[img]), g))[np.arange(C), pos]
+    npix = g.win * g.win
+    ta = np.full((C, g.literals), -1, np.int8)
+    for j in range(C):
+        k = int(rng.integers(8, 25))
+        lit = np.flatnonzero(lits[j, :npix])
+        rest = np.setdiff1d(np.flatnonzero(lits[j]), lit)
+        n_lit = min(len(lit), -(-3 * k // 10))
+        ta[j, rng.choice(lit, n_lit, replace=False)] = 0
+        ta[j, rng.choice(rest, k - n_lit, replace=False)] = 0
+    w = CONV_WEIGHT_MAX
+    return ta, rng.integers(-w, w + 1, (C, config.n_classes)).astype(np.int32)
 
 
 @jax.tree_util.register_dataclass

@@ -25,6 +25,7 @@ from repro.runtime import faults
 
 from repro.kernels import class_sum as _class_sum_kernel
 from repro.kernels import clause_eval as _clause_eval_kernel
+from repro.kernels import conv_infer as _conv_infer_kernel
 from repro.kernels import fused_infer as _fused_infer_kernel
 from repro.kernels import fused_train as _fused_train_kernel
 from repro.kernels import ref
@@ -52,7 +53,7 @@ def kernel_dispatch(use_kernel=None, interpret=None):
     return _resolve(use_kernel, interpret)
 
 
-ENGINE_NAMES = ("auto", "factorized", "sparse", "dense", "oracle")
+ENGINE_NAMES = ("auto", "factorized", "sparse", "dense", "conv", "oracle")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -70,6 +71,8 @@ class EngineSpec:
     * ``"sparse"`` — the flat block-sparse chain schedule kernel.
     * ``"dense"`` — the fused dense kernel (``fuse=False`` for the legacy
       two-kernel pipeline).
+    * ``"conv"`` — the convolutional kernel (``kernels/conv_infer.py``),
+      the one kernel of a convolutional artifact.
     * ``"oracle"`` — the pure-jnp XLA reference path.
 
     Named kernel engines pin ``use_kernel=True`` (that is what naming them
@@ -93,7 +96,7 @@ class EngineSpec:
         if self.name == "oracle" and self.use_kernel:
             raise ValueError("engine 'oracle' is the non-kernel path; "
                              "use_kernel=True contradicts it")
-        if (self.name in ("factorized", "sparse", "dense")
+        if (self.name in ("factorized", "sparse", "dense", "conv")
                 and self.use_kernel is False):
             raise ValueError(
                 f"engine {self.name!r} names a Pallas kernel; "
@@ -126,11 +129,38 @@ class EngineSpec:
             return True, it, True, True, True
         if self.name == "sparse":
             return True, it, True, True, False
-        if self.name == "dense":
+        if self.name in ("dense", "conv"):
             return True, it, self.fuse, False, False
         if self.name == "oracle":
             return False, it, self.fuse, False, False
         return self.use_kernel, it, self.fuse, None, None
+
+
+def engine_levels(compiled, use_kernel: bool | None = None, *,
+                  sparse: bool = True,
+                  factorize: bool | None = None) -> list:
+    """The engine ladder a compiled artifact is served on, preferred
+    engine first, chosen from the artifact itself: a convolutional
+    artifact runs its kernel, then the oracle; a vanilla one the factorized
+    schedule kernel (when its measured term sharing clears the compiler's
+    threshold), the sparse and dense kernels, then the oracle.  Off the
+    kernel path (:func:`kernel_dispatch`) only the oracle is left.
+
+    Pins for a vanilla artifact: ``sparse=False`` leaves the dense kernel
+    alone above the oracle; ``factorize`` True or False takes the
+    factorized kernel or leaves it out whatever the sharing."""
+    from repro.core import compiler
+
+    uk, _ = _resolve(use_kernel, None)
+    if compiled.geometry is not None:
+        return (["conv"] if uk else []) + ["oracle"]
+    sparse = uk and sparse
+    if factorize is None:
+        factorize = (compiled.stats.partial_term_sharing
+                     >= compiler.FACTORIZE_SHARING_THRESHOLD)
+    return ((["factorized"] if sparse and factorize else [])
+            + (["sparse"] if sparse else [])
+            + (["dense"] if uk else []) + ["oracle"])
 
 
 class EngineLadder:
@@ -415,6 +445,38 @@ def tm_forward_packed(
     if nonempty is not None:
         fired = fired * nonempty[None, :].astype(fired.dtype)
     return class_sums(fired, votes, **kw, **cs_blocks)
+
+
+def conv_tm_forward_packed(
+    img_words: jax.Array,    # (B, Wr) packed images
+    include_words,           # (C, Wl) packed patch-literal include bits
+    votes,                   # (C, K) int32 weights
+    *,
+    geom,                    # kernels/conv_infer.Geometry
+    operands=None,           # conv_infer.conv_operands(...) of this bank
+    use_kernel: bool | None = None,
+    interpret: bool | None = None,
+    **blocks,
+) -> jax.Array:
+    """Packed images -> (B, K) class sums of a convolutional TM.
+
+    Kernel path: ``conv_infer.conv_tm_forward`` over the bank's banded
+    ``operands`` (which it needs).  Oracle path: the patch literals made
+    on the device (``packetizer.patch_literals``) and
+    ``ref.conv_class_sums_ref`` over the include bits and votes."""
+    from repro.core import packetizer
+
+    use_kernel, interpret = _resolve(use_kernel, interpret)
+    if use_kernel:
+        faults.raise_if("kernel.conv")   # drill: conv-kernel failure
+        band, v = operands
+        return _conv_infer_kernel.conv_tm_forward(
+            img_words, jnp.asarray(band), jnp.asarray(v),
+            geom=geom, interpret=interpret, **blocks)
+    return ref.conv_class_sums_ref(
+        packetizer.patch_literals(img_words, geom),
+        packetizer.unpack_bits(jnp.asarray(include_words), geom.literals),
+        jnp.asarray(votes))
 
 
 def tm_forward_schedule(

@@ -77,6 +77,28 @@ def class_sum_ref(fired: jax.Array, votes: jax.Array) -> jax.Array:
 
 
 # ---------------------------------------------------------------------------
+# conv_class_sums: convolutional coalesced TM (ConvCoTM) inference
+# ---------------------------------------------------------------------------
+
+def conv_class_sums_ref(lits: jax.Array, include: jax.Array,
+                        votes: jax.Array) -> jax.Array:
+    """(B, P, Lp) {0,1} patch literals x (C, Lp) {0,1} include bits x
+    (C, K) weights -> (B, K) int32.
+
+    Clause ``c`` fires on patch ``p`` iff none of its included literals is
+    0; it fires on the image iff it fires on some patch, and an empty
+    clause never fires.  Integer throughout (an int8 dot with int32
+    accumulation counts the violations)."""
+    B, P, L = lits.shape
+    zero = (1 - lits.reshape(B * P, L)).astype(jnp.int8)
+    viol = jnp.dot(zero, include.astype(jnp.int8).T,
+                   preferred_element_type=jnp.int32)            # (B*P, C)
+    fire = jnp.any(viol.reshape(B, P, -1) == 0, axis=1)
+    fire = fire & jnp.any(include != 0, axis=1)[None, :]
+    return fire.astype(jnp.int32) @ votes.astype(jnp.int32)
+
+
+# ---------------------------------------------------------------------------
 # ta_delta: batched Type I/II feedback deltas (training hot loop)
 # ---------------------------------------------------------------------------
 

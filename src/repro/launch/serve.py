@@ -2,6 +2,7 @@
 LM prefill+decode.
 
     PYTHONPATH=src python -m repro.launch.serve --arch tm-mnist --requests 4096
+    PYTHONPATH=src python -m repro.launch.serve --arch convcotm-mnist
     PYTHONPATH=src python -m repro.launch.serve --arch tinyllama-1.1b --smoke
 
 The TM path mirrors the MATADOR runtime: train -> compile (compiler.py) ->
@@ -125,6 +126,11 @@ def serve_tm(args) -> dict:
     from repro.runtime import StragglerMonitor, faults
 
     config = TM_CONFIGS[args.arch]
+    conv = isinstance(config, tm.ConvTMConfig)
+    if conv and (args.online or args.mesh):
+        raise SystemExit(f"--arch {args.arch} is a convolutional TM: it has "
+                         "no training step for --online and no sharded "
+                         "engine for --mesh")
     if args.artifact and not args.artifact.endswith(".npz"):
         # np.savez_compressed appends .npz — normalize up front so the
         # load check looks for the file save() actually wrote
@@ -147,17 +153,32 @@ def serve_tm(args) -> dict:
             compiled = compiler.CompiledTM.load(args.artifact)
         except compiler.ArtifactError as e:
             raise SystemExit(f"refusing to serve: {e}")
+        # a mismatched artifact would serve silently wrong predictions
+        # (out-of-range word gathers clamp instead of failing).  tm-mnist
+        # and convcotm-mnist share F and K, so the kind (vanilla or
+        # convolutional) and a convolutional geometry must match too: the
+        # ladder follows the artifact and the request rows the --arch
+        want = config.geometry if conv else None
         if (compiled.n_features != config.n_features
-                or compiled.n_classes != config.n_classes):
-            # a mismatched artifact would serve silently wrong predictions
-            # (out-of-range word gathers clamp instead of failing)
+                or compiled.n_classes != config.n_classes
+                or compiled.geometry != want):
             raise SystemExit(
                 f"artifact {args.artifact} was compiled for "
-                f"F={compiled.n_features}/K={compiled.n_classes}, but "
-                f"--arch {args.arch} is F={config.n_features}/"
-                f"K={config.n_classes}")
+                f"F={compiled.n_features}/K={compiled.n_classes}/"
+                f"geometry={compiled.geometry}, but --arch {args.arch} is "
+                f"F={config.n_features}/K={config.n_classes}/"
+                f"geometry={want}")
         print(f"loaded artifact {args.artifact} "
               f"(U={compiled.n_unique}, tuned={sorted(compiled.tuned)})")
+    elif conv:
+        # no ConvCoTM training step yet: a bank drawn from seeded images
+        # (tm.conv_bank), every clause firing on some image
+        X, _ = make_boolean_classification(
+            args.n_train, config.n_features, config.n_classes, seed=0)
+        ta, weights = tm.conv_bank(
+            config, packetizer.pack_bits_np(X), seed=0)
+        compiled = compiler.compile_tm(config, ta, weights=weights)
+        trained_this_run = True
     else:
         X, y = make_boolean_classification(
             args.n_train, config.n_features, config.n_classes, seed=0
@@ -182,21 +203,20 @@ def serve_tm(args) -> dict:
 
     bucket = args.bucket
     use_kernel, interpret = ops.kernel_dispatch()
-    # kernel-path default: the chain-schedule kernels (work scales with the
-    # artifact's include structure); --no-sparse pins the dense kernel.
-    # Within the schedule path the FACTORIZED kernel serves when the
+    # the ladder comes from the artifact (ops.engine_levels): on the kernel
+    # path the chain-schedule kernels, the FACTORIZED one first when the
     # artifact's measured term sharing clears the compile-time threshold
-    # (shared AND terms evaluated once per bucket); --no-factorize pins
-    # the flat bit-chain kernel, --factorize pins the factorized one
-    # regardless of the measured sharing.
+    # (shared AND terms evaluated once per bucket).  --no-sparse pins the
+    # dense kernel, --no-factorize the flat bit-chain kernel, --factorize
+    # the factorized one regardless of the measured sharing; a
+    # convolutional artifact has one kernel, which they leave alone.
     if args.factorize and args.no_factorize:
         raise SystemExit("--factorize and --no-factorize are exclusive")
-    sparse = use_kernel and not args.no_sparse
-    factorize = sparse and not args.no_factorize and (
-        args.factorize
-        or compiled.stats.partial_term_sharing
-        >= compiler.FACTORIZE_SHARING_THRESHOLD
-    )
+    levels = ops.engine_levels(
+        compiled, use_kernel, sparse=not args.no_sparse,
+        factorize=(True if args.factorize
+                   else False if args.no_factorize else None))
+    sparse, factorize = "sparse" in levels, "factorized" in levels
 
     def tuned_blocks(n_clauses):
         # autotune the shape the kernel ACTUALLY runs: per-shard C_loc on a
@@ -438,6 +458,11 @@ def serve_tm(args) -> dict:
             blocks = tuned_sparse_blocks(art.include_words)
             return _quality_engine(
                 art, "sparse", blocks, ("block_c", "block_j"))
+        if name == "conv":
+            return jax.jit(
+                lambda xw: compiler.run_compiled(
+                    art, xw, engine="conv").argmax(-1),
+                donate_argnums=donate)
         if name == "dense":
             blocks = tuned_blocks(art.n_unique)
             return jax.jit(
@@ -451,14 +476,6 @@ def serve_tm(args) -> dict:
             lambda xw: compiler.run_compiled(
                 art, xw, engine="oracle").argmax(-1))
 
-    levels = []
-    if use_kernel:
-        if factorize:
-            levels.append("factorized")
-        if sparse:
-            levels.append("sparse")
-        levels.append("dense")
-    levels.append("oracle")
     if args.mesh:
         # the sharded engine degrades to the unsharded ladder: a mesh-only
         # failure (bad spec, per-shard lowering) still serves every bucket
@@ -472,7 +489,9 @@ def serve_tm(args) -> dict:
     )
     # --online: the request stream's labels double as the labeled feedback
     # stream (serve.py's stand-in for a production label joiner)
-    xp = np.asarray(packetizer.pack_literals(jnp.asarray(Xr)))
+    # a request row: packed literals, or a convolutional TM's packed image
+    xp = (packetizer.pack_bits_np(Xr) if conv
+          else np.asarray(packetizer.pack_literals(jnp.asarray(Xr))))
     n, W = xp.shape
 
     mon = StragglerMonitor(threshold=args.bucket_deadline or 2.0, warmup=2)
@@ -680,7 +699,8 @@ def serve_tm(args) -> dict:
         print(f"saved artifact (schedules + tuned tilings) to {args.artifact}")
     engine_labels = {"factorized": "factorized-schedule",
                      "sparse": "sparse-schedule",
-                     "dense": "fused-kernel", "oracle": "oracle"}
+                     "dense": "fused-kernel", "conv": "conv-kernel",
+                     "oracle": "oracle"}
     eng = ladder.engine
     label = (f"clause-sharded {engine_labels[eng[len('mesh-'):]]} "
              f"({args.mesh})" if eng.startswith("mesh-")
@@ -872,7 +892,9 @@ def main() -> None:
 
     args = build_parser().parse_args()
     enable_compile_cache()
-    if args.arch.startswith("tm-"):
+    from repro.configs.matador_tm import TM_CONFIGS
+
+    if args.arch in TM_CONFIGS:
         serve_tm(args)
     else:
         serve_lm(args)

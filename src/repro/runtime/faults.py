@@ -53,6 +53,8 @@ SITES = {
                      "failure of the flat block-sparse chain kernel",
     "kernel.dense": "ops.tm_forward_packed fused kernel launch — a lowering "
                     "failure of the dense single-pass kernel",
+    "kernel.conv": "ops.conv_tm_forward_packed kernel launch — a lowering "
+                   "failure of the convolutional kernel",
     "serve.slow_bucket": "launch/serve.py bucket loop — a stalled bucket "
                          "(param = seconds of stall)",
     "train.sigterm": "core/train.fit + launch/train.py step boundary — "

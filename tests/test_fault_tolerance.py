@@ -438,6 +438,27 @@ def _serve_health(r):
     return json.loads(lines[0][len("SERVE_HEALTH "):])
 
 
+@pytest.mark.parametrize("use_kernel, sparse, factorize, want", [
+    (True, True, True, ["factorized", "sparse", "dense", "oracle"]),
+    (True, True, False, ["sparse", "dense", "oracle"]),
+    (True, False, True, ["dense", "oracle"]),
+    (False, True, True, ["oracle"]),
+])
+def test_engine_levels_honour_the_pins(tiny_compiled, use_kernel, sparse,
+                                       factorize, want):
+    _, compiled = tiny_compiled
+    assert ops.engine_levels(compiled, use_kernel, sparse=sparse,
+                             factorize=factorize) == want
+
+
+def test_engine_levels_follow_the_measured_sharing(tiny_compiled):
+    _, compiled = tiny_compiled
+    shares = (compiled.stats.partial_term_sharing
+              >= compiler.FACTORIZE_SHARING_THRESHOLD)
+    assert ops.engine_levels(compiled, True) == (
+        ["factorized"] if shares else []) + ["sparse", "dense", "oracle"]
+
+
 def test_serve_ladder_demotes_to_oracle_under_kernel_faults():
     r = _run(SERVE_ARGV + ["--factorize"], env_extra={
         "REPRO_USE_PALLAS": "1",

@@ -11,7 +11,10 @@ words, 2000 clauses padded to C = 2048, K = 10) at serving bucket and
 training batch B = 512, with the default tilings; the schedule-table
 shapes are those ``compile_tm`` gives a tm-mnist bank after one training
 epoch.  One factorized compile uses a tm-edge-xl term table, whose
-whole-table VMEM scratch grows with the artifact.  Every autotune
+whole-table VMEM scratch grows with the artifact.  The convolutional
+kernel compiles at convcotm-mnist's published widths: 28x28 images, a
+10x10 window (361 positions, 272 literals a patch), 128 clauses, K = 10,
+at each of its image tilings.  Every autotune
 candidate is compiled too: on the chip ``tune()`` would crash serving on
 one that does not lower.
 
@@ -29,8 +32,9 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.configs.matador_tm import TM_EDGE_XL, TM_MNIST
-from repro.kernels import autotune, fused_infer, fused_train, sparse_infer
+from repro.configs.matador_tm import CONVCOTM_MNIST, TM_EDGE_XL, TM_MNIST
+from repro.kernels import autotune, conv_infer, fused_infer, fused_train
+from repro.kernels import sparse_infer
 from repro.kernels import term_infer
 
 B = 512
@@ -159,6 +163,24 @@ def test_factorized_forward_lowers_edge_xl_term_table(one_chip):
     factorized_forward(one_chip, w=XL_W, cp=XL_CP, tp=XL_TP,
                        term_w=XL_TERM_W, jp=64,
                        t=XL_TP // bt + XL_CP // term_infer.DEFAULT_BLOCK_C)
+
+
+def conv_forward(sharding, block_b):
+    g, cp = CONVCOTM_MNIST.geometry, CONVCOTM_MNIST.n_clauses
+    assert (g.positions, g.literals, cp) == (361, 272, 128)
+    assert_lowers(
+        sharding,
+        lambda x, band, v: conv_infer.conv_tm_forward(
+            x, band, v, geom=g, block_b=block_b),
+        ((B, g.image_words), jnp.uint32),
+        ((g.Pw * cp, g.contraction), jnp.int8),
+        ((cp, CONVCOTM_MNIST.n_classes), jnp.int32),
+    )
+
+
+@pytest.mark.parametrize("block_b", [128, 256, 512])
+def test_conv_forward_lowers(one_chip, block_b):
+    conv_forward(one_chip, block_b)
 
 
 CANDIDATES = (
