@@ -26,7 +26,13 @@ _H3 = np.uint32(3266489917)
 
 def hash_u32(idx: jax.Array, seed: jax.Array) -> jax.Array:
     """Deterministic uint32 hash of (index, seed) — the kernel's RNG."""
-    x = idx.astype(jnp.uint32) * _H1 + seed.astype(jnp.uint32)
+    return hash_finish(idx.astype(jnp.uint32) * _H1 + seed.astype(jnp.uint32))
+
+
+def hash_finish(x: jax.Array) -> jax.Array:
+    """The avalanche of :func:`hash_u32` after its first round
+    ``x = idx * H1 + seed``.  That round is affine mod 2**32, so a kernel
+    can hoist the part of it that does not change from draw to draw."""
     x = x ^ (x >> 16)
     x = x * _H2
     x = x ^ (x >> 13)

@@ -84,23 +84,66 @@ def test_chunked_matches_unchunked_all_engines(B, chunk):
         np.testing.assert_array_equal(d_ref, d_c)
 
 
-def test_fused_delta_offsets_match_composed_oracle():
-    """b_offset/c_offset != 0 (the sharded caller): the fused kernel must
-    reproduce feedback_select + ta_delta_ref on the local shard, with the
-    selection hash on GLOBAL (sample, clause) ids and the automaton hash
-    on (global sample, local clause)."""
-    cfg, ta, x, y = _problem(B=11, F=23, K=3, cpc=9, seed=3)
+def _polarity(kind, C, n_pad, rng):
+    """+1/-1 per clause by ``kind``, 0 on the last ``n_pad`` clauses."""
+    pol = {"alternating": np.where(np.arange(C) % 2 == 0, 1, -1),
+           "random": rng.choice([-1, 1], C),
+           "all_plus": np.ones(C, int)}[kind].astype(np.int32)
+    pol[C - n_pad:] = 0
+    return jnp.asarray(pol)
+
+
+# Each case: bank and batch sizes, the polarity array, the local slice
+# (c_off, n_loc) of the bank the kernel sees, and whether it hashes
+# automata by GLOBAL clause id (c_total); class boundaries fall at
+# multiples of cpc, so cpc=100 puts them mid-block at an even clause and
+# cpc=101 at an odd one (the kernel's even and odd row halves split at
+# different rows).
+DELTA_CASES = {
+    "offsets": dict(B=11, F=23, K=3, cpc=9, pol="alternating", n_pad=0,
+                    b_off=37, c_off=10, n_loc=11, c_total=False, blocks={}),
+    "random_pol_padded_c128": dict(
+        B=13, F=20, K=3, cpc=100, pol="random", n_pad=20, b_off=5, c_off=0,
+        n_loc=300, c_total=False, blocks=dict(block_b=8, block_c=128)),
+    "all_plus_c256": dict(
+        B=13, F=20, K=3, cpc=100, pol="all_plus", n_pad=0, b_off=0, c_off=0,
+        n_loc=300, c_total=False, blocks=dict(block_c=256)),
+    "odd_class_boundary_c256": dict(
+        B=13, F=20, K=3, cpc=101, pol="alternating", n_pad=3, b_off=0,
+        c_off=0, n_loc=303, c_total=False, blocks=dict(block_b=8)),
+    "shard_odd_offset_global_ids": dict(
+        B=9, F=20, K=3, cpc=100, pol="alternating", n_pad=0, b_off=3,
+        c_off=37, n_loc=150, c_total=True,
+        blocks=dict(block_b=8, block_c=128)),
+}
+
+
+@pytest.mark.parametrize("case", list(DELTA_CASES), ids=list(DELTA_CASES))
+def test_fused_delta_offsets_match_composed_oracle(case):
+    """The fused kernel reproduces feedback_select + ta_delta_ref on the
+    slice of the bank it is given, for any polarity array, class layout,
+    tiling and batch: the selection hash on GLOBAL (sample, clause) ids,
+    the automaton hash on (global sample, local clause), or on the global
+    clause id in a bank of ``c_total`` clauses."""
+    p = DELTA_CASES[case]
+    rng = np.random.default_rng(len(case))
+    cfg, ta, x, y = _problem(B=p["B"], F=p["F"], K=p["K"], cpc=p["cpc"],
+                             seed=p["B"])
+    # mostly excluded automata, so clauses fire and both types fold
+    ta = jnp.where(jnp.asarray(rng.random(ta.shape) < 0.9),
+                   -jnp.abs(ta) - 1, ta).astype(jnp.int8)
     T = cfg.threshold
+    C = cfg.n_clauses_total
     seed = jnp.uint32(55)
-    b_off, c_off, n_loc = 37, 10, 11
+    b_off, c_off, n_loc = p["b_off"], p["c_off"], p["n_loc"]
 
     lits = tm.literals(x)
     lw = packetizer.pack_bits(lits)
     iw = packetizer.pack_include_masks(ta)
     votes = tm.vote_matrix(cfg)
-    cls = jnp.clip(jnp.arange(cfg.n_clauses_total) // cfg.clauses_per_class,
+    cls = jnp.clip(jnp.arange(C) // cfg.clauses_per_class,
                    0, cfg.n_classes - 1)
-    pol = tm.polarity(cfg)
+    pol = _polarity(p["pol"], C, p["n_pad"], rng)
 
     # per-sample scalars from the FULL clause bank's class sums
     sums = jnp.clip(ref.clause_fire_ref(lw, iw).astype(jnp.int32) @ votes,
@@ -109,17 +152,39 @@ def test_fused_delta_offsets_match_composed_oracle():
                                       b_offset=b_off)
 
     sl = slice(c_off, c_off + n_loc)
+    c_total = C if p["c_total"] else None
     fire_loc = ref.clause_fire_ref(lw, iw[sl]).astype(jnp.uint8)
     ftype_loc = ops.feedback_select(y, kn, p_t, p_n, cls[sl], pol[sl], seed,
                                     b_offset=b_off, c_offset=c_off)
     d_ref = ref.ta_delta_ref(ta[sl], lits, fire_loc, ftype_loc, seed,
-                             p_act=1.0, p_inact=0.25, b_offset=b_off)
+                             p_act=1.0, p_inact=0.25, b_offset=b_off,
+                             c_offset=c_off, c_total=c_total)
     d_k = fused_train.fused_tm_train_delta(
         ta[sl], lits, lw, iw[sl], y, kn, p_t, p_n, cls[sl], pol[sl], seed,
         p_act=1.0, p_inact=0.25, b_offset=b_off, c_offset=c_off,
-        interpret=True)
+        c_total=c_total, interpret=True, **p["blocks"])
     np.testing.assert_array_equal(np.asarray(d_ref), np.asarray(d_k))
+    ft = np.asarray(ftype_loc)
+    assert (ft == 1).any() and (ft == 2).any()
     assert int(np.abs(np.asarray(d_ref)).sum()) > 0
+
+
+def test_fold_engagement_on_a_hand_built_plan():
+    """Per (sample, half-block): a half with a Type I row takes the hashed
+    path, one with Type II rows only the Type II path, one with no
+    feedback neither.  Half-blocks are the even and odd rows of a block."""
+    ft = np.zeros((3, 512), np.uint8)
+    ft[0, :256] = 2          # sample 0: block 0 all Type II, block 1 none
+    ft[1, 10] = 1            # sample 1: one Type I row in block 0's evens
+    ft[2, 300] = 1           # sample 2: block 1's evens mixed I and II,
+    ft[2, 302] = 2           #           its odds Type II only
+    ft[2, 301] = 2
+    got = fused_train.fold_engagement(ft, block_c=256)
+    assert got == {"hashed": 2 / 12, "type2_only": 3 / 12,
+                   "neither": 7 / 12}
+    # a smaller bank is tiled as the kernel tiles it: one 128-row block
+    assert fused_train.fold_engagement(np.zeros((2, 100), np.uint8)) == {
+        "hashed": 0.0, "type2_only": 0.0, "neither": 1.0}
 
 
 def test_fused_clause_shards_reassemble_full_delta():

@@ -8,9 +8,10 @@ more SMEM or VMEM than a core holds — fails here, at no chip time.
 
 Shapes are the paper's tm-mnist widths (784 features -> W = 49 literal
 words, 2000 clauses padded to C = 2048, K = 10) at serving bucket and
-training batch B = 512, with the default tilings; the schedule-table
-shapes are those ``compile_tm`` gives a tm-mnist bank after one training
-epoch.  One factorized compile uses a tm-edge-xl term table, whose
+training batch B = 512, with the default tilings; the training kernel
+also at tm-fmnist's 5120 clauses and the training cell's batch of 256.
+The schedule-table shapes are those ``compile_tm`` gives a tm-mnist bank
+after one training epoch.  One factorized compile uses a tm-edge-xl term table, whose
 whole-table VMEM scratch grows with the artifact.  The convolutional
 kernel compiles at convcotm-mnist's published widths: 28x28 images, a
 10x10 window (361 positions, 272 literals a patch), 128 clauses, K = 10,
@@ -32,7 +33,8 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.configs.matador_tm import CONVCOTM_MNIST, TM_EDGE_XL, TM_MNIST
+from repro.configs.matador_tm import (CONVCOTM_MNIST, TM_EDGE_XL, TM_FMNIST,
+                                     TM_MNIST)
 from repro.kernels import autotune, conv_infer, fused_infer, fused_train
 from repro.kernels import sparse_infer
 from repro.kernels import term_infer
@@ -87,17 +89,17 @@ def dense_forward(sharding, **blocks):
         ((C,), jnp.int32))
 
 
-def train_delta(sharding, **blocks):
+def train_delta(sharding, c=C, b=B, **blocks):
     assert_lowers(
         sharding,
         lambda ta, lits, lw, iw, y, kn, pt, pn, cc, pol, seed:
         fused_train.fused_tm_train_delta(
             ta, lits, lw, iw, y, kn, pt, pn, cc, pol, seed,
             p_act=1.0, p_inact=1.0 / TM_MNIST.s, **blocks),
-        ((C, L), jnp.int8), ((B, L), jnp.uint8), ((B, W), jnp.uint32),
-        ((C, W), jnp.uint32), ((B,), jnp.int32), ((B,), jnp.int32),
-        ((B,), jnp.float32), ((B,), jnp.float32), ((C,), jnp.int32),
-        ((C,), jnp.int32), ((), jnp.uint32))
+        ((c, L), jnp.int8), ((b, L), jnp.uint8), ((b, W), jnp.uint32),
+        ((c, W), jnp.uint32), ((b,), jnp.int32), ((b,), jnp.int32),
+        ((b,), jnp.float32), ((b,), jnp.float32), ((c,), jnp.int32),
+        ((c,), jnp.int32), ((), jnp.uint32))
 
 
 def sparse_forward(sharding, *, early_exit=False, jp=SPARSE_JP, t=SPARSE_T,
@@ -142,8 +144,10 @@ def test_fused_forward_lowers(one_chip):
     dense_forward(one_chip)
 
 
-def test_fused_train_delta_lowers(one_chip):
-    train_delta(one_chip)
+@pytest.mark.parametrize("c,b", [(C, B), (TM_FMNIST.n_clauses_total, 256)],
+                         ids=["tm-mnist", "tm-fmnist"])
+def test_fused_train_delta_lowers(one_chip, c, b):
+    train_delta(one_chip, c=c, b=b)
 
 
 @pytest.mark.parametrize("early_exit", [False, True],
