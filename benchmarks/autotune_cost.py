@@ -63,7 +63,7 @@ def _train_artifact(n_features, n_classes, cpc, density, seed):
         prototype_density=density, seed=seed)
     state = tm.init(cfg, jax.random.PRNGKey(seed))
     step = jax.jit(
-        lambda ta, x, yy, s: ops.tm_train_step_matmul(cfg, ta, x, yy, s)[0])
+        lambda ta, x, yy, s: ops.tm_train_step_kernel(cfg, ta, x, yy, s)[0])
     ta, k = state.ta_state, 0
     Xj, yj = jnp.asarray(X), jnp.asarray(y)
     for _ in range(_TRAIN_EPOCHS):

@@ -1,22 +1,17 @@
-"""Fused vs unfused TM inference microbenchmark (perf trajectory tracker).
+"""Fused TM inference microbenchmark (perf trajectory tracker).
 
-Times three execution engines on identical problem shapes:
+Times two execution engines on identical problem shapes:
 
   * ``fused``    — kernels/fused_infer.py single-pass kernel (clause eval +
     class sum in one ``pallas_call``, no (B, C) fired matrix in HBM), at
     the block tiling picked by kernels/autotune.py's cached sweep
-  * ``unfused``  — the legacy two-kernel pipeline (clause_eval then
-    class_sum at their shipped default tilings, fired matrix materialized
-    between them)
   * ``oracle``   — the pure-jnp XLA path (the off-TPU default engine)
 
 Engines are timed interleaved (alternating calls, min over rounds) so
-container noise hits all rows equally.  ``write_report`` persists the rows
-to ``BENCH_fused_infer.json`` so the fused-kernel perf trajectory is
-tracked across PRs.  On this CPU container both kernel paths run in Pallas
-interpret mode — the fused-vs-unfused ratio is still meaningful (same
-interpreter, one pass vs two + the materialized intermediate); on TPU the
-same harness times compiled kernels.
+host noise hits all rows equally.  ``write_report`` persists the rows to
+``BENCH_fused_infer.json`` so the fused-kernel perf trajectory is tracked
+over time.  On a CPU the kernel runs in Pallas interpret mode; on a TPU
+the same harness times the compiled kernel.
 """
 
 from __future__ import annotations
@@ -34,8 +29,8 @@ from repro.kernels import autotune as _autotune
 from repro.kernels import ops
 
 # (B, C, W, K): serving bucket x clause bank x literal words x classes.
-# The lead shape is a big clause bank — where the (B, C) HBM intermediate
-# the unfused pipeline materializes actually costs something.
+# The lead shape is a big clause bank — where a (B, C) fired matrix in HBM
+# would actually cost something.
 SHAPES = [
     (512, 4096, 8, 10),
     (256, 512, 16, 10),
@@ -71,30 +66,22 @@ def run(fast: bool = True, reps: int = 8, autotune: bool = True) -> list:
             if autotune else {}
         )
 
-        def fwd(use_kernel, fuse, **blk):
+        def fwd(use_kernel, **blk):
             # inputs stay jit arguments (not closure constants) so XLA
             # cannot constant-fold the timed computation away
             jitted = jax.jit(lambda l, i, v, n: ops.tm_forward_packed(
                 l, i, v, n,
-                use_kernel=use_kernel, interpret=interpret, fuse=fuse, **blk,
+                use_kernel=use_kernel, interpret=interpret, **blk,
             ))
             return lambda: jitted(lit, inc, votes, ne)
 
         t = _time_interleaved(
-            dict(
-                fused=fwd(True, True, **blocks),
-                unfused=fwd(True, False),
-                oracle=fwd(False, True),
-            ),
-            reps,
-        )
+            dict(fused=fwd(True, **blocks), oracle=fwd(False)), reps)
         tag = f"b{B}_c{C}_w{W}_k{K}"
         blk_str = ";".join(f"{k}={v}" for k, v in sorted(blocks.items()))
         rows.append((f"fusedinfer_fused_{tag}", t["fused"] * 1e6,
-                     f"speedup_vs_unfused={t['unfused'] / t['fused']:.2f}x"
+                     f"speedup_vs_oracle={t['oracle'] / t['fused']:.2f}x"
                      + (f";{blk_str}" if blk_str else "")))
-        rows.append((f"fusedinfer_unfused_{tag}", t["unfused"] * 1e6,
-                     "two_kernel_pipeline"))
         rows.append((f"fusedinfer_oracle_{tag}", t["oracle"] * 1e6,
                      "pure_jnp_xla"))
     return rows

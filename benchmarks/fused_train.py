@@ -1,6 +1,6 @@
-"""Fused vs unfused TM *training-step* microbenchmark (perf trajectory).
+"""Fused TM *training-step* microbenchmark (perf trajectory).
 
-Times three execution engines on identical problem shapes, mirroring
+Times two execution engines on identical problem shapes, mirroring
 ``benchmarks/fused_infer.py`` for the training hot loop:
 
   * ``fused``    — kernels/fused_train.py single-pass kernel (clause fire +
@@ -8,21 +8,16 @@ Times three execution engines on identical problem shapes, mirroring
     inference pass for class sums; the (B, C) fire/ftype matrices never
     exist in HBM), at the block tiling picked by kernels/autotune.py's
     cached training-shape sweep
-  * ``unfused``  — the legacy three-dispatch pipeline (clause_eval kernel,
-    XLA feedback plan, ta_update kernel, fire and ftype materialized
-    between them)
   * ``oracle``   — the pure-jnp XLA path (the off-TPU default engine),
     batch-chunked so its (chunk, C, L) random field stays bounded
 
-All three engines are bit-identical on the delta (tests/test_fused_train
-.py); only speed differs.  Engines are timed interleaved (alternating
-calls, min over rounds) so container noise hits all rows equally.
-``write_report`` persists the rows to ``BENCH_fused_train.json`` so the
-fused training kernel's perf trajectory is tracked across PRs.  On this
-CPU container the kernel paths run in Pallas interpret mode; the
-fused-vs-unfused ratio is still meaningful (same interpreter, two launches
-vs three + HBM intermediates + a dense hash field where the fused kernel
-exploits feedback sparsity), and on TPU the HBM-traffic win is larger.
+Both engines are bit-identical on the delta (tests/test_fused_train.py);
+only speed differs.  Engines are timed interleaved (alternating calls, min
+over rounds) so host noise hits all rows equally.  ``write_report``
+persists the rows to ``BENCH_fused_train.json`` so the fused training
+kernel's perf trajectory is tracked over time.  On a CPU the kernel runs
+in Pallas interpret mode; on a TPU the same harness times the compiled
+kernels.
 """
 
 from __future__ import annotations
@@ -40,8 +35,8 @@ from repro.kernels import autotune as _autotune
 from repro.kernels import ops
 
 # (B, n_features, n_classes, clauses_per_class): the lead shape is the
-# 512 x 4096-clause training cell — where the (B, C) HBM intermediates and
-# the dense (B, C, L) hash sweep of the unfused pipeline actually cost.
+# 512 x 4096-clause training cell — where (B, C) HBM intermediates and a
+# dense (B, C, L) hash sweep would actually cost.
 SHAPES = [
     (512, 128, 8, 512),    # C = 4096, L = 256, W = 8
     (256, 128, 8, 64),     # C = 512: small-bank regime
@@ -80,10 +75,8 @@ def run(fast: bool = True, reps: int = 3, autotune: bool = True) -> list:
 
         t = _time_interleaved(
             dict(
-                fused=step(use_kernel=True, interpret=interpret, fuse=True,
+                fused=step(use_kernel=True, interpret=interpret,
                            blocks=blocks),
-                unfused=step(use_kernel=True, interpret=interpret,
-                             fuse=False),
                 oracle=step(use_kernel=False, batch_chunk=_ORACLE_CHUNK),
             ),
             reps,
@@ -91,10 +84,8 @@ def run(fast: bool = True, reps: int = 3, autotune: bool = True) -> list:
         tag = f"b{B}_c{C}_l{L}_k{K}"
         blk_str = ";".join(f"{k}={v}" for k, v in sorted((blocks or {}).items()))
         rows.append((f"fusedtrain_fused_{tag}", t["fused"] * 1e6,
-                     f"speedup_vs_unfused={t['unfused'] / t['fused']:.2f}x"
+                     f"speedup_vs_oracle={t['oracle'] / t['fused']:.2f}x"
                      + (f";{blk_str}" if blk_str else "")))
-        rows.append((f"fusedtrain_unfused_{tag}", t["unfused"] * 1e6,
-                     "three_dispatch_pipeline"))
         rows.append((f"fusedtrain_oracle_{tag}", t["oracle"] * 1e6,
                      f"pure_jnp_xla;batch_chunk={_ORACLE_CHUNK}"))
     return rows

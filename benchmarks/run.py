@@ -7,12 +7,12 @@ Prints ``name,us_per_call,derived`` CSV (one row per measurement):
   * fig8_*     — paper Fig. 8 analog (logic-sharing resource savings)
   * fig7_*     — paper Fig. 7 analog (HCB chain schedule sweep)
   * tmcore_*   — TM datapath micro-benchmarks (train/infer steps)
-  * fusedinfer_* — fused single-pass inference kernel vs the unfused
-    two-kernel pipeline vs the jnp oracle (also written, with metadata,
-    to BENCH_fused_infer.json — the cross-PR perf trajectory file)
+  * fusedinfer_* — fused single-pass inference kernel vs the jnp oracle
+    (also written, with metadata, to BENCH_fused_infer.json — the perf
+    trajectory file)
   * fusedtrain_* — fused single-pass TRAINING kernel (clause fire ->
-    feedback -> TA delta in one pallas_call) vs the three-dispatch
-    pipeline vs the jnp oracle (-> BENCH_fused_train.json)
+    feedback -> TA delta in one pallas_call) vs the jnp oracle
+    (-> BENCH_fused_train.json)
   * sparseinfer_* — block-sparse compiled-schedule inference on a trained
     artifact vs the dense fused kernel vs the uncompiled bank
     (-> BENCH_sparse_infer.json; speedup scales with model sparsity)
@@ -83,9 +83,9 @@ def main() -> int:
                     help="also write the per-benchmark status JSON here")
     args = ap.parse_args()
 
-    from benchmarks import (fused_infer, fused_train, hcb_pipeline,
-                            logic_sharing, roofline_report, sparse_infer,
-                            table1_inference, term_infer)
+    from benchmarks import (fused_infer, fused_train, logic_sharing,
+                            roofline_report, sparse_infer, table1_inference,
+                            term_infer)
 
     # Per-benchmark status (name -> ok | skipped | "fail: <exc>") so the CI
     # log shows which benchmark actually ran — wall times alone can't
@@ -103,7 +103,6 @@ def main() -> int:
             traceback.print_exc()
 
     section("tmcore", _tm_core_micro)
-    section("hcb_pipeline", hcb_pipeline.run)
 
     def _fused_infer():
         r = fused_infer.run(fast=args.fast)

@@ -93,8 +93,7 @@ def run(n_model: int, reps: int = 3, full: bool = False) -> list:
         tag = f"b{B}_c{C}_l{L}_m{n_model}"
 
         one = jax.jit(lambda t, xx, yy, s: ops.tm_train_step_kernel(
-            cfg, t, xx, yy, s, fuse=True, use_kernel=True,
-            interpret=interpret)[0])
+            cfg, t, xx, yy, s, use_kernel=True, interpret=interpret)[0])
         step_sh = sharding.sharded_train_step_fn(
             cfg, mesh, engine="kernel", use_kernel=True, interpret=interpret)
         # equality gate: the bench refuses to record numbers for a schedule
@@ -115,7 +114,7 @@ def run(n_model: int, reps: int = 3, full: bool = False) -> list:
         ne = jnp.any(ta >= 0, -1).astype(jnp.uint8)
         lw = packetizer.pack_bits(tm.literals(X))
         one_f = jax.jit(lambda l, i, v, n: ops.tm_forward_packed(
-            l, i, v, n, use_kernel=True, interpret=interpret, fuse=True))
+            l, i, v, n, use_kernel=True, interpret=interpret))
         fwd_sh = sharding.sharded_forward_fn(
             mesh, use_kernel=True, interpret=interpret)
         np.testing.assert_array_equal(

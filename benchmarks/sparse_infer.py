@@ -19,8 +19,8 @@ the same request stream:
 The lead shape is the repo's edge-XL-width bank: B=512 requests x C=4096
 clauses over 4096 boolean features (W=256 literal words) — wide enough that
 a trained clause's ~20-bit chain leaves >90% of the dense word stream
-untouched.  Training uses the fast matmul engine (statistically equivalent
-feedback; the artifact's include statistics are what matter here).
+untouched.  The bank is trained with ``ops.tm_train_step_kernel`` (the
+artifact's include statistics are what matter here).
 
 Engines are timed in ISOLATED per-engine loops, the whole sweep run twice
 (see ``_time_isolated`` — a round-robin would charge whichever engine runs
@@ -60,7 +60,7 @@ _TRAIN_BATCH = 64
 
 
 def _train_artifact(n_features: int, n_classes: int, cpc: int, seed: int = 0):
-    """Train a TM with the matmul engine and compile it."""
+    """Train a TM with the training step and compile it."""
     cfg = tm.TMConfig(n_features=n_features, n_classes=n_classes,
                       clauses_per_class=cpc, threshold=50, s=10.0)
     X, y = make_boolean_classification(
@@ -70,7 +70,7 @@ def _train_artifact(n_features: int, n_classes: int, cpc: int, seed: int = 0):
     Xj, yj = jnp.asarray(X), jnp.asarray(y)
     state = tm.init(cfg, jax.random.PRNGKey(seed))
     step = jax.jit(
-        lambda ta, x, yy, s: ops.tm_train_step_matmul(cfg, ta, x, yy, s)[0]
+        lambda ta, x, yy, s: ops.tm_train_step_kernel(cfg, ta, x, yy, s)[0]
     )
     ta = state.ta_state
     k = 0

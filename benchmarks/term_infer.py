@@ -81,8 +81,8 @@ def _thermo_batch(n, *, seed, protos):
 
 
 def _train_artifact(seed: int = 0):
-    """Train a TM on word-aligned thermometer features (matmul engine) and
-    compile it; returns (cfg, protos, compiled)."""
+    """Train a TM on word-aligned thermometer features and compile it;
+    returns (cfg, protos, compiled)."""
     rng = np.random.default_rng(seed)
     protos = rng.normal(size=(LEAD["K"], LEAD["n_cont"]))
     X, y = _thermo_batch(_TRAIN_SAMPLES, seed=seed + 1, protos=protos)
@@ -91,7 +91,7 @@ def _train_artifact(seed: int = 0):
     Xj, yj = jnp.asarray(X), jnp.asarray(y)
     ta = tm.init(cfg, jax.random.PRNGKey(seed)).ta_state
     step = jax.jit(
-        lambda t_, x, yy, s: ops.tm_train_step_matmul(cfg, t_, x, yy, s)[0]
+        lambda t_, x, yy, s: ops.tm_train_step_kernel(cfg, t_, x, yy, s)[0]
     )
     k = 0
     n_batches = _TRAIN_SAMPLES // _TRAIN_BATCH

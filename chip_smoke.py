@@ -1,24 +1,30 @@
 """Chip smoke test: the TM main path once on a TPU, checked against oracles.
 
-    python chip_smoke.py            # one chip: train, then serve on every engine
-    python chip_smoke.py --chips 4  # four chips: only the --mesh model=4 paths
+    python chip_smoke.py            # one chip: train, serve, every engine
+    python chip_smoke.py --chips 4  # four chips: only the model=4 mesh paths
 
 One process, one JAX import, no child process.  It exits non-zero, printing
 no result, unless JAX's first device is a TPU and the script sits in a
-checkout of the repo.  Every phase goes through the launchers' entry points
-(``launch/train.py:train_tm``, ``launch/serve.py:serve_tm``) at the paper's
-tm-mnist width (784 features, 10 classes, 200 clauses per class):
+checkout of the repo.  At the paper's tm-mnist width (784 features, 10
+classes, 200 clauses per class):
 
   one chip
-    train   5 fused-kernel training steps; one step's (new_ta, delta) equal,
-            bit for bit, the same step on the XLA oracle path.
-    serve   2048 synthetic requests in buckets of 512, once per pinned
-            engine (factorized, sparse, dense, sparse with --early-exit):
-            no demotion, no failed probe, no shed, the pinned engine serves
-            every bucket, and every prediction equals the oracle engine's.
+    train    5 fused-kernel training steps through ``launch/train.py:
+             train_tm``; one step's (new_ta, delta) equal, bit for bit,
+             the same step on the XLA oracle path.
+    serve    2048 synthetic requests in buckets of 512 through
+             ``launch/serve.py:serve_tm`` on the default ladder
+             (``ops.engine_levels`` of the artifact): no demotion, no
+             failed probe, no shed, the ladder's head serves every bucket,
+             and every prediction equals the oracle engine's.
+    engines  each named kernel engine (factorized, sparse, dense) through
+             ``compiler.run_compiled`` on the same requests: class sums
+             equal the oracle's, and with early exit the predictions do.
   four chips (--chips 4)
-    mesh serve  the same checks with --mesh model=4 on each schedule
-                engine, against the one-device oracle.
+    mesh     each clause-sharded forward builder of ``core/sharding``
+             (factorized, sparse and dense schedules over model=4) equals
+             the one-device oracle's class sums; one ``--mesh model=4``
+             serve run on the default ladder passes the serve checks.
     mesh train  3 --mesh model=4 kernel steps equal 3 unsharded steps.
 
 Per-phase wall time (compilation included), engine and health are printed;
@@ -66,26 +72,38 @@ def serve_args(workdir: Path, *flags: str):
         "--artifact", str(workdir / f"{ARCH}.npz"), *flags])
 
 
-def oracle_preds(workdir: Path):
-    """The XLA oracle engine's argmax on serve_tm's request stream, from
-    the artifact the first serve run saved."""
-    import jax
+def requests():
+    """serve_tm's request stream, packed, as (N_REQUESTS, W) uint32."""
     import jax.numpy as jnp
     import numpy as np
 
     from repro.configs.matador_tm import TM_CONFIGS
-    from repro.core import compiler, packetizer
+    from repro.core import packetizer
     from repro.data import make_boolean_classification
 
     config = TM_CONFIGS[ARCH]
-    art = compiler.CompiledTM.load(str(workdir / f"{ARCH}.npz"))
     Xr, _ = make_boolean_classification(
         N_REQUESTS, config.n_features, config.n_classes, seed=2)
-    xp = np.asarray(packetizer.pack_literals(jnp.asarray(Xr)))
-    run = jax.jit(lambda xw: compiler.run_compiled(
-        art, xw, engine="oracle").argmax(-1))
-    return np.concatenate([np.asarray(run(jnp.asarray(xp[i:i + BUCKET])))
+    return np.asarray(packetizer.pack_literals(jnp.asarray(Xr)))
+
+
+def class_sums(fn, xp):
+    """``fn`` over the request stream, bucket by bucket."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    return np.concatenate([np.asarray(fn(jnp.asarray(xp[i:i + BUCKET])))
                            for i in range(0, N_REQUESTS, BUCKET)])
+
+
+def oracle_sums(art, xp):
+    """The XLA oracle engine's class sums on the request stream."""
+    import jax
+
+    from repro.core import compiler
+
+    return class_sums(jax.jit(lambda xw: compiler.run_compiled(
+        art, xw, engine="oracle")), xp)
 
 
 def check_serve(res, engine: str, want_preds):
@@ -159,37 +177,103 @@ def phase_train():
           f"({np.count_nonzero(o_delta)} nonzero delta cells)")
 
 
+def load_artifact(workdir: Path):
+    from repro.core import compiler
+
+    return compiler.CompiledTM.load(str(workdir / f"{ARCH}.npz"))
+
+
 def phase_serve(workdir: Path):
+    from repro.kernels import ops
     from repro.launch import serve
 
-    runs = [("factorized", ("--factorize",)),
-            ("sparse", ("--no-factorize",)),
-            ("dense", ("--no-sparse",)),
-            ("sparse", ("--no-factorize", "--early-exit"))]
-    want = None
-    for engine, flags in runs:
-        name = f"serve {engine} {' '.join(flags)}"
-        res = timed(name, lambda: serve.serve_tm(serve_args(workdir, *flags)))
-        if want is None:   # the first run trained and saved the artifact
-            want = timed("oracle predictions", lambda: oracle_preds(workdir))
-        check_serve(res, engine, want)
+    res = timed("serve, default ladder",
+                lambda: serve.serve_tm(serve_args(workdir)))
+    art = load_artifact(workdir)   # the serve run trained and saved it
+    levels = ops.engine_levels(art)
+    check(res["serve"]["ladder"] == levels,
+          f"serve ladder {res['serve']['ladder']} != {levels}")
+    want = timed("oracle class sums",
+                 lambda: oracle_sums(art, requests())).argmax(-1)
+    check_serve(res, levels[0], want)
+
+
+def phase_engines(workdir: Path):
+    import jax
+    import numpy as np
+
+    from repro.core import compiler
+
+    art = load_artifact(workdir)
+    xp = requests()
+    want = oracle_sums(art, xp)
+    for engine in ("factorized", "sparse", "dense"):
+        for early_exit in (False, True):
+            name = f"{engine}{' early-exit' if early_exit else ''}"
+            fn = jax.jit(lambda xw, e=engine, x=early_exit:
+                         compiler.run_compiled(art, xw, engine=e,
+                                               early_exit=x))
+            got = timed(f"run_compiled {name}", lambda: class_sums(fn, xp))
+            if early_exit:   # argmax-identical, not sum-identical
+                same = np.array_equal(got.argmax(-1), want.argmax(-1))
+            else:
+                same = np.array_equal(got, want)
+            check(same, f"{name}: differs from the oracle engine")
+            print(f"  {name} == oracle on all {N_REQUESTS} requests")
 
 
 def phase_mesh(workdir: Path):
+    import jax
+    import jax.numpy as jnp
     import numpy as np
+    from jax.sharding import NamedSharding, PartitionSpec as P
 
+    from repro.core import sharding
+    from repro.kernels import sparse_infer, term_infer
     from repro.launch import serve, train
+    from repro.launch.mesh import parse_mesh_spec
 
-    want = None
-    for engine, flags in [("factorized", ("--factorize",)),
-                          ("sparse", ("--no-factorize",)),
-                          ("dense", ("--no-sparse",))]:
-        res = timed(f"serve mesh-{engine} model=4", lambda: serve.serve_tm(
-            serve_args(workdir, "--mesh", "model=4", *flags)))
-        if want is None:
-            want = timed("oracle predictions (one device)",
-                         lambda: oracle_preds(workdir))
-        check_serve(res, f"mesh-{engine}", want)
+    res = timed("serve, --mesh model=4", lambda: serve.serve_tm(
+        serve_args(workdir, "--mesh", "model=4")))
+    art = load_artifact(workdir)
+    xp = requests()
+    want = timed("oracle class sums (one device)",
+                 lambda: oracle_sums(art, xp))
+    check_serve(res, res["serve"]["ladder"][0], want.argmax(-1))
+
+    mesh = parse_mesh_spec("model=4")
+
+    def place(stack):
+        return jax.device_put(stack, NamedSharding(
+            mesh, P("model", *[None] * (np.ndim(stack) - 1))))
+
+    word_ids = jnp.asarray(art.word_ids)
+    fs, terms, chains, votes, tiles, _ = term_infer.stack_shard_factorized(
+        art.include_words, art.votes, 4)
+    fwd = sharding.sharded_factorized_forward_fn(
+        mesh, block_t=fs[0].block_t, block_c=fs[0].block_c,
+        block_j=fs[0].block_j)
+    stacks = tuple(map(place, (terms, chains, votes, tiles)))
+    variants = {"factorized": jax.jit(
+        lambda xw: fwd(*stacks, xw[:, word_ids]))}
+    ss, schains, svotes, stiles, _ = sparse_infer.stack_shard_schedules(
+        art.include_words, art.votes, 4)
+    sfwd = sharding.sharded_schedule_forward_fn(
+        mesh, block_c=ss[0].block_c, block_j=ss[0].block_j)
+    sstacks = tuple(map(place, (schains, svotes, stiles)))
+    variants["sparse"] = jax.jit(lambda xw: sfwd(*sstacks, xw[:, word_ids]))
+    U = art.n_unique
+    Up = -(-U // 4) * 4
+    inc = place(np.pad(art.include_words, ((0, Up - U), (0, 0))))
+    dv = place(np.pad(art.votes, ((0, Up - U), (0, 0))))
+    ne = place(np.ones((Up,), np.uint8))
+    dfwd = sharding.sharded_forward_fn(mesh)
+    variants["dense"] = jax.jit(lambda xw: dfwd(inc, dv, ne, xw[:, word_ids]))
+    for name, fn in variants.items():
+        got = timed(f"mesh {name} model=4", lambda: class_sums(fn, xp))
+        check(np.array_equal(got, want),
+              f"mesh {name}: class sums differ from the one-device oracle")
+        print(f"  mesh {name} == oracle on all {N_REQUESTS} requests")
 
     base = ["--arch", ARCH, "--steps", "3", "--batch-size", "256",
             "--n-train", "2048", "--log-every", "1000"]
@@ -238,6 +322,7 @@ def main() -> None:
         else:
             phase_train()
             phase_serve(workdir)
+            phase_engines(workdir)
     finally:
         shutil.rmtree(workdir, ignore_errors=True)
     print(json.dumps({"ok": True, "device": {

@@ -4,13 +4,13 @@ BENCH_fused_train.json + BENCH_sparse_infer.json + BENCH_term_infer.json.
     PYTHONPATH=src python scripts/bench_smoke.py [--full] [--reps N]
         [--no-autotune] [--only {infer,train,sparse,term}]
 
-A CI-sized smoke of the fused single-pass TM kernels against their legacy
-pipelines and the jnp oracles on identical shapes:
+A CI-sized smoke of the fused single-pass TM kernels against the jnp
+oracles on identical shapes:
 
-  * inference (src/repro/kernels/fused_infer.py) vs the two-kernel
-    clause_eval -> class_sum pipeline -> ``BENCH_fused_infer.json``
+  * inference (src/repro/kernels/fused_infer.py) vs the oracle ->
+    ``BENCH_fused_infer.json``
   * training (src/repro/kernels/fused_train.py: clause fire -> feedback ->
-    TA delta in one pallas_call) vs the three-dispatch pipeline ->
+    TA delta in one pallas_call) vs the oracle ->
     ``BENCH_fused_train.json``
   * block-sparse compiled-schedule inference on a TRAINED artifact
     (src/repro/kernels/sparse_infer.py) vs the dense fused kernel vs the

@@ -22,12 +22,3 @@ from repro.core.compiler import (  # noqa: F401
 )
 from repro.core.train import eval_step, fit, train_step  # noqa: F401
 
-
-def __getattr__(name):
-    # EngineSpec/ENGINE_NAMES live in kernels/ops and are re-exported
-    # lazily through compiler — eager resolution here would re-open the
-    # kernels <-> core import cycle compiler.__getattr__ exists to break.
-    if name in ("EngineSpec", "ENGINE_NAMES"):
-        from repro.core import compiler
-        return getattr(compiler, name)
-    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")

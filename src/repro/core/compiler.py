@@ -1034,53 +1034,50 @@ def incremental_recompile(
     return new, info
 
 
-_UNSET = object()   # sentinel distinguishing "not passed" from None/False
+# the tiling keys each engine takes; the oracle has no tiling and takes
+# them all, so a call tuned for a kernel still runs on the last rung
+_ENGINE_BLOCKS = {
+    "factorized": {"block_c", "block_j", "block_s", "block_t", "term_w"},
+    "sparse": {"block_c", "block_j", "block_s"},
+    "dense": {"block_b", "block_c", "block_w"},
+    "conv": {"block_b"},
+}
+_ALL_BLOCKS = set().union(*_ENGINE_BLOCKS.values())
 
 
 def run_compiled(
     compiled: CompiledTM,
     x_packed: jnp.ndarray,
     *,
-    engine=None,
+    engine: str = "auto",
     interpret: bool | None = None,
     quality: int = 0,
     early_exit: bool = False,
-    use_kernel=_UNSET,
-    fuse=_UNSET,
-    sparse=_UNSET,
-    factorize=_UNSET,
     **blocks,
 ) -> jnp.ndarray:
     """Inference with the compiled artifact: (B, W_dense) packed literals ->
     (B, n_classes) int32 class sums.
 
-    The engine is selected by ``engine=`` — an ``ops.EngineSpec`` or one
-    of the :class:`ops.EngineLadder` level names ``"auto"`` (default) /
-    ``"factorized"`` / ``"sparse"`` / ``"dense"`` / ``"oracle"``.
-    ``"auto"`` defers to ``kernels/ops`` ambient resolution (kernels
-    on a TPU, or on CPU under ``REPRO_USE_PALLAS=1``; ``interpret=None``
-    compiles on TPU and interprets elsewhere) and, on the kernel path,
-    picks the two-level FACTORIZED schedule kernel
-    (``kernels/term_infer.py``: each unique AND term evaluated once per
-    sample slab) when the artifact's
-    ``partial_term_sharing`` clears ``FACTORIZE_SHARING_THRESHOLD``, else
-    the flat block-sparse chain kernel (``kernels/sparse_infer.py``); the
-    named engines pin the choice.  All engines are bit-identical.
-    Empty-clause masking is unnecessary here — compilation already
-    dropped empty clauses (the degenerate all-empty artifact keeps one
-    all-zero clause whose votes are zero).
+    ``engine`` is one of ``ops.ENGINE_NAMES``: ``"auto"`` (default) is
+    ``ops.engine_levels(compiled)[0]``, the head of the ladder the
+    artifact is served on; ``"factorized"`` the two-level shared-term
+    schedule kernel (``kernels/term_infer.py``), ``"sparse"`` the flat
+    block-sparse chain kernel (``kernels/sparse_infer.py``), ``"dense"``
+    the fused dense kernel (``kernels/fused_infer.py``), ``"conv"`` the
+    convolutional kernel (a convolutional artifact's one kernel), and
+    ``"oracle"`` the pure-jnp XLA reference.  All engines are
+    bit-identical.  ``interpret=None`` compiles the kernels on a TPU and
+    interprets them elsewhere.  Empty-clause masking is unnecessary here
+    — compilation already dropped empty clauses (the degenerate all-empty
+    artifact keeps one all-zero clause whose votes are zero).
 
-    The pre-``EngineSpec`` booleans (``use_kernel=``, ``fuse=``,
-    ``sparse=``, ``factorize=``) still work as deprecation shims emitting
-    ``DeprecationWarning``; they cannot be combined with ``engine=``.
-
-    Schedule-path tiling comes from ``blocks`` keys ``block_c``/``block_j``
-    (chain tiling, memoized on the artifact), ``block_s`` (sample slab),
-    and — factorized only — ``block_t``/``term_w`` (term-table tiling);
-    the dense paths keep their ``block_b``/``block_c``/``block_w``.
-    Under ``engine="auto"``, a caller that pins dense-only keys
-    (``block_b``/``block_w``) keeps the dense fused kernel — a dense-tuned
-    configuration must not be silently reinterpreted as a schedule tiling.
+    Tiling comes from ``blocks``: ``block_c``/``block_j`` (chain tiling,
+    memoized on the artifact) and ``block_s`` (sample slab) for the
+    schedule kernels, plus ``block_t``/``term_w`` (term-table tiling) for
+    the factorized one; ``block_b``/``block_c``/``block_w`` for the dense
+    kernel; ``block_b`` for the conv kernel.  A key the engine does not
+    take raises rather than being dropped; the oracle has no tiling and
+    ignores them.
 
     Anytime inference (``kernels/anytime.py``): ``quality > 0`` serves a
     budgeted tile prefix (error bounded by the artifact's margin
@@ -1090,85 +1087,44 @@ def run_compiled(
     engines always serve exact sums (a stronger answer than requested, so
     ladder degradation stays safe).
     """
-    import warnings
-
     from repro.kernels import ops
 
-    known = {"block_b", "block_c", "block_w", "block_j", "block_s",
-             "block_t", "term_w"}
-    unknown = blocks.keys() - known
+    if engine not in ops.ENGINE_NAMES:
+        raise ValueError(f"unknown engine {engine!r}; one of "
+                         f"{ops.ENGINE_NAMES}")
+    unknown = blocks.keys() - _ALL_BLOCKS
     if unknown:
-        # the per-path whitelists below would silently drop a typo like
-        # block_ww=8, serving at default tilings while the caller believes
-        # their tuning applied
+        # a typo like block_ww=8 would otherwise serve at default tilings
+        # while the caller believes their tuning applied
         raise TypeError(f"run_compiled: unknown block kwargs {sorted(unknown)}; "
-                        f"expected a subset of {sorted(known)}")
-    if compiled.geometry is not None:
-        return _run_conv(compiled, x_packed, engine, interpret, blocks,
-                         legacy=(use_kernel, fuse, sparse, factorize))
-
-    legacy = {name: v for name, v in (
-        ("use_kernel", use_kernel), ("fuse", fuse),
-        ("sparse", sparse), ("factorize", factorize)) if v is not _UNSET}
-    if legacy:
-        if engine is not None:
-            raise TypeError(
-                f"run_compiled: engine= cannot be combined with the "
-                f"deprecated kwargs {sorted(legacy)}")
-        warnings.warn(
-            f"run_compiled kwargs {sorted(legacy)} are deprecated; pass "
-            f"engine=EngineSpec(...) or one of {ops.ENGINE_NAMES} instead",
-            DeprecationWarning, stacklevel=2)
-        use_kernel = legacy.get("use_kernel")
-        fuse = legacy.get("fuse", True)
-        sparse = legacy.get("sparse")
-        factorize = legacy.get("factorize")
-        uk, it = ops.kernel_dispatch(use_kernel, interpret)
-    else:
-        spec = ops.EngineSpec.coerce(engine)
-        if spec.name == "conv":
-            raise TypeError("run_compiled: engine 'conv' runs convolutional "
-                            "artifacts; this one is a vanilla TM")
-        use_kernel, interpret, fuse, sparse, factorize = (
-            spec.resolve(interpret))
-        if spec.name == "auto":
-            uk, it = ops.kernel_dispatch(use_kernel, interpret)
-        else:
-            # named engines already resolved use_kernel; only interpret
-            # still follows the ambient backend default
-            uk, it = use_kernel, ops.kernel_dispatch(None, interpret)[1]
+                        f"expected a subset of {sorted(_ALL_BLOCKS)}")
+    if engine == "auto":
+        engine = ops.engine_levels(compiled)[0]
+    conv = compiled.geometry is not None
+    if conv and engine not in ("conv", "oracle"):
+        raise TypeError(
+            f"run_compiled: engine {engine!r} runs vanilla TM artifacts; "
+            "a convolutional one runs on 'conv' or 'oracle'")
+    if not conv and engine == "conv":
+        raise TypeError("run_compiled: engine 'conv' runs convolutional "
+                        "artifacts; this one is a vanilla TM")
+    takes = _ALL_BLOCKS if engine == "oracle" else _ENGINE_BLOCKS[engine]
+    extra = blocks.keys() - takes
+    if extra:
+        raise TypeError(f"run_compiled: engine {engine!r} takes no tiling "
+                        f"keys {sorted(extra)}; it takes {sorted(takes)}")
+    uk = engine != "oracle"
+    _, it = ops.kernel_dispatch(None, interpret)
+    if conv:
+        return ops.conv_tm_forward_packed(
+            x_packed, compiled.include_words, compiled.votes,
+            geom=compiled.geometry,
+            operands=compiled.conv_operands() if uk else None,
+            use_kernel=uk, interpret=it, **blocks)
 
     xw = x_packed[:, jnp.asarray(compiled.word_ids)]        # dead-word elim
     votes = jnp.asarray(compiled.votes)
-    if sparse is None:
-        # the chain schedules ride the fused default, unless the caller
-        # passed a dense-kernel tiling
-        sparse = fuse and not ({"block_b", "block_w"} & blocks.keys())
-    fact_keys = {"block_t", "term_w"} & blocks.keys()
-    if factorize is None:
-        # heuristic default: factorized execution pays when enough terms
-        # are shared for stage 1 to amortize (the compiler measured it);
-        # a factorized-only tiling key pins the factorized kernel the same
-        # way a dense-only key pins the dense one — a tuned configuration
-        # must not be silently reinterpreted
-        factorize = sparse and (
-            bool(fact_keys)
-            or compiled.stats.partial_term_sharing
-            >= FACTORIZE_SHARING_THRESHOLD
-        )
-    elif not factorize and fact_keys:
-        raise TypeError(
-            f"run_compiled: factorize=False but factorized-only block "
-            f"kwargs {sorted(fact_keys)} were passed — they would be "
-            "silently dropped")
-    if factorize and not (fuse and sparse):
-        # the docstring promises factorize=True pins the factorized
-        # engine; serving the dense kernel instead must fail loudly
-        raise TypeError(
-            "run_compiled: factorize=True requires the schedule path "
-            "(fuse=True and sparse not pinned off via sparse=False or a "
-            "dense-kernel tiling)")
-    if uk and fuse and sparse and factorize:
+    if engine == "factorized":
         ftiling = dict(block_c=blocks.get("block_c"),
                        block_j=blocks.get("block_j"),
                        block_t=blocks.get("block_t"),
@@ -1187,7 +1143,7 @@ def run_compiled(
             use_kernel=True, interpret=it,
             block_s=blocks.get("block_s"), tile_margin=margin,
         )
-    if uk and fuse and sparse:
+    if engine == "sparse":
         stiling = dict(block_c=blocks.get("block_c"),
                        block_j=blocks.get("block_j"))
         if quality > 0:
@@ -1203,43 +1159,10 @@ def run_compiled(
             use_kernel=True, interpret=it,
             block_s=blocks.get("block_s"), tile_margin=margin,
         )
-    inc = jnp.asarray(compiled.include_words)
-    dense_blocks = {k: v for k, v in blocks.items()
-                    if k in ("block_b", "block_c", "block_w")}
     return ops.tm_forward_packed(
-        xw, inc, votes, None,
-        use_kernel=uk, interpret=it, fuse=fuse, **dense_blocks,
+        xw, jnp.asarray(compiled.include_words), votes, None,
+        use_kernel=uk, interpret=it, **blocks,
     )
-
-
-def _run_conv(compiled: CompiledTM, x_packed, engine, interpret, blocks,
-              legacy) -> jnp.ndarray:
-    """A convolutional artifact: (B, Wr) packed images -> (B, K) class
-    sums on the ``"conv"`` kernel or the ``"oracle"`` (``"auto"`` follows
-    the ambient dispatch).  Always exact: there is no anytime prefix."""
-    from repro.kernels import ops
-
-    if any(v is not _UNSET for v in legacy):
-        raise TypeError("run_compiled: the deprecated engine kwargs do not "
-                        "apply to a convolutional artifact; pass engine=")
-    spec = ops.EngineSpec.coerce(engine)
-    if spec.name not in ("auto", "conv", "oracle"):
-        raise TypeError(
-            f"run_compiled: engine {spec.name!r} runs vanilla TM artifacts; "
-            "a convolutional one runs on 'conv' or 'oracle'")
-    extra = blocks.keys() - {"block_b"}
-    if extra:
-        raise TypeError(f"run_compiled: the conv kernel tiles only "
-                        f"block_b; got {sorted(extra)}")
-    use_kernel = {"conv": True, "oracle": False}.get(spec.name,
-                                                     spec.use_kernel)
-    uk, it = ops.kernel_dispatch(
-        use_kernel, spec.interpret if interpret is None else interpret)
-    return ops.conv_tm_forward_packed(
-        x_packed, compiled.include_words, compiled.votes,
-        geom=compiled.geometry,
-        operands=compiled.conv_operands() if uk else None,
-        use_kernel=uk, interpret=it, **blocks)
 
 
 def predict_compiled(compiled: CompiledTM, x: jnp.ndarray, **kw) -> jnp.ndarray:
@@ -1248,15 +1171,3 @@ def predict_compiled(compiled: CompiledTM, x: jnp.ndarray, **kw) -> jnp.ndarray:
     xp = (packetizer.pack_bits(x) if compiled.geometry is not None
           else packetizer.pack_literals(x))
     return jnp.argmax(run_compiled(compiled, xp, **kw), axis=-1)
-
-
-# Re-exported so engine selection and artifact execution come from one
-# module (serve and the tests spell ``compiler.EngineSpec``).  Lazy (PEP
-# 562) rather than a plain import: ``kernels/ops`` pulls the whole kernel
-# stack in, and the kernel modules import ``repro.core`` — an eager import
-# here is circular whenever a kernel module is the first thing imported.
-def __getattr__(name):
-    if name in ("EngineSpec", "ENGINE_NAMES"):
-        from repro.kernels import ops
-        return getattr(ops, name)
-    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")

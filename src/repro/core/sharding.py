@@ -46,32 +46,6 @@ def data_axes(mesh: Mesh) -> tuple:
     return tuple(a for a in ("pod", "data") if a in mesh.axis_names)
 
 
-def _engine_dispatch(engine, use_kernel, interpret, *, allowed,
-                     fuse: bool = True) -> tuple:
-    """Resolve a forward builder's ``(use_kernel, interpret, fuse)`` from
-    either an ``ops.EngineSpec``/name (the high-level vocabulary) or the
-    low-level ``use_kernel``/``interpret`` overrides — not both.  Each
-    builder already names its kernel, so only the engines it can actually
-    build (``allowed``) plus ``"auto"``/``"oracle"`` are accepted: asking
-    the dense-fused builder for ``"sparse"`` would silently build the
-    wrong schedule."""
-    from repro.kernels import ops
-
-    if engine is None:
-        uk, it = ops.kernel_dispatch(use_kernel, interpret)
-        return uk, it, fuse
-    if use_kernel is not None:
-        raise TypeError("pass engine= or use_kernel=, not both")
-    spec = ops.EngineSpec.coerce(engine)
-    if spec.name not in allowed:
-        raise ValueError(
-            f"engine {spec.name!r} does not apply to this sharded builder; "
-            f"one of {allowed}")
-    uk_s, it_s, fuse_s, _, _ = spec.resolve(interpret)
-    uk, it = ops.kernel_dispatch(uk_s, it_s)
-    return uk, it, fuse_s
-
-
 def tm_shardings(config: tm.TMConfig, mesh: Mesh):
     """(state_sharding, batch_sharding) for the TM train/serve steps."""
     d = data_axes(mesh)
@@ -83,17 +57,17 @@ def tm_shardings(config: tm.TMConfig, mesh: Mesh):
     return state, batch
 
 
-def sharded_forward_fn(mesh: Mesh, *, engine=None,
+def sharded_forward_fn(mesh: Mesh, *,
                        use_kernel: bool | None = None,
-                       interpret: bool | None = None, fuse: bool = True,
+                       interpret: bool | None = None,
                        blocks: dict | None = None):
     """Clause-sharded fused forward: (inc_words, votes, nonempty,
     lit_words) -> (B, K) int32 GLOBAL class sums.
 
     An explicit ``shard_map`` schedule: each ``model`` shard evaluates its
     local clause bank with the fused single-pass inference kernel (or the
-    oracle, per ``engine`` — ``"auto"``/``"dense"``/``"oracle"``, or the
-    low-level ``use_kernel`` override) — the full bank never needs to fit
+    oracle, per ``ops.kernel_dispatch(use_kernel, interpret)``) — the
+    full bank never needs to fit
     one core's VMEM — and one int32 ``psum`` over ``model`` completes the
     adder bank.  Exact: integer partial sums compose bit-identically to
     the unsharded kernel.  Shape-agnostic (works for dense banks and
@@ -102,15 +76,13 @@ def sharded_forward_fn(mesh: Mesh, *, engine=None,
     """
     from repro.kernels import ops
 
-    uk, it, fuse = _engine_dispatch(engine, use_kernel, interpret,
-                                    allowed=("auto", "dense", "oracle"),
-                                    fuse=fuse)
+    uk, it = ops.kernel_dispatch(use_kernel, interpret)
     d = data_axes(mesh)
 
     def body(inc_loc, votes_loc, ne_loc, lw_loc):
         sums = ops.tm_forward_packed(
             lw_loc, inc_loc, votes_loc, ne_loc,
-            use_kernel=uk, interpret=it, fuse=fuse, **(blocks or {}),
+            use_kernel=uk, interpret=it, **(blocks or {}),
         )
         return jax.lax.psum(sums, "model")
 
@@ -126,7 +98,6 @@ def sharded_forward_fn(mesh: Mesh, *, engine=None,
 def sharded_schedule_forward_fn(mesh: Mesh, *,
                                 block_c: int, block_j: int,
                                 block_s: int | None = None,
-                                engine=None,
                                 use_kernel: bool | None = None,
                                 interpret: bool | None = None):
     """Clause-sharded COMPILED-SCHEDULE forward: each ``model`` shard owns
@@ -146,8 +117,7 @@ def sharded_schedule_forward_fn(mesh: Mesh, *,
     """
     from repro.kernels import ops, sparse_infer
 
-    uk, it, _ = _engine_dispatch(engine, use_kernel, interpret,
-                                 allowed=("auto", "sparse", "oracle"))
+    uk, it = ops.kernel_dispatch(use_kernel, interpret)
     d = data_axes(mesh)
     bs = block_s or sparse_infer.DEFAULT_BLOCK_S
 
@@ -175,7 +145,6 @@ def sharded_schedule_forward_fn(mesh: Mesh, *,
 def sharded_factorized_forward_fn(mesh: Mesh, *,
                                   block_t: int, block_c: int, block_j: int,
                                   block_s: int | None = None,
-                                  engine=None,
                                   use_kernel: bool | None = None,
                                   interpret: bool | None = None):
     """Clause-sharded FACTORIZED-schedule forward: each ``model`` shard
@@ -196,8 +165,7 @@ def sharded_factorized_forward_fn(mesh: Mesh, *,
     """
     from repro.kernels import ops, term_infer
 
-    uk, it, _ = _engine_dispatch(engine, use_kernel, interpret,
-                                 allowed=("auto", "factorized", "oracle"))
+    uk, it = ops.kernel_dispatch(use_kernel, interpret)
     d = data_axes(mesh)
     bs = block_s or term_infer.DEFAULT_BLOCK_S
 
@@ -226,9 +194,8 @@ def sharded_factorized_forward_fn(mesh: Mesh, *,
 
 
 def sharded_predict_fn(config: tm.TMConfig, mesh: Mesh, *,
-                       engine=None,
                        use_kernel: bool | None = None,
-                       interpret: bool | None = None, fuse: bool = True,
+                       interpret: bool | None = None,
                        blocks: dict | None = None):
     """Build a jit'd sharded inference fn: packed literals -> class ids.
 
@@ -239,11 +206,9 @@ def sharded_predict_fn(config: tm.TMConfig, mesh: Mesh, *,
     on the wire).  Otherwise GSPMD turns the vote matmul into a local
     matmul + all-reduce over ``model`` of the (B, K) partial sums.
     """
-    from repro.kernels import ops
+    from repro.kernels import ops, ref
 
-    uk, it, fuse = _engine_dispatch(engine, use_kernel, interpret,
-                                    allowed=("auto", "dense", "oracle"),
-                                    fuse=fuse)
+    uk, it = ops.kernel_dispatch(use_kernel, interpret)
     d = data_axes(mesh)
     votes_s = NamedSharding(mesh, P("model", None))
     inc_s = NamedSharding(mesh, P("model", None))
@@ -252,14 +217,14 @@ def sharded_predict_fn(config: tm.TMConfig, mesh: Mesh, *,
 
     if uk:
         fwd = sharded_forward_fn(mesh, use_kernel=uk, interpret=it,
-                                 fuse=fuse, blocks=blocks)
+                                 blocks=blocks)
 
         def predict(inc_words, votes, nonempty, lit_words):
             return jnp.argmax(fwd(inc_words, votes, nonempty, lit_words),
                               axis=-1)
     else:
         def predict(inc_words, votes, nonempty, lit_words):
-            fired = ops.clause_fire(lit_words, inc_words, use_kernel=False)
+            fired = ref.clause_fire_ref(lit_words, inc_words)
             fired = fired * nonempty[None, :].astype(fired.dtype)
             sums = fired.astype(jnp.int32) @ votes
             return jnp.argmax(sums, axis=-1)
@@ -273,12 +238,10 @@ def sharded_predict_fn(config: tm.TMConfig, mesh: Mesh, *,
 
 def sharded_train_step_fn(config: tm.TMConfig, mesh: Mesh,
                           batch_chunk: int | None = 2048,
-                          algorithm: str = "bitwise",
                           *,
                           engine: str = "gspmd",
                           use_kernel: bool | None = None,
                           interpret: bool | None = None,
-                          fuse: bool = True,
                           blocks: dict | None = None):
     """Build a jit'd sharded batch training step.
 
@@ -295,7 +258,7 @@ def sharded_train_step_fn(config: tm.TMConfig, mesh: Mesh,
         pure layout.
       * ``"kernel"`` — explicit ``shard_map`` schedule running
         ``ops.tm_train_step_kernel`` per shard (the fused two-launch Pallas
-        pipeline when the kernel path is active; ``fuse``/``use_kernel``/
+        pipeline when the kernel path is active; ``use_kernel``/
         ``interpret``/``blocks`` pass through).  Collectives: one int32
         (B, K) class-sum ``psum`` over ``model`` + one int32 (C_loc, L)
         delta ``psum`` over ``data``.  Bit-identical to the single-device
@@ -303,9 +266,6 @@ def sharded_train_step_fn(config: tm.TMConfig, mesh: Mesh,
         runtime ``b_offset``/``c_offset`` scalars.  Requires the clause
         axis divisible by the ``model`` axis size (``clause_pad_multiple``)
         and the batch by the data axes.
-
-    ``algorithm="matmul"`` selects the beyond-paper binomial-aggregation
-    step (its own shard_map schedule; statistically, not bitwise, exact).
     """
     if engine not in ("gspmd", "kernel"):
         # all engines are bit-identical, so a silent fallthrough on a typo
@@ -318,33 +278,12 @@ def sharded_train_step_fn(config: tm.TMConfig, mesh: Mesh,
             f"model axis ({mesh.shape['model']}); align via "
             "clause_pad_multiple")
     d = data_axes(mesh)
-    # matmul path: automata sharded over BOTH axes (clauses x literals): the
-    # step all-gathers the int8 states over `data` (34 MB at pod scale) and
-    # GSPMD reduce-scatters the f32 delta — far less wire than all-reducing
-    # the dense delta against data-replicated states.
-    lit_shard = d if algorithm == "matmul" else None
-    state_s = NamedSharding(mesh, P("model", lit_shard))
+    state_s = NamedSharding(mesh, P("model", None))
     x_s = NamedSharding(mesh, P(d, None))
     y_s = NamedSharding(mesh, P(d))
 
     def step(ta_state, x, y, seed):
         from repro.kernels import ops
-
-        if algorithm == "matmul":   # beyond-paper binomial-aggregation path
-            # explicit shard_map schedule: GSPMD falls back to a dense f32
-            # delta all-reduce here; the hand schedule is AG(int8) + two tiny
-            # psums + psum_scatter (see EXPERIMENTS.md §Perf, TM cell)
-            data_ax = d[-1] if d else "data"
-
-            return jax.shard_map(
-                lambda ta, xx, yy: ops.tm_train_step_matmul_local(
-                    config, ta, xx, yy, seed
-                ),
-                mesh=mesh,
-                in_specs=(P("model", data_ax), P(d, None), P(d)),
-                out_specs=P("model", data_ax),
-                check_vma=False,
-            )(ta_state, x, y)
 
         if engine == "kernel":
             # explicit clause-sharded shard_map schedule around the fused
@@ -362,7 +301,7 @@ def sharded_train_step_fn(config: tm.TMConfig, mesh: Mesh,
                 b_off = b_off * jnp.uint32(B_loc)
                 _, delta = ops.tm_train_step_kernel(
                     config, ta_loc, xx, yy, seed,
-                    batch_chunk=batch_chunk, fuse=fuse, blocks=blocks,
+                    batch_chunk=batch_chunk, blocks=blocks,
                     b_offset=b_off, c_offset=c_off,
                     c_total=config.n_clauses_total,
                     sums_reduce=lambda s: jax.lax.psum(s, "model"),

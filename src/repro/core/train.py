@@ -35,12 +35,12 @@ def train_step(
     return new_state, metrics
 
 
-@functools.partial(jax.jit, static_argnums=(0, 5, 6), donate_argnums=1)
+@functools.partial(jax.jit, static_argnums=(0, 5), donate_argnums=1)
 def train_step_kernel(
     config: tm.TMConfig, state: tm.TMState, x: jax.Array, y: jax.Array,
-    seed: jax.Array, batch_chunk: int | None = None, fuse: bool = True,
+    seed: jax.Array, batch_chunk: int | None = None,
 ) -> Tuple[tm.TMState, dict]:
-    """Kernel-path batch step (hash RNG; fused Pallas pipeline by default).
+    """Kernel-path batch step (hash RNG; fused Pallas pipeline).
 
     Same contract as :func:`train_step` but driven by ``ops.
     tm_train_step_kernel`` — on the kernel path the whole step is two
@@ -53,7 +53,7 @@ def train_step_kernel(
 
     new_ta, delta = ops.tm_train_step_kernel(
         config, state.ta_state, x, y, seed,
-        batch_chunk=batch_chunk, fuse=fuse,
+        batch_chunk=batch_chunk,
     )
     new_state = tm.TMState(ta_state=new_ta, steps=state.steps + 1)
     metrics = {

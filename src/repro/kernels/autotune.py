@@ -75,7 +75,7 @@ TIMING_RUNS = 0
 # them all): the word chain unrolls block_w steps over a (block_c,
 # block_b) tile, so block_w stays <= 64 and the tile <= 512 x 512.
 _DEFAULT_CANDIDATES = (
-    (128, 128, 64),   # clause_eval.py's defaults (VMEM-lean)
+    (128, 128, 64),   # VMEM-lean
     (128, 256, 64),   # wider clause bank: fewer adder-fold steps
     (256, 128, 64),   # taller request slab: fewer batch steps
     (256, 256, 32),

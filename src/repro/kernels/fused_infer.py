@@ -2,8 +2,8 @@
 
 This is the whole MATADOR inference datapath of paper Fig. 5 in ONE
 ``pallas_call`` — the Hard-Coded Clause Block chain feeding the class-sum
-adder bank with no off-chip traffic in between.  The unfused pipeline
-(``clause_eval.py`` then ``class_sum.py``) materializes the full ``(B, C)``
+adder bank with no off-chip traffic in between.  A clause-eval kernel
+followed by a vote-tally kernel would materialize the full ``(B, C)``
 fired matrix in HBM; the eFPGA (arXiv:2502.07823) and 65-nm ASIC
 (arXiv:2501.19347) TM accelerators both keep clause outputs on-chip, and so
 does this kernel: the clause state lives in VMEM scratch and is folded into

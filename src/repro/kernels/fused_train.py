@@ -1,9 +1,9 @@
 """Pallas TPU kernel: fused single-pass TM training step delta.
 
-The unfused training step runs three dispatches with two ``(B, C)`` HBM
-round-trips in between::
+The oracle training step (``ref.py``) runs three stages with two
+``(B, C)`` arrays in between::
 
-    clause_fire -> fire (HBM) -> feedback_plan -> ftype (HBM) -> ta_delta
+    clause_fire_ref -> fire -> feedback_plan -> ftype -> ta_delta_ref
 
 Here the whole chain runs in ONE ``pallas_call``: the clause-fire word
 chain is evaluated into VMEM scratch (exactly the fused-inference HCB
@@ -162,8 +162,8 @@ def _fused_train_kernel(
     def _init_block():
         # the clause block's sample-independent work, once per batch sweep:
         # the automaton hash's first round, (c * L + l) * H1 + seed, indexed
-        # by LOCAL clause id — matching the unfused composition, where
-        # ta_delta runs on the local shard — unless ``global_clause`` (the
+        # by LOCAL clause id — matching the oracle composition, where
+        # ta_delta_ref runs on the local shard — unless ``global_clause`` (the
         # clause-sharded trainer), which indexes by GLOBAL clause id so every
         # shard reproduces exactly the full bank's draws for its rows.
         out_ref[...] = jnp.zeros_like(out_ref)
@@ -326,7 +326,7 @@ def fused_tm_train_delta(
 ) -> jax.Array:
     """Batch-summed feedback delta -> (C, L) int32, single fused pass.
 
-    Bit-identical to the unfused three-dispatch composition::
+    Bit-identical to the oracle composition::
 
         fire  = clause_fire_ref(lit_words, inc_words)
         ftype = feedback_select(y, kn, p_t, p_n, clause_class, clause_pol,

@@ -6,13 +6,16 @@ the platform: on a TPU the kernels are the default engine and run compiled
 (XLA-compiled, fast), and the kernels run in Pallas interpret mode, where
 the tests validate them bit-exactly against those oracles.
 
-``REPRO_USE_PALLAS=1`` makes the kernels the default on CPU too.
+Which inference engine serves a compiled artifact is decided in one place,
+:func:`engine_levels`, from the platform and the artifact.
+``REPRO_USE_PALLAS=1``, read once at import, makes the kernels the default
+on CPU too, the way a TPU does; it never picks among the kernels.  The CPU
+drills use it to put the kernel ladder under the launchers.
 """
 
 from __future__ import annotations
 
 import contextlib
-import dataclasses
 import functools
 import os
 import threading
@@ -23,14 +26,11 @@ from jax.profiler import TraceAnnotation
 
 from repro.runtime import faults
 
-from repro.kernels import class_sum as _class_sum_kernel
-from repro.kernels import clause_eval as _clause_eval_kernel
 from repro.kernels import conv_infer as _conv_infer_kernel
 from repro.kernels import fused_infer as _fused_infer_kernel
 from repro.kernels import fused_train as _fused_train_kernel
 from repro.kernels import ref
 from repro.kernels import sparse_infer as _sparse_infer_kernel
-from repro.kernels import ta_update as _ta_update_kernel
 from repro.kernels import term_infer as _term_infer_kernel
 from repro.kernels import xnor_popcount as _xnor_kernel
 
@@ -56,111 +56,25 @@ def kernel_dispatch(use_kernel=None, interpret=None):
 ENGINE_NAMES = ("auto", "factorized", "sparse", "dense", "conv", "oracle")
 
 
-@dataclasses.dataclass(frozen=True)
-class EngineSpec:
-    """One inference-engine selection, replacing the old ``use_kernel=/
-    sparse=/factorize=`` boolean sprawl on ``core.compiler.run_compiled``.
-
-    ``name`` uses the :class:`EngineLadder` level vocabulary — serve's
-    degradation ladder and the library share one set of words:
-
-    * ``"auto"`` — ambient dispatch (:func:`kernel_dispatch`: kernels on
-      a TPU, or on CPU under ``REPRO_USE_PALLAS=1``); on the kernel path
-      the schedule heuristics pick factorized vs sparse exactly as before.
-    * ``"factorized"`` — the two-level shared-term schedule kernel.
-    * ``"sparse"`` — the flat block-sparse chain schedule kernel.
-    * ``"dense"`` — the fused dense kernel (``fuse=False`` for the legacy
-      two-kernel pipeline).
-    * ``"conv"`` — the convolutional kernel (``kernels/conv_infer.py``),
-      the one kernel of a convolutional artifact.
-    * ``"oracle"`` — the pure-jnp XLA reference path.
-
-    Named kernel engines pin ``use_kernel=True`` (that is what naming them
-    means); ``"oracle"`` pins ``use_kernel=False``.  ``use_kernel`` on the
-    spec is only meaningful for ``"auto"``, where it overrides the ambient
-    default; a contradiction (e.g. ``"sparse"`` with ``use_kernel=False``)
-    raises rather than silently serving a different engine.  ``interpret``
-    rides along as the spec's default, overridden by a call-site
-    ``interpret=``.
-    """
-
-    name: str = "auto"
-    use_kernel: bool | None = None
-    interpret: bool | None = None
-    fuse: bool = True
-
-    def __post_init__(self):
-        if self.name not in ENGINE_NAMES:
-            raise ValueError(
-                f"unknown engine {self.name!r}; one of {ENGINE_NAMES}")
-        if self.name == "oracle" and self.use_kernel:
-            raise ValueError("engine 'oracle' is the non-kernel path; "
-                             "use_kernel=True contradicts it")
-        if (self.name in ("factorized", "sparse", "dense", "conv")
-                and self.use_kernel is False):
-            raise ValueError(
-                f"engine {self.name!r} names a Pallas kernel; "
-                "use_kernel=False contradicts it")
-        if self.name == "factorized" and not self.fuse:
-            raise ValueError("engine 'factorized' has no unfused form")
-        if self.name == "sparse" and not self.fuse:
-            raise ValueError("engine 'sparse' has no unfused form")
-
-    @classmethod
-    def coerce(cls, spec) -> "EngineSpec":
-        """``None`` -> auto; a level-name string -> that engine; an
-        ``EngineSpec`` passes through."""
-        if spec is None:
-            return cls()
-        if isinstance(spec, cls):
-            return spec
-        if isinstance(spec, str):
-            return cls(name=spec)
-        raise TypeError(
-            f"engine must be an EngineSpec or one of {ENGINE_NAMES}, "
-            f"got {type(spec).__name__}")
-
-    def resolve(self, interpret: bool | None = None) -> tuple:
-        """Legacy dispatch tuple ``(use_kernel, interpret, fuse, sparse,
-        factorize)`` consumed by ``run_compiled``'s engine body; call-site
-        ``interpret`` wins over the spec's."""
-        it = self.interpret if interpret is None else interpret
-        if self.name == "factorized":
-            return True, it, True, True, True
-        if self.name == "sparse":
-            return True, it, True, True, False
-        if self.name in ("dense", "conv"):
-            return True, it, self.fuse, False, False
-        if self.name == "oracle":
-            return False, it, self.fuse, False, False
-        return self.use_kernel, it, self.fuse, None, None
-
-
-def engine_levels(compiled, use_kernel: bool | None = None, *,
-                  sparse: bool = True,
-                  factorize: bool | None = None) -> list:
+def engine_levels(compiled, use_kernel: bool | None = None) -> list:
     """The engine ladder a compiled artifact is served on, preferred
-    engine first, chosen from the artifact itself: a convolutional
-    artifact runs its kernel, then the oracle; a vanilla one the factorized
-    schedule kernel (when its measured term sharing clears the compiler's
-    threshold), the sparse and dense kernels, then the oracle.  Off the
-    kernel path (:func:`kernel_dispatch`) only the oracle is left.
+    engine first: the one decision of which inference engine runs, made
+    from the platform (:func:`kernel_dispatch`) and the artifact alone.
 
-    Pins for a vanilla artifact: ``sparse=False`` leaves the dense kernel
-    alone above the oracle; ``factorize`` True or False takes the
-    factorized kernel or leaves it out whatever the sharing."""
+    A convolutional artifact runs its kernel, then the oracle; a vanilla
+    one the factorized schedule kernel (when its measured term sharing
+    clears ``compiler.FACTORIZE_SHARING_THRESHOLD``), the sparse and
+    dense kernels, then the oracle.  Off the kernel path only the oracle
+    is left.  ``run_compiled(engine="auto")`` serves ``levels[0]``."""
     from repro.core import compiler
 
     uk, _ = _resolve(use_kernel, None)
     if compiled.geometry is not None:
         return (["conv"] if uk else []) + ["oracle"]
-    sparse = uk and sparse
-    if factorize is None:
-        factorize = (compiled.stats.partial_term_sharing
-                     >= compiler.FACTORIZE_SHARING_THRESHOLD)
-    return ((["factorized"] if sparse and factorize else [])
-            + (["sparse"] if sparse else [])
-            + (["dense"] if uk else []) + ["oracle"])
+    factorize = (compiled.stats.partial_term_sharing
+                 >= compiler.FACTORIZE_SHARING_THRESHOLD)
+    return ((["factorized"] if uk and factorize else [])
+            + (["sparse", "dense"] if uk else []) + ["oracle"])
 
 
 class EngineLadder:
@@ -332,57 +246,6 @@ class EngineLadder:
             return out
 
 
-def clause_fire(
-    lit_words: jax.Array,
-    inc_words: jax.Array,
-    *,
-    use_kernel: bool | None = None,
-    interpret: bool | None = None,
-    **blocks,
-) -> jax.Array:
-    """(B, W) x (C, W) packed -> (B, C) int8 clause outputs."""
-    use_kernel, interpret = _resolve(use_kernel, interpret)
-    if use_kernel:
-        return _clause_eval_kernel.clause_fire(
-            lit_words, inc_words, interpret=interpret, **blocks
-        )
-    return ref.clause_fire_ref(lit_words, inc_words)
-
-
-def class_sums(
-    fired: jax.Array,
-    votes: jax.Array,
-    *,
-    use_kernel: bool | None = None,
-    interpret: bool | None = None,
-    **blocks,
-) -> jax.Array:
-    use_kernel, interpret = _resolve(use_kernel, interpret)
-    if use_kernel:
-        return _class_sum_kernel.class_sum(fired, votes, interpret=interpret, **blocks)
-    return ref.class_sum_ref(fired, votes)
-
-
-def ta_delta(
-    ta, lits, fire, ftype, seed, *, p_act, p_inact, b_offset=0,
-    c_offset=0, c_total=None,
-    use_kernel: bool | None = None,
-    interpret: bool | None = None,
-    **blocks,
-) -> jax.Array:
-    use_kernel, interpret = _resolve(use_kernel, interpret)
-    if use_kernel:
-        return _ta_update_kernel.ta_delta(
-            ta, lits, fire, ftype, seed,
-            p_act=p_act, p_inact=p_inact, b_offset=b_offset,
-            c_offset=c_offset, c_total=c_total,
-            interpret=interpret, **blocks,
-        )
-    return ref.ta_delta_ref(ta, lits, fire, ftype, seed, p_act=p_act,
-                            p_inact=p_inact, b_offset=b_offset,
-                            c_offset=c_offset, c_total=c_total)
-
-
 def xnor_dot(
     a_words, w_words, n_bits: int,
     *,
@@ -410,7 +273,6 @@ def tm_forward_packed(
     *,
     use_kernel: bool | None = None,
     interpret: bool | None = None,
-    fuse: bool = True,
     autotune: bool = False,
     **blocks,
 ) -> jax.Array:
@@ -418,13 +280,13 @@ def tm_forward_packed(
 
     Kernel path (``use_kernel=True`` or ``REPRO_USE_PALLAS=1``) runs the
     fused single-pass kernel (``fused_infer.py``) — clause eval and vote
-    accumulation in one ``pallas_call``, no (B, C) fired matrix in HBM.
-    ``fuse=False`` keeps the legacy two-kernel pipeline; the oracle path is
-    the default execution engine off-TPU.  ``autotune=True`` (kernel path,
-    no explicit blocks) picks block sizes via ``autotune.py``'s cached sweep.
+    accumulation in one ``pallas_call``, no (B, C) fired matrix in HBM;
+    the ``ref.py`` oracle is the default execution engine off-TPU.
+    ``autotune=True`` (kernel path, no explicit blocks) picks block sizes
+    via ``autotune.py``'s cached sweep.
     """
     use_kernel, interpret = _resolve(use_kernel, interpret)
-    if use_kernel and fuse:
+    if use_kernel:
         faults.raise_if("kernel.dense")   # drill: dense-kernel lowering failure
         if autotune and not blocks:
             from repro.kernels import autotune as _autotune
@@ -437,14 +299,10 @@ def tm_forward_packed(
         return _fused_infer_kernel.fused_tm_forward(
             lit_words, inc_words, votes, nonempty, interpret=interpret, **blocks
         )
-    kw = dict(use_kernel=use_kernel, interpret=interpret)
-    cf_blocks = {k: v for k, v in blocks.items()
-                 if k in ("block_b", "block_c", "block_w")}
-    cs_blocks = {k: v for k, v in blocks.items() if k in ("block_b", "block_c")}
-    fired = clause_fire(lit_words, inc_words, **kw, **cf_blocks)
+    fired = ref.clause_fire_ref(lit_words, inc_words)
     if nonempty is not None:
         fired = fired * nonempty[None, :].astype(fired.dtype)
-    return class_sums(fired, votes, **kw, **cs_blocks)
+    return ref.class_sum_ref(fired, votes)
 
 
 def conv_tm_forward_packed(
@@ -657,7 +515,7 @@ def feedback_select(
     """(B, C) uint8 feedback types: 0 none, 1 Type I, 2 Type II.
 
     This is the oracle the fused training kernel reproduces bit-for-bit;
-    randomness is the same counter hash as the ta_update kernel, indexed by
+    randomness is the same counter hash as ``ref.ta_delta_ref``, indexed by
     GLOBAL (sample, clause) id so sharded/chunked callers match unsharded.
     """
     B = y.shape[0]
@@ -699,8 +557,8 @@ def feedback_plan(
 ):
     """Compute per-(sample, clause) feedback types: 0 none, 1 Type I, 2 Type II.
 
-    Clause-level randomness uses the same hash RNG as the ta_update kernel so
-    the whole kernel-path training step is reproducible and oracle-testable.
+    Clause-level randomness uses the same hash RNG as ``ref.ta_delta_ref``
+    so the whole training step is reproducible and oracle-testable.
     """
     K = votes.shape[1]
     T = threshold
@@ -740,6 +598,8 @@ def tm_train_step_kernel(
     seed: jax.Array,         # uint32 scalar
     batch_chunk: int | None = None,
     *,
+    # bench/systems/training.py spells fuse=True; the fused step is the
+    # only kernel step, so any other value is refused
     fuse: bool = True,
     autotune: bool = False,
     blocks: dict | None = None,
@@ -747,18 +607,18 @@ def tm_train_step_kernel(
     c_offset=0,              # global index of clause 0 (clause-sharded caller)
     c_total: int | None = None,  # set when ta_state is a clause shard
     sums_reduce=None,        # e.g. lambda s: lax.psum(s, "model")
-    **kw,
+    use_kernel: bool | None = None,
+    interpret: bool | None = None,
 ):
-    """Full kernel-path batch training step (clause_fire -> plan -> ta_delta).
+    """Full batch training step (class sums -> feedback plan -> TA delta).
 
-    On the kernel path (``use_kernel=True`` / ``REPRO_USE_PALLAS=1``),
-    ``fuse=True`` (the default) runs the whole step as TWO kernel launches:
-    a fused-inference pass for the class sums the feedback plan needs, then
-    the fused training kernel (``fused_train.py``) — clause fire, feedback
-    type, and TA delta in one ``pallas_call``, with the ``(B, C)`` fire and
-    ftype matrices never touching HBM.  ``fuse=False`` keeps the legacy
-    three-dispatch pipeline; off the kernel path the ``ref.py`` oracles run.
-    All engines are bit-identical.
+    On the kernel path (``use_kernel=True`` / ``REPRO_USE_PALLAS=1``) the
+    whole step is TWO kernel launches: a fused-inference pass for the
+    class sums the feedback plan needs, then the fused training kernel
+    (``fused_train.py``) — clause fire, feedback type, and TA delta in one
+    ``pallas_call``, with the ``(B, C)`` fire and ftype matrices never
+    touching HBM.  Off the kernel path the ``ref.py`` oracles run.  Both
+    are bit-identical.
 
     An eager call (concrete arrays) runs the step as ONE compiled program,
     :func:`tm_train_step` under a cached ``jax.jit``: the configuration,
@@ -792,15 +652,16 @@ def tm_train_step_kernel(
     LOCAL batch's delta — a data-sharded caller must ``psum`` the returned
     delta over its data axes and apply it to the shard itself.
     """
+    if fuse is not True:
+        raise ValueError(f"fuse={fuse!r}: the fused step is the only "
+                         "kernel training step")
     args = (ta_state, x, y, seed, b_offset, c_offset)
     traced = any(isinstance(a, jax.core.Tracer) for a in args)
     # Spans time the eager call only: under a caller's trace the body runs
     # once, while tracing, and a span there would time the trace.
     with (contextlib.nullcontext() if traced
           else TraceAnnotation("repro.train.step")):
-        use_kernel, interpret = _resolve(kw.pop("use_kernel", None),
-                                         kw.pop("interpret", None))
-        fused = bool(fuse and use_kernel)
+        fused, interpret = _resolve(use_kernel, interpret)
         infer_blocks = {}
         if fused and autotune:   # timed eagerly, outside the step's own jit
             from repro.core import packetizer
@@ -817,11 +678,10 @@ def tm_train_step_kernel(
             infer_blocks = _autotune.autotune_fused_blocks(
                 chunk_b, C_tot, W, config.n_classes, interpret=interpret)
         static = dict(
-            config=config, batch_chunk=batch_chunk, fused=fused,
-            blocks=_frozen(blocks), infer_blocks=_frozen(infer_blocks),
-            c_total=c_total, sums_reduce=sums_reduce,
-            engine=_frozen(dict(kw, use_kernel=use_kernel,
-                                interpret=interpret)))
+            config=config, batch_chunk=batch_chunk, fused=bool(fused),
+            interpret=interpret, blocks=_frozen(blocks),
+            infer_blocks=_frozen(infer_blocks), c_total=c_total,
+            sums_reduce=sums_reduce)
         with _step_calls_lock:
             _step_calls["inlined" if traced else "compiled"] += 1
         if traced:
@@ -831,17 +691,15 @@ def tm_train_step_kernel(
 
 
 def tm_train_step(ta_state, x, y, seed, b_offset, c_offset, *, config,
-                  batch_chunk, fused, blocks, infer_blocks, c_total,
-                  sums_reduce, engine):
+                  batch_chunk, fused, interpret, blocks, infer_blocks,
+                  c_total, sums_reduce):
     """The body of :func:`tm_train_step_kernel`, always traced: inlined
     into a caller's program, or compiled alone as the eager step.
-    ``blocks``/``infer_blocks`` are the fused kernels' tilings and
-    ``engine`` the resolved ``use_kernel``/``interpret`` with the unfused
-    kernels' tilings, each as sorted items."""
+    ``fused`` picks the fused kernels over the ``ref.py`` oracles;
+    ``blocks``/``infer_blocks`` are the fused kernels' tilings, each as
+    sorted items."""
     from repro.core import packetizer, tm
 
-    kw = dict(engine)
-    interpret = kw["interpret"]
     inc_words = packetizer.pack_include_masks(ta_state)
     C_loc = ta_state.shape[0]
     votes = tm.vote_matrix(config)
@@ -891,7 +749,7 @@ def tm_train_step(ta_state, x, y, seed, b_offset, c_offset, *, config,
                 c_offset=c_offset, c_total=c_total,
                 interpret=interpret, **dict(blocks),
             )
-        fire = clause_fire(lit_words, inc_words, **kw).astype(jnp.uint8)
+        fire = ref.clause_fire_ref(lit_words, inc_words).astype(jnp.uint8)
         sums = None
         if sums_reduce is not None:   # clause shard: complete partials
             sums = jnp.clip(
@@ -903,10 +761,10 @@ def tm_train_step(ta_state, x, y, seed, b_offset, c_offset, *, config,
         )
         if valid is not None:
             ftype = jnp.where(valid[:, None], ftype, jnp.uint8(0))
-        return ta_delta(
+        return ref.ta_delta_ref(
             ta_state, lits, fire, ftype, seed,
             p_act=p_act, p_inact=1.0 / config.s, b_offset=b_off,
-            c_offset=c_offset, c_total=c_total, **kw,
+            c_offset=c_offset, c_total=c_total,
         )
 
     if batch_chunk and B > batch_chunk:
@@ -945,192 +803,5 @@ def tm_train_step(ta_state, x, y, seed, b_offset, c_offset, *, config,
 
 _compiled_train_step = jax.jit(
     tm_train_step,
-    static_argnames=("config", "batch_chunk", "fused", "blocks",
-                     "infer_blocks", "c_total", "sums_reduce", "engine"))
-
-
-# ---------------------------------------------------------------------------
-# Beyond-paper: matmul + binomial-aggregation TM training step
-# ---------------------------------------------------------------------------
-
-def _binomial_approx(n: jax.Array, p: float, gidx: jax.Array, seed: jax.Array):
-    """~Binomial(n, p) per element via moment-matched normal (triangular z).
-
-    Exact in mean/variance; the normal approximation error is negligible for
-    the O(batch)-sized counts this path aggregates (and TM training is robust
-    to RNG quality by design — the paper's trainers use LFSRs).
-    """
-    u1 = ref.hash_u32(gidx, seed).astype(jnp.float32) / jnp.float32(2**32)
-    u2 = ref.hash_u32(gidx, seed ^ jnp.uint32(0xC2B2AE35)).astype(jnp.float32) \
-        / jnp.float32(2**32)
-    z = (u1 + u2 - 1.0) * jnp.float32(2.449489742783178)   # sqrt(6): unit var
-    nf = n.astype(jnp.float32)
-    s = nf * p + jnp.sqrt(jnp.maximum(nf * p * (1.0 - p), 0.0)) * z
-    return jnp.clip(jnp.round(s), 0.0, nf).astype(jnp.int32)
-
-
-def tm_train_step_matmul(
-    config,
-    ta_state: jax.Array,     # (C, L) int8
-    x: jax.Array,            # (B, F) {0,1}
-    y: jax.Array,            # (B,)
-    seed: jax.Array,         # uint32 scalar
-    delta_constrain=None,    # optional (C, L) sharding constraint: applied at
-                             # the dot outputs so partial sums reduce-scatter
-):
-    """Batch TM training as three MXU matmuls + (C, L) elementwise sampling.
-
-    Decomposition (boost_true_positive=True):
-      Type I, clause=1, lit=1: deterministic +1  -> A   = M1f^T @ lit
-      Type I penalties (p=1/s):        counts n1 = M1f^T @ (1-lit) + rowsum(M1n)
-                                       draw ~ Binomial(n1, 1/s)
-      Type II (deterministic on excluded, lit=0): n2 = M2^T @ (1-lit)
-    where M1f/M1n/M2 are (B, C) feedback masks.  Memory is O(BC + BL + CL) —
-    no (B, C, L) intermediate exists, and clause evaluation itself is the
-    violation-count matmul (C,L)@(L,B).  Statistically equivalent to the
-    exact per-sample path (matched mean/variance; see tests).
-    """
-    from repro.core import tm
-
-    assert config.boost_true_positive, "matmul path assumes boost (p_act=1)"
-    B = x.shape[0]
-    C, L = ta_state.shape
-    lits = tm.literals(x)                                    # (B, L) uint8
-    lit_f = lits.astype(jnp.bfloat16)
-    inc = (ta_state >= 0).astype(jnp.bfloat16)               # (C, L)
-
-    # clause evaluation as a violation-count matmul (MXU)
-    viol = jax.lax.dot_general(
-        inc, (1.0 - lit_f), (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )                                                        # (C, B)
-    fire = (viol.T < 0.5).astype(jnp.uint8)                  # (B, C)
-
-    votes = tm.vote_matrix(config)
-    c = jnp.arange(config.n_clauses_total)
-    clause_class = jnp.clip(c // config.clauses_per_class, 0, config.n_classes - 1)
-    ftype, _ = feedback_plan(
-        fire, y, votes, clause_class, tm.polarity(config), config.threshold, seed
-    )
-
-    f1 = (ftype == 1)
-    m1f = (f1 & (fire == 1)).astype(jnp.bfloat16)            # (B, C)
-    m1n = (f1 & (fire == 0)).astype(jnp.float32)
-    m2 = ((ftype == 2) & (fire == 1)).astype(jnp.bfloat16)
-
-    def cb_matmul(m_bc, lit_bl):                             # -> (C, L) f32
-        return jax.lax.dot_general(
-            m_bc, lit_bl, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-
-    A = cb_matmul(m1f, lit_f)                                # reward counts
-    n1 = cb_matmul(m1f, 1.0 - lit_f) + jnp.sum(m1n, axis=0)[:, None]
-    n2 = cb_matmul(m2, 1.0 - lit_f)
-    if delta_constrain is not None:
-        A, n1, n2 = map(delta_constrain, (A, n1, n2))
-
-    gidx = (
-        jnp.arange(C, dtype=jnp.uint32)[:, None] * jnp.uint32(L)
-        + jnp.arange(L, dtype=jnp.uint32)[None, :]
-    )
-    pen = _binomial_approx(n1, 1.0 / config.s, gidx, seed ^ jnp.uint32(0x27D4EB2F))
-    excl = (ta_state < 0).astype(jnp.int32)
-    if delta_constrain is not None:
-        excl = delta_constrain(excl)
-    delta = A.astype(jnp.int32) - pen + n2.astype(jnp.int32) * excl
-
-    new_ta = jnp.clip(
-        ta_state.astype(jnp.int32) + delta, -config.n_states, config.n_states - 1
-    ).astype(jnp.int8)
-    return new_ta, delta
-
-
-def tm_train_step_matmul_local(
-    config,
-    ta_loc: jax.Array,     # (C_loc, L_loc) int8 — dual-axis shard
-    x_loc: jax.Array,      # (B_loc, F) {0,1}
-    y_loc: jax.Array,      # (B_loc,)
-    seed: jax.Array,       # uint32 scalar
-):
-    """shard_map body for the matmul TM step on a ("data", "model") mesh.
-
-    Explicit collective schedule (GSPMD's partitioner falls back to a dense
-    all-reduce of the f32 delta here — see EXPERIMENTS.md §Perf):
-      1. all-gather int8 automata over `data`       (C_loc x L, ~31 MB)
-      2. local viol/feedback matmuls (MXU)
-      3. one tiny psum of (B_loc, K) class sums over `model`
-      4. psum_scatter the f32 partial deltas over `data` -> (C_loc, L_loc)
-    """
-    from repro.core import tm
-
-    di = jax.lax.axis_index("data")
-    mi = jax.lax.axis_index("model")
-    n_data = jax.lax.axis_size("data")
-    C_loc, L_loc = ta_loc.shape
-    B_loc = x_loc.shape[0]
-    b_off = di * B_loc
-    c_off = mi * C_loc
-    l_off = di * L_loc
-
-    ta_full = jax.lax.all_gather(ta_loc, "data", axis=1, tiled=True)  # (C_loc, L)
-    lits = tm.literals(x_loc)                                 # (B_loc, L)
-    lit_f = lits.astype(jnp.bfloat16)
-    inc = (ta_full >= 0).astype(jnp.bfloat16)
-
-    viol = jax.lax.dot_general(
-        inc, (1.0 - lit_f), (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )                                                         # (C_loc, B_loc)
-    fire = (viol.T < 0.5).astype(jnp.uint8)                   # (B_loc, C_loc)
-
-    votes = tm.vote_matrix(config)                            # (C, K) global
-    votes_loc = jax.lax.dynamic_slice_in_dim(votes, c_off, C_loc, 0)
-    sums = jax.lax.psum(fire.astype(jnp.int32) @ votes_loc, "model")
-    sums = jnp.clip(sums, -config.threshold, config.threshold)
-
-    cc = jnp.clip(
-        jnp.arange(config.n_clauses_total) // config.clauses_per_class,
-        0, config.n_classes - 1,
-    )
-    pol = tm.polarity(config)
-    cc_loc = jax.lax.dynamic_slice_in_dim(cc, c_off, C_loc, 0)
-    pol_loc = jax.lax.dynamic_slice_in_dim(pol, c_off, C_loc, 0)
-    ftype, _ = feedback_plan(
-        fire, y_loc, votes_loc, cc_loc, pol_loc, config.threshold, seed,
-        b_offset=b_off, c_offset=c_off, sums=sums,
-    )
-
-    f1 = (ftype == 1)
-    m1f = (f1 & (fire == 1)).astype(jnp.bfloat16)
-    m1n = (f1 & (fire == 0)).astype(jnp.float32)
-    m2 = ((ftype == 2) & (fire == 1)).astype(jnp.bfloat16)
-
-    def cb(m_bc, lit_bl):
-        return jax.lax.dot_general(
-            m_bc, lit_bl, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-
-    A = cb(m1f, lit_f)                                        # (C_loc, L) partial
-    n1 = cb(m1f, 1.0 - lit_f) + jnp.sum(m1n, axis=0)[:, None]
-    n2 = cb(m2, 1.0 - lit_f)
-    stacked = jnp.stack([A, n1, n2])                          # (3, C_loc, L)
-    stacked = jax.lax.psum_scatter(
-        stacked, "data", scatter_dimension=2, tiled=True
-    )                                                         # (3, C_loc, L_loc)
-    A, n1, n2 = stacked[0], stacked[1], stacked[2]
-
-    L_total = L_loc * n_data
-    gidx = (
-        (jnp.arange(C_loc, dtype=jnp.uint32) + jnp.uint32(c_off))[:, None]
-        * jnp.uint32(L_total)
-        + (jnp.arange(L_loc, dtype=jnp.uint32) + jnp.uint32(l_off))[None, :]
-    )
-    pen = _binomial_approx(n1, 1.0 / config.s, gidx, seed ^ jnp.uint32(0x27D4EB2F))
-    excl = (ta_loc < 0).astype(jnp.int32)
-    delta = jnp.round(A).astype(jnp.int32) - pen + jnp.round(n2).astype(jnp.int32) * excl
-    return jnp.clip(
-        ta_loc.astype(jnp.int32) + delta,
-        -config.n_states, config.n_states - 1,
-    ).astype(jnp.int8)
+    static_argnames=("config", "batch_chunk", "fused", "interpret", "blocks",
+                     "infer_blocks", "c_total", "sums_reduce"))

@@ -129,8 +129,8 @@ def ta_delta_ref(
     bit-identical to unchunked.  ``c_total`` (with ``c_offset``) switches
     the clause index to GLOBAL ids in a bank of ``c_total`` clauses, so a
     clause shard reproduces exactly the full-bank stream's draws for its
-    rows; the default (``c_total=None``) keeps local indexing, matching the
-    unfused per-shard composition the pre-sharded tests pin down.
+    rows; the default (``c_total=None``) keeps local indexing, as a step
+    over the shard alone would.
     """
     B, L = lits.shape
     C = ta.shape[0]

@@ -2,8 +2,8 @@
 
 The paper benchmarks MATADOR against FINN BNNs whose core op is the
 XNOR-popcount dot product over {-1,+1} packed into bits.  We implement that
-baseline with the same bitpacked streaming structure as clause_eval (shared
-word-axis "packet" decomposition), so the Table-I comparison is like-for-like
+baseline with the same bitpacked streaming structure as the clause chain of
+``fused_infer`` (shared word-axis "packet" decomposition), so the Table-I comparison is like-for-like
 on this substrate too.
 """
 

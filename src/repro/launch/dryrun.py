@@ -152,7 +152,6 @@ class SkipCell(Exception):
 
 TM_SHAPES = {
     "tm_train": dict(batch=8192, kind="train"),
-    "tm_train_matmul": dict(batch=8192, kind="train", algorithm="matmul"),
     "tm_train_fused": dict(batch=8192, kind="train", engine="kernel"),
     "tm_infer": dict(batch=65536, kind="infer"),
     "tm_infer_fused": dict(batch=65536, kind="infer", engine="kernel"),
@@ -174,9 +173,7 @@ def _lower_tm_cell(arch: str, shape_name: str, mesh):
 
     if spec["kind"] == "train":
         fn = tm_shd.sharded_train_step_fn(
-            config, mesh, algorithm=spec.get("algorithm", "bitwise"),
-            engine=engine, **kernel_kw,
-        )
+            config, mesh, engine=engine, **kernel_kw)
         args = (
             jax.ShapeDtypeStruct((C, L), jnp.int8),
             jax.ShapeDtypeStruct((B, config.n_features), jnp.uint8),

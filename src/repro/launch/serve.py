@@ -203,19 +203,12 @@ def serve_tm(args) -> dict:
 
     bucket = args.bucket
     use_kernel, interpret = ops.kernel_dispatch()
-    # the ladder comes from the artifact (ops.engine_levels): on the kernel
-    # path the chain-schedule kernels, the FACTORIZED one first when the
-    # artifact's measured term sharing clears the compile-time threshold
-    # (shared AND terms evaluated once per bucket).  --no-sparse pins the
-    # dense kernel, --no-factorize the flat bit-chain kernel, --factorize
-    # the factorized one regardless of the measured sharing; a
-    # convolutional artifact has one kernel, which they leave alone.
-    if args.factorize and args.no_factorize:
-        raise SystemExit("--factorize and --no-factorize are exclusive")
-    levels = ops.engine_levels(
-        compiled, use_kernel, sparse=not args.no_sparse,
-        factorize=(True if args.factorize
-                   else False if args.no_factorize else None))
+    # the ladder comes from the platform and the artifact alone
+    # (ops.engine_levels): on the kernel path the chain-schedule kernels,
+    # the FACTORIZED one first when the artifact's measured term sharing
+    # clears the compile-time threshold (shared AND terms evaluated once
+    # per bucket); a convolutional artifact has one kernel
+    levels = ops.engine_levels(compiled)
     sparse, factorize = "sparse" in levels, "factorized" in levels
 
     def tuned_blocks(n_clauses):
@@ -791,18 +784,6 @@ def build_parser() -> argparse.ArgumentParser:
                          "'verify' (default) wall-clocks only the model's "
                          "top-3 shortlist, 'sweep' times every candidate "
                          "and feeds the model's training-data sidecar")
-    ap.add_argument("--no-sparse", action="store_true",
-                    help="TM kernel path: serve the compiled artifact with "
-                         "the dense fused kernel instead of the default "
-                         "block-sparse chain schedule")
-    ap.add_argument("--no-factorize", action="store_true",
-                    help="TM kernel path: pin the flat bit-chain sparse "
-                         "kernel even when the artifact's partial_term_"
-                         "sharing clears the factorized-serving threshold")
-    ap.add_argument("--factorize", action="store_true",
-                    help="TM kernel path: start the engine ladder on the "
-                         "factorized kernel even when the artifact's "
-                         "measured term sharing is below the threshold")
     ap.add_argument("--bucket-deadline", type=float, default=None,
                     help="TM: demote the serving engine when a bucket runs "
                          "longer than this multiple of the EWMA of bucket "

@@ -64,16 +64,10 @@ def train_tm(args) -> dict:
     mon = StragglerMonitor()
     ta = state.ta_state
     it = iter(loader)
-    # the fused training pipeline (fuse=True) is the kernel-path default:
-    # two pallas launches per step, no (B, C) fire/ftype HBM round-trips.
+    # on the kernel path the step is the fused training pipeline: two
+    # pallas launches per step, no (B, C) fire/ftype HBM round-trips.
     # --autotune resolves (and caches) the fused block tilings on first use.
-    step_kw = dict(
-        batch_chunk=args.batch_chunk,
-        fuse=not args.no_fuse,
-        autotune=args.autotune,
-    )
-    if args.use_kernel:
-        step_kw["use_kernel"] = True
+    step_kw = dict(batch_chunk=args.batch_chunk, autotune=args.autotune)
     sharded_step = None
     if args.mesh:
         # clause-sharded shard_map schedule: automata over `model`, batch
@@ -92,9 +86,8 @@ def train_tm(args) -> dict:
         if args.autotune:
             # autotune the PER-SHARD shapes (C_loc clauses, B_loc samples)
             # outside the shard_map trace and pin them via `blocks`
-            uk, interp = ops.kernel_dispatch(
-                True if args.use_kernel else None, None)
-            if uk and not args.no_fuse:
+            uk, interp = ops.kernel_dispatch()
+            if uk:
                 from repro.core import packetizer
                 from repro.kernels import autotune as _autotune
 
@@ -110,12 +103,11 @@ def train_tm(args) -> dict:
                     config.n_literals, config.n_classes, interpret=interp)
                 print("autotuned sharded blocks:", blocks)
             else:
-                print("--autotune ignored: fused kernel path inactive "
-                      "(need --use-kernel/REPRO_USE_PALLAS=1, no --no-fuse)")
+                print("--autotune ignored: the kernel path is inactive "
+                      "(a TPU, or REPRO_USE_PALLAS=1)")
         sharded_step = tm_sharding.sharded_train_step_fn(
             config, mesh, batch_chunk=args.batch_chunk, engine="kernel",
-            fuse=not args.no_fuse, blocks=blocks,
-            use_kernel=True if args.use_kernel else None,
+            blocks=blocks,
         )
         print(f"mesh {dict(mesh.shape)}: clause axis sharded over "
               f"model={mesh.shape['model']}")
@@ -240,15 +232,9 @@ def build_parser() -> argparse.ArgumentParser:
                     help="TM: scan the batch in slices of this size "
                          "(O(chunk) working set; ragged tails are padded "
                          "and masked, results stay bit-identical)")
-    ap.add_argument("--no-fuse", action="store_true",
-                    help="TM: use the legacy three-dispatch training step "
-                         "instead of the fused Pallas pipeline")
     ap.add_argument("--autotune", action="store_true",
                     help="TM: pick fused-kernel block tilings from the "
                          "cached autotuner sweep")
-    ap.add_argument("--use-kernel", action="store_true",
-                    help="TM: force the Pallas kernel path (same as "
-                         "REPRO_USE_PALLAS=1)")
     ap.add_argument("--mesh", default=None,
                     help="TM: mesh spec, e.g. 'model=4' or 'data=2,model=4' "
                          "— clause-sharded shard_map training step (on CPU "

@@ -149,24 +149,25 @@ def test_save_load_roundtrip():
 
 
 def test_kernel_path_equivalence():
-    """oracle == fused single-pass kernel == unfused two-kernel pipeline."""
+    """oracle == the kernel ladder's head == the fused dense kernel."""
+    from repro.kernels import ops
+
     cfg, ta = _random_tm(100, 4, 16, 0.08, 3)
     comp = compiler.compile_tm(cfg, ta)
     x = jnp.asarray(np.random.default_rng(0).integers(0, 2, (12, 100), dtype=np.uint8))
     a = compiler.predict_compiled(comp, x, engine="oracle")
     b = compiler.predict_compiled(
-        comp, x, engine=compiler.EngineSpec(use_kernel=True), interpret=True)
-    c = compiler.predict_compiled(
-        comp, x, engine=compiler.EngineSpec(use_kernel=True, fuse=False),
+        comp, x, engine=ops.engine_levels(comp, use_kernel=True)[0],
         interpret=True)
+    c = compiler.predict_compiled(comp, x, engine="dense", interpret=True)
     np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
     np.testing.assert_array_equal(np.asarray(a), np.asarray(c))
 
 
 def test_run_compiled_dispatch_defaults():
-    """run_compiled defers to ops._resolve: off-TPU defaults to the oracle
-    path with interpret resolved (no unconditional interpret=True), and
-    explicit kernel dispatch matches it bit-for-bit."""
+    """run_compiled defers to ops.engine_levels: off-TPU defaults to the
+    oracle path with interpret resolved (no unconditional interpret=True),
+    and the kernel ladder's head matches it bit-for-bit."""
     from repro.kernels import ops
 
     cfg, ta = _random_tm(24, 3, 6, 0.12, 9)
@@ -174,11 +175,12 @@ def test_run_compiled_dispatch_defaults():
     xp = packetizer.pack_literals(
         jnp.asarray(np.random.default_rng(1).integers(0, 2, (9, 24), dtype=np.uint8))
     )
-    uk, it = ops.kernel_dispatch()
+    _, it = ops.kernel_dispatch()
     default = compiler.run_compiled(comp, xp)
     explicit = compiler.run_compiled(
-        comp, xp, engine=compiler.EngineSpec(use_kernel=uk), interpret=it)
+        comp, xp, engine=ops.engine_levels(comp)[0], interpret=it)
     kernel = compiler.run_compiled(
-        comp, xp, engine=compiler.EngineSpec(use_kernel=True), interpret=True)
+        comp, xp, engine=ops.engine_levels(comp, use_kernel=True)[0],
+        interpret=True)
     np.testing.assert_array_equal(np.asarray(default), np.asarray(explicit))
     np.testing.assert_array_equal(np.asarray(default), np.asarray(kernel))

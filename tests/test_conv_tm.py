@@ -232,9 +232,6 @@ def test_ladder_is_chosen_from_the_artifact(use_kernel, want):
     _, _, include, weights = make_case(0)
     art = compiler.compile_tm(CFG, ta_of(include), weights=weights)
     assert ops.engine_levels(art, use_kernel=use_kernel) == want
-    # the vanilla pins leave a convolutional artifact's one kernel alone
-    assert ops.engine_levels(art, use_kernel=use_kernel, sparse=False,
-                             factorize=True) == want
     cfg = tm.TMConfig(n_features=8, n_classes=2, clauses_per_class=2)
     st = tm.init(cfg, __import__("jax").random.PRNGKey(0))
     vanilla = compiler.compile_tm(cfg, st.ta_state)

@@ -1,6 +1,7 @@
-"""The unified engine/tuning API: EngineSpec dispatch on run_compiled,
-deprecation shims for the old boolean kwargs, the `autotune.tune` facade's
-three policies, and the zero-timing-run plan_engine cold-start path."""
+"""The unified engine/tuning API: the engine-name contract of
+run_compiled, ``"auto"`` as the head of ``ops.engine_levels``, the sharded
+builders' kernel dispatch, the `autotune.tune` facade's three policies, and
+the zero-timing-run plan_engine cold-start path."""
 
 from __future__ import annotations
 
@@ -35,39 +36,76 @@ def artifact():
     return comp, packetizer.pack_literals(x)
 
 
+def _sharing_artifact():
+    """Every clause carries the same two-word core: the measured term
+    sharing clears the factorize threshold."""
+    cfg = tm.TMConfig(n_features=64, n_classes=2, clauses_per_class=8)
+    C, L = 16, 128
+    ta = np.full((C, L), -5, np.int8)
+    ta[:, 3] = 3
+    ta[:, 40] = 3
+    for c in range(C):
+        ta[c, 64 + ((c * 4) % 64)] = 3
+    return compiler.compile_tm(cfg, ta)
+
+
 # ---------------------------------------------------------------------------
-# EngineSpec
+# the engine-name contract
 # ---------------------------------------------------------------------------
 
-def test_engine_spec_validation():
+def test_engine_spec_validation(artifact):
+    """An engine is one of ops.ENGINE_NAMES; anything else raises."""
+    comp, xp = artifact
     with pytest.raises(ValueError, match="unknown engine"):
-        ops.EngineSpec(name="bogus")
-    with pytest.raises(ValueError, match="oracle"):
-        ops.EngineSpec(name="oracle", use_kernel=True)
-    with pytest.raises(ValueError, match="use_kernel=False"):
-        ops.EngineSpec(name="sparse", use_kernel=False)
-    with pytest.raises(ValueError, match="unfused"):
-        ops.EngineSpec(name="factorized", fuse=False)
-    # dense DOES have an unfused (two-kernel pipeline) form
-    ops.EngineSpec(name="dense", fuse=False)
-
-
-def test_engine_spec_coerce():
-    assert ops.EngineSpec.coerce(None) == ops.EngineSpec()
-    assert ops.EngineSpec.coerce("sparse").name == "sparse"
-    spec = ops.EngineSpec(name="dense", interpret=True)
-    assert ops.EngineSpec.coerce(spec) is spec
-    with pytest.raises(TypeError, match="EngineSpec"):
-        ops.EngineSpec.coerce(42)
+        compiler.run_compiled(comp, xp, engine="bogus")
     with pytest.raises(ValueError, match="unknown engine"):
-        ops.EngineSpec.coerce("fastest")
+        compiler.run_compiled(comp, xp, engine="fastest")
+    with pytest.raises(TypeError, match="convolutional"):
+        compiler.run_compiled(comp, xp, engine="conv")
 
 
-def test_engine_spec_resolve_interpret_precedence():
-    spec = ops.EngineSpec(name="sparse", interpret=False)
-    # call-site interpret wins over the spec's
-    assert spec.resolve(True)[1] is True
-    assert spec.resolve(None)[1] is False
+def test_engine_spec_coerce(artifact):
+    """Only a name selects an engine: the switches that used to stand in
+    for one are refused."""
+    comp, xp = artifact
+    with pytest.raises(ValueError, match="unknown engine"):
+        compiler.run_compiled(comp, xp, engine=None)
+    for legacy in ("use_kernel", "fuse", "sparse", "factorize"):
+        with pytest.raises(TypeError):
+            compiler.run_compiled(comp, xp, **{legacy: True})
+    with pytest.raises(TypeError):
+        ops.engine_levels(comp, True, sparse=False)
+
+
+def test_engine_spec_resolve_interpret_precedence(artifact):
+    """A named kernel engine runs whatever the platform default; a
+    call-site ``interpret`` wins over the platform's (interpret off a
+    TPU, compiled on one)."""
+    assert ops.kernel_dispatch(None, False)[1] is False
+    assert ops.kernel_dispatch(None, True)[1] is True
+    assert ops.kernel_dispatch()[1] is (not ops._ON_TPU)
+    comp, xp = artifact
+    oracle = np.asarray(compiler.run_compiled(comp, xp, engine="oracle"))
+    got = compiler.run_compiled(comp, xp, engine="sparse")
+    np.testing.assert_array_equal(oracle, np.asarray(got))
+
+
+@pytest.mark.parametrize("engine, key", [
+    ("sparse", "block_t"), ("sparse", "block_b"), ("dense", "block_j"),
+    ("factorized", "block_w")])
+def test_engine_refuses_a_tiling_it_does_not_take(artifact, engine, key):
+    comp, xp = artifact
+    with pytest.raises(TypeError, match="takes no tiling"):
+        compiler.run_compiled(comp, xp, engine=engine, interpret=True,
+                              **{key: 8})
+
+
+def test_oracle_ignores_kernel_tilings(artifact):
+    comp, xp = artifact
+    want = np.asarray(compiler.run_compiled(comp, xp, engine="oracle"))
+    got = compiler.run_compiled(comp, xp, engine="oracle", block_t=8,
+                                block_b=8)
+    np.testing.assert_array_equal(want, np.asarray(got))
 
 
 # ---------------------------------------------------------------------------
@@ -80,60 +118,88 @@ def test_all_named_engines_bit_identical(artifact):
     for name in ("factorized", "sparse", "dense", "auto"):
         got = compiler.run_compiled(comp, xp, engine=name, interpret=True)
         np.testing.assert_array_equal(oracle, np.asarray(got), err_msg=name)
-    spec = compiler.EngineSpec(name="dense", fuse=False, interpret=True)
-    np.testing.assert_array_equal(
-        oracle, np.asarray(compiler.run_compiled(comp, xp, engine=spec)))
 
 
-def test_deprecated_kwargs_warn_and_match(artifact):
-    """The legacy boolean kwargs still work — behind a DeprecationWarning —
-    and agree bit-for-bit with their EngineSpec replacements.  CI reruns
-    this test with ``-W error::DeprecationWarning`` to prove the warning
-    actually fires."""
-    comp, xp = artifact
-    with pytest.warns(DeprecationWarning, match="engine="):
-        legacy = compiler.run_compiled(
-            comp, xp, use_kernel=True, interpret=True,
-            sparse=True, factorize=False)
-    new = compiler.run_compiled(comp, xp, engine="sparse", interpret=True)
-    np.testing.assert_array_equal(np.asarray(legacy), np.asarray(new))
-
-    with pytest.warns(DeprecationWarning):
-        legacy = compiler.predict_compiled(comp, jnp.asarray(
-            np.random.default_rng(0).integers(0, 2, (5, 48), np.uint8)),
-            use_kernel=False)
-    assert legacy.shape == (5,)
-
-
-def test_engine_and_legacy_kwargs_conflict(artifact):
-    comp, xp = artifact
-    with pytest.raises(TypeError, match="deprecated"):
-        compiler.run_compiled(comp, xp, engine="sparse", use_kernel=True)
+@pytest.mark.parametrize("kind, want", [
+    ("shared", "factorized"), ("unshared", "sparse"), ("conv", "conv")])
+def test_auto_is_the_head_of_the_ladder(kind, want, monkeypatch):
+    """``engine="auto"`` serves ``engine_levels(compiled)[0]`` and nothing
+    else: on the kernel path, the factorized kernel above the sharing
+    threshold, the sparse one below it, and a convolutional artifact's
+    own kernel."""
+    rng = np.random.default_rng(3)
+    if kind == "conv":
+        cfg = tm.ConvTMConfig(image_h=12, image_w=12, window=4,
+                              n_clauses=16, n_classes=3)
+        words = packetizer.pack_bits_np(
+            (rng.random((9, cfg.n_features)) < 0.3).astype(np.uint8))
+        ta, weights = tm.conv_bank(cfg, words, 0)
+        comp = compiler.compile_tm(cfg, ta, weights=weights)
+        xp = jnp.asarray(words)
+    else:
+        if kind == "shared":
+            comp = _sharing_artifact()
+        else:
+            cfg, ta = _random_tm(24, 2, 4, 0.08, 0)
+            comp = compiler.compile_tm(cfg, ta)
+        xp = packetizer.pack_literals(jnp.asarray(rng.integers(
+            0, 2, (9, comp.n_features), dtype=np.uint8)))
+        shares = (comp.stats.partial_term_sharing
+                  >= compiler.FACTORIZE_SHARING_THRESHOLD)
+        assert shares == (kind == "shared")
+    assert ops.engine_levels(comp, use_kernel=True)[0] == want
+    monkeypatch.setattr(ops, "_DEFAULT_USE_KERNEL", True)
+    assert ops.engine_levels(comp)[0] == want
+    auto = compiler.run_compiled(comp, xp, engine="auto", interpret=True)
+    named = compiler.run_compiled(comp, xp, engine=want, interpret=True)
+    oracle = compiler.run_compiled(comp, xp, engine="oracle")
+    np.testing.assert_array_equal(np.asarray(auto), np.asarray(named))
+    np.testing.assert_array_equal(np.asarray(auto), np.asarray(oracle))
 
 
 # ---------------------------------------------------------------------------
 # sharding builders
 # ---------------------------------------------------------------------------
 
-def test_sharding_engine_dispatch_rules():
+def test_sharding_engine_dispatch_rules(monkeypatch):
+    """The sharded forward builders take no engine name: each names its
+    kernel, and whether that kernel or the oracle runs is
+    ``ops.kernel_dispatch(use_kernel, interpret)`` alone, the platform
+    default when ``use_kernel`` is not given."""
+    import jax
+    from jax.sharding import Mesh
+
     from repro.core import sharding
 
-    uk, it, fuse = sharding._engine_dispatch(
-        "dense", None, True, allowed=("auto", "dense", "oracle"))
-    assert (uk, it, fuse) == (True, True, True)
-    uk, it, fuse = sharding._engine_dispatch(
-        "oracle", None, None, allowed=("auto", "dense", "oracle"))
-    assert uk is False
-    with pytest.raises(ValueError, match="sparse"):
-        sharding._engine_dispatch(
-            "sparse", None, True, allowed=("auto", "dense", "oracle"))
-    with pytest.raises(TypeError, match="not both"):
-        sharding._engine_dispatch(
-            "dense", True, True, allowed=("auto", "dense", "oracle"))
-    # engine=None: plain passthrough to ambient kernel dispatch
-    uk, it, fuse = sharding._engine_dispatch(
-        None, True, True, allowed=("auto", "dense", "oracle"), fuse=False)
-    assert (uk, it, fuse) == (True, True, False)
+    cfg, ta = _random_tm(48, 3, 8, 0.10, 7)
+    ta = jnp.asarray(ta)
+    inc = packetizer.pack_include_masks(ta)
+    votes = tm.vote_matrix(cfg)
+    ne = jnp.any(ta >= 0, -1).astype(jnp.uint8)
+    lw = packetizer.pack_bits(tm.literals(jnp.asarray(
+        np.random.default_rng(1).integers(0, 2, (11, 48), dtype=np.uint8))))
+    want = np.asarray(ops.tm_forward_packed(lw, inc, votes, ne,
+                                            use_kernel=False))
+
+    seen, real = [], ops.kernel_dispatch
+    monkeypatch.setattr(ops, "kernel_dispatch", lambda uk=None, it=None: (
+        seen.append((uk, it)) or real(uk, it)))
+    mesh = Mesh(np.array(jax.devices()[:1]), ("model",))
+    for uk in (True, False, None):
+        fwd = sharding.sharded_forward_fn(mesh, use_kernel=uk, interpret=True)
+        np.testing.assert_array_equal(np.asarray(fwd(inc, votes, ne, lw)),
+                                      want)
+    assert seen == [(True, True), (False, True), (None, True)]
+
+    for build, kw in ((sharding.sharded_forward_fn, {}),
+                      (sharding.sharded_schedule_forward_fn,
+                       dict(block_c=32, block_j=8)),
+                      (sharding.sharded_factorized_forward_fn,
+                       dict(block_t=32, block_c=32, block_j=4))):
+        with pytest.raises(TypeError, match="engine"):
+            build(mesh, engine="dense", **kw)
+    with pytest.raises(TypeError, match="engine"):
+        sharding.sharded_predict_fn(cfg, mesh, engine="dense")
 
 
 # ---------------------------------------------------------------------------
@@ -223,16 +289,8 @@ def test_plan_engine_cold_start(tune_env):
     assert set(blocks) == {"block_c", "block_j", "block_s"}
     assert autotune.TIMING_RUNS == before
 
-    # high-sharing artifact routes factorized (same bank construction as
-    # the run_compiled heuristic test)
-    cfg2 = tm.TMConfig(n_features=64, n_classes=2, clauses_per_class=8)
-    C, L = 16, 128
-    ta2 = np.full((C, L), -5, np.int8)
-    ta2[:, 3] = 3
-    ta2[:, 40] = 3
-    for c in range(C):
-        ta2[c, 64 + ((c * 4) % 64)] = 3
-    comp2 = compiler.compile_tm(cfg2, ta2)
+    # high-sharing artifact routes factorized
+    comp2 = _sharing_artifact()
     engine2, blocks2 = autotune.plan_engine(comp2, 32, interpret=True)
     assert engine2 == "factorized"
     assert "block_t" in blocks2

@@ -426,9 +426,22 @@ def test_engine_ladder_failed_probe_doubles_cooldown_drill():
     assert lad.counts["kernel"] + lad.counts["oracle"] == 8
 
 
-SERVE_ARGV = ["-m", "repro.launch.serve", "--arch", "tm-tiny",
-              "--requests", "640", "--bucket", "128",
+SERVE_ARGV = ["--arch", "tm-tiny", "--requests", "640", "--bucket", "128",
               "--epochs", "1", "--n-train", "256"]
+
+
+def _serve_full_ladder(argv, env_extra):
+    """Run the serve launcher with the factorize threshold at 0, so the
+    ladder is the full ``[factorized, sparse, dense, oracle]`` whatever
+    sharing tm-tiny's trained bank measures, and every kernel fault site
+    is reached by the drills below."""
+    code = ("import sys\n"
+            "from repro.core import compiler\n"
+            "compiler.FACTORIZE_SHARING_THRESHOLD = 0.0\n"
+            "from repro.launch import serve\n"
+            f"sys.argv = ['serve'] + {argv!r}\n"
+            "serve.main()\n")
+    return _run(code, env_extra=env_extra)
 
 
 def _serve_health(r):
@@ -438,17 +451,20 @@ def _serve_health(r):
     return json.loads(lines[0][len("SERVE_HEALTH "):])
 
 
-@pytest.mark.parametrize("use_kernel, sparse, factorize, want", [
-    (True, True, True, ["factorized", "sparse", "dense", "oracle"]),
-    (True, True, False, ["sparse", "dense", "oracle"]),
-    (True, False, True, ["dense", "oracle"]),
-    (False, True, True, ["oracle"]),
+@pytest.mark.parametrize("use_kernel, shares, want", [
+    (True, True, ["factorized", "sparse", "dense", "oracle"]),
+    (True, False, ["sparse", "dense", "oracle"]),
+    (False, True, ["oracle"]),
+    (False, False, ["oracle"]),
 ])
-def test_engine_levels_honour_the_pins(tiny_compiled, use_kernel, sparse,
-                                       factorize, want):
+def test_engine_levels_honour_the_pins(tiny_compiled, monkeypatch,
+                                       use_kernel, shares, want):
+    """The ladder is pinned by the platform and the artifact's measured
+    sharing against the compiler's threshold, and by nothing else."""
     _, compiled = tiny_compiled
-    assert ops.engine_levels(compiled, use_kernel, sparse=sparse,
-                             factorize=factorize) == want
+    monkeypatch.setattr(compiler, "FACTORIZE_SHARING_THRESHOLD",
+                        0.0 if shares else 1.01)
+    assert ops.engine_levels(compiled, use_kernel) == want
 
 
 def test_engine_levels_follow_the_measured_sharing(tiny_compiled):
@@ -460,7 +476,7 @@ def test_engine_levels_follow_the_measured_sharing(tiny_compiled):
 
 
 def test_serve_ladder_demotes_to_oracle_under_kernel_faults():
-    r = _run(SERVE_ARGV + ["--factorize"], env_extra={
+    r = _serve_full_ladder(SERVE_ARGV, env_extra={
         "REPRO_USE_PALLAS": "1",
         "REPRO_FAULT_INJECT": "kernel.factorized,kernel.sparse,kernel.dense",
     })
@@ -474,19 +490,18 @@ def test_serve_ladder_demotes_to_oracle_under_kernel_faults():
 
 
 def test_serve_healthy_kernel_path_stays_on_top_engine():
-    r = _run(SERVE_ARGV + ["--factorize"],
-             env_extra={"REPRO_USE_PALLAS": "1"})
+    r = _serve_full_ladder(SERVE_ARGV, env_extra={"REPRO_USE_PALLAS": "1"})
     h = _serve_health(r)
     assert h["final_engine"] == "factorized" and h["demotions"] == []
     assert h["engine_buckets"]["factorized"] == h["buckets"]
 
 
 def test_serve_bucket_deadline_demotes_on_slow_bucket():
-    r = _run(SERVE_ARGV + ["--factorize", "--bucket-deadline", "3"],
-             env_extra={
-                 "REPRO_USE_PALLAS": "1",
-                 "REPRO_FAULT_INJECT": "serve.slow_bucket@3:0.3",
-             })
+    r = _serve_full_ladder(SERVE_ARGV + ["--bucket-deadline", "3"],
+                           env_extra={
+                               "REPRO_USE_PALLAS": "1",
+                               "REPRO_FAULT_INJECT": "serve.slow_bucket@3:0.3",
+                           })
     h = _serve_health(r)
     assert h["stragglers"] and h["stragglers"][0]["step"] == 3
     assert h["demotions"] and "deadline" in h["demotions"][0]["reason"]

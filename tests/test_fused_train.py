@@ -1,8 +1,8 @@
 """Fused single-pass training kernel vs the ref.py oracle composition.
 
 The fused kernel (kernels/fused_train.py) must be bit-identical to the
-unfused three-dispatch path (clause_fire -> feedback_plan -> ta_delta) and
-to the pure-jnp oracle, in every calling mode: unchunked, batch-chunked
+pure-jnp oracle composition (clause_fire_ref -> feedback_plan ->
+ta_delta_ref), in every calling mode: unchunked, batch-chunked
 (even and ragged tails), and offset (b_offset/c_offset != 0 — the sharded
 caller's view of a clause/batch shard).
 """
@@ -45,9 +45,7 @@ def test_fused_step_matches_unfused_and_oracle(B, F, K, cpc):
     cfg, ta, x, y = _problem(B=B, F=F, K=K, cpc=cpc, seed=B)
     seed = jnp.uint32(77)
     ta_o, d_o = _steps(cfg, ta, x, y, seed, use_kernel=False)
-    ta_u, d_u = _steps(cfg, ta, x, y, seed, fuse=False, **KW)
-    ta_f, d_f = _steps(cfg, ta, x, y, seed, fuse=True, **KW)
-    np.testing.assert_array_equal(d_o, d_u)
+    ta_f, d_f = _steps(cfg, ta, x, y, seed, **KW)
     np.testing.assert_array_equal(d_o, d_f)
     np.testing.assert_array_equal(ta_o, ta_f)
     assert np.abs(d_o).sum() > 0   # the step actually trained something
@@ -62,7 +60,7 @@ def test_fused_step_blockings(blocks):
     cfg, ta, x, y = _problem(B=21, F=19, K=3, cpc=11, seed=5)
     seed = jnp.uint32(9)
     _, d_o = _steps(cfg, ta, x, y, seed, use_kernel=False)
-    _, d_f = _steps(cfg, ta, x, y, seed, fuse=True, blocks=blocks, **KW)
+    _, d_f = _steps(cfg, ta, x, y, seed, blocks=blocks, **KW)
     np.testing.assert_array_equal(d_o, d_f)
 
 
@@ -78,8 +76,7 @@ def test_chunked_matches_unchunked_all_engines(B, chunk):
     cfg, ta, x, y = _problem(B=B, seed=B + chunk)
     seed = jnp.uint32(31)
     _, d_ref = _steps(cfg, ta, x, y, seed, use_kernel=False)
-    for kw in (dict(use_kernel=False), dict(fuse=False, **KW),
-               dict(fuse=True, **KW)):
+    for kw in (dict(use_kernel=False), KW):
         _, d_c = _steps(cfg, ta, x, y, seed, batch_chunk=chunk, **kw)
         np.testing.assert_array_equal(d_ref, d_c)
 
@@ -101,21 +98,36 @@ def _polarity(kind, C, n_pad, rng):
 # different rows).
 DELTA_CASES = {
     "offsets": dict(B=11, F=23, K=3, cpc=9, pol="alternating", n_pad=0,
-                    b_off=37, c_off=10, n_loc=11, c_total=False, blocks={}),
+                    b_off=37, c_off=10, n_loc=11, c_total=False, blocks={},
+                    p_act=1.0, p_inact=0.25),
     "random_pol_padded_c128": dict(
         B=13, F=20, K=3, cpc=100, pol="random", n_pad=20, b_off=5, c_off=0,
-        n_loc=300, c_total=False, blocks=dict(block_b=8, block_c=128)),
+        n_loc=300, c_total=False, blocks=dict(block_b=8, block_c=128),
+        p_act=1.0, p_inact=0.25),
     "all_plus_c256": dict(
         B=13, F=20, K=3, cpc=100, pol="all_plus", n_pad=0, b_off=0, c_off=0,
-        n_loc=300, c_total=False, blocks=dict(block_c=256)),
+        n_loc=300, c_total=False, blocks=dict(block_c=256),
+        p_act=1.0, p_inact=0.25),
     "odd_class_boundary_c256": dict(
         B=13, F=20, K=3, cpc=101, pol="alternating", n_pad=3, b_off=0,
-        c_off=0, n_loc=303, c_total=False, blocks=dict(block_b=8)),
+        c_off=0, n_loc=303, c_total=False, blocks=dict(block_b=8),
+        p_act=1.0, p_inact=0.25),
     "shard_odd_offset_global_ids": dict(
         B=9, F=20, K=3, cpc=100, pol="alternating", n_pad=0, b_off=3,
         c_off=37, n_loc=150, c_total=True,
-        blocks=dict(block_b=8, block_c=128)),
+        blocks=dict(block_b=8, block_c=128), p_act=1.0, p_inact=0.25),
 }
+# (C, L, B) sweeps of the delta alone, L = 2F: the kernel updates one
+# class's C clauses of a binary bank (whichever class a sample targets,
+# the other is its negative, so both feedback types land), at a boosted
+# and an unboosted (p_act, p_inact) pair each
+DELTA_CASES.update({
+    f"bank_c{C}_b{B}_pact{p_act}": dict(
+        B=B, F=F, K=2, cpc=C, pol="alternating", n_pad=0, b_off=0,
+        c_off=0, n_loc=C, c_total=False, blocks={}, p_act=p_act,
+        p_inact=p_inact)
+    for C, B, F in ((5, 2, 5), (64, 7, 100), (130, 4, 257))
+    for p_act, p_inact in ((1.0, 0.1), (0.9, 0.25))})
 
 
 @pytest.mark.parametrize("case", list(DELTA_CASES), ids=list(DELTA_CASES))
@@ -157,11 +169,11 @@ def test_fused_delta_offsets_match_composed_oracle(case):
     ftype_loc = ops.feedback_select(y, kn, p_t, p_n, cls[sl], pol[sl], seed,
                                     b_offset=b_off, c_offset=c_off)
     d_ref = ref.ta_delta_ref(ta[sl], lits, fire_loc, ftype_loc, seed,
-                             p_act=1.0, p_inact=0.25, b_offset=b_off,
-                             c_offset=c_off, c_total=c_total)
+                             p_act=p["p_act"], p_inact=p["p_inact"],
+                             b_offset=b_off, c_offset=c_off, c_total=c_total)
     d_k = fused_train.fused_tm_train_delta(
         ta[sl], lits, lw, iw[sl], y, kn, p_t, p_n, cls[sl], pol[sl], seed,
-        p_act=1.0, p_inact=0.25, b_offset=b_off, c_offset=c_off,
+        p_act=p["p_act"], p_inact=p["p_inact"], b_offset=b_off, c_offset=c_off,
         c_total=c_total, interpret=True, **p["blocks"])
     np.testing.assert_array_equal(np.asarray(d_ref), np.asarray(d_k))
     ft = np.asarray(ftype_loc)
@@ -189,7 +201,7 @@ def test_fold_engagement_on_a_hand_built_plan():
 
 def test_fused_clause_shards_reassemble_full_delta():
     """Two clause shards evaluated with c_offset stitch together into the
-    full-bank unfused delta (the clause-sharded trainer's invariant)."""
+    full-bank oracle delta (the clause-sharded trainer's invariant)."""
     cfg, ta, x, y = _problem(B=9, F=15, K=2, cpc=12, seed=8)
     T = cfg.threshold
     seed = jnp.uint32(13)
@@ -280,7 +292,7 @@ def test_autotune_train_roundtrip(tmp_path, monkeypatch):
     cfg, ta, x, y = _problem()
     seed = jnp.uint32(6)
     _, d_o = _steps(cfg, ta, x, y, seed, use_kernel=False)
-    _, d_f = _steps(cfg, ta, x, y, seed, fuse=True, blocks=blocks, **KW)
+    _, d_f = _steps(cfg, ta, x, y, seed, blocks=blocks, **KW)
     np.testing.assert_array_equal(d_o, d_f)
 
 

@@ -17,48 +17,6 @@ def _sparse_includes(C, W, density=0.05):
     return packetizer.pack_bits_np(m.astype(np.uint8))
 
 
-@pytest.mark.parametrize("B,C,W", [(1, 1, 1), (7, 13, 3), (64, 128, 8), (33, 257, 5)])
-def test_clause_fire_sweep(B, C, W):
-    lit = jnp.asarray(RNG.integers(0, 2**32, (B, W), dtype=np.uint32))
-    inc = jnp.asarray(_sparse_includes(C, W))
-    r = ref.clause_fire_ref(lit, inc)
-    k = ops.clause_fire(lit, inc, **KW)
-    np.testing.assert_array_equal(np.asarray(r), np.asarray(k))
-    assert int(np.asarray(k).sum()) > 0  # sparse includes -> some clauses fire
-
-
-@pytest.mark.parametrize("blocks", [dict(), dict(block_b=8, block_c=128, block_w=2)])
-def test_clause_fire_blockings(blocks):
-    lit = jnp.asarray(RNG.integers(0, 2**32, (17, 5), dtype=np.uint32))
-    inc = jnp.asarray(_sparse_includes(39, 5))
-    r = ref.clause_fire_ref(lit, inc)
-    k = ops.clause_fire(lit, inc, **KW, **blocks)
-    np.testing.assert_array_equal(np.asarray(r), np.asarray(k))
-
-
-@pytest.mark.parametrize("B,C,K", [(3, 7, 2), (65, 300, 10), (128, 512, 32)])
-def test_class_sum_sweep(B, C, K):
-    fired = jnp.asarray(RNG.integers(0, 2, (B, C), dtype=np.int8))
-    votes = jnp.asarray(RNG.integers(-9, 10, (C, K), dtype=np.int32))
-    np.testing.assert_array_equal(
-        np.asarray(ref.class_sum_ref(fired, votes)),
-        np.asarray(ops.class_sums(fired, votes, **KW)),
-    )
-
-
-@pytest.mark.parametrize("C,L,B", [(5, 9, 2), (64, 200, 7), (130, 513, 4)])
-@pytest.mark.parametrize("p_act,p_inact", [(1.0, 0.1), (0.9, 0.25)])
-def test_ta_delta_sweep(C, L, B, p_act, p_inact):
-    ta = jnp.asarray(RNG.integers(-128, 128, (C, L), dtype=np.int8))
-    lits = jnp.asarray(RNG.integers(0, 2, (B, L), dtype=np.uint8))
-    fire = jnp.asarray(RNG.integers(0, 2, (B, C), dtype=np.uint8))
-    ftype = jnp.asarray(RNG.integers(0, 3, (B, C), dtype=np.uint8))
-    seed = jnp.uint32(1234)
-    r = ref.ta_delta_ref(ta, lits, fire, ftype, seed, p_act=p_act, p_inact=p_inact)
-    k = ops.ta_delta(ta, lits, fire, ftype, seed, p_act=p_act, p_inact=p_inact, **KW)
-    np.testing.assert_array_equal(np.asarray(r), np.asarray(k))
-
-
 @pytest.mark.parametrize("B,O,W,pad", [(4, 6, 2, 0), (33, 65, 4, 13), (128, 256, 8, 31)])
 def test_xnor_popcount_sweep(B, O, W, pad):
     n_bits = W * 32 - pad
@@ -104,6 +62,10 @@ def _fused_expect(lit, inc, votes, nonempty):
         (33, 257, 5, 10),    # C not a multiple of 128
         (64, 300, 8, 1),     # single class with a wide bank
         (130, 128, 2, 4),    # B not a multiple of block_b
+        (64, 128, 8, 4),     # exactly one default clause block
+        (3, 7, 1, 2),        # the adder bank on a tiny bank
+        (65, 300, 4, 10),    # ... on a ragged bank
+        (128, 512, 2, 32),   # ... with many classes
     ],
 )
 @pytest.mark.parametrize("masked", [True, False])
@@ -113,7 +75,7 @@ def test_fused_infer_sweep(B, C, W, K, masked):
     votes = jnp.asarray(RNG.integers(-9, 10, (C, K), dtype=np.int32))
     ne = jnp.asarray(RNG.integers(0, 2, (C,), dtype=np.uint8)) if masked else None
     expect = _fused_expect(lit, inc, votes, ne)
-    got = ops.tm_forward_packed(lit, inc, votes, ne, fuse=True, **KW)
+    got = ops.tm_forward_packed(lit, inc, votes, ne, **KW)
     np.testing.assert_array_equal(np.asarray(expect), np.asarray(got))
 
 
@@ -129,7 +91,7 @@ def test_fused_infer_blockings(blocks):
     votes = jnp.asarray(RNG.integers(-3, 4, (39, 3), dtype=np.int32))
     ne = jnp.asarray(RNG.integers(0, 2, (39,), dtype=np.uint8))
     expect = _fused_expect(lit, inc, votes, ne)
-    got = ops.tm_forward_packed(lit, inc, votes, ne, fuse=True, **KW, **blocks)
+    got = ops.tm_forward_packed(lit, inc, votes, ne, **KW, **blocks)
     np.testing.assert_array_equal(np.asarray(expect), np.asarray(got))
 
 
@@ -141,25 +103,14 @@ def test_fused_infer_all_empty_bank():
     inc = jnp.zeros((C, W), jnp.uint32)
     votes = jnp.asarray(RNG.integers(-5, 6, (C, K), dtype=np.int32))
     ne = jnp.zeros((C,), jnp.uint8)
-    got = ops.tm_forward_packed(lit, inc, votes, ne, fuse=True, **KW)
+    got = ops.tm_forward_packed(lit, inc, votes, ne, **KW)
     np.testing.assert_array_equal(np.asarray(got), 0)
     # unmasked (training semantics): vacuous fire = 1 -> column sums of votes
-    got_unmasked = ops.tm_forward_packed(lit, inc, votes, None, fuse=True, **KW)
+    got_unmasked = ops.tm_forward_packed(lit, inc, votes, None, **KW)
     np.testing.assert_array_equal(
         np.asarray(got_unmasked),
         np.broadcast_to(np.asarray(votes).sum(0), (B, K)),
     )
-
-
-def test_fused_matches_unfused_pipeline():
-    """fuse=True and fuse=False kernel paths agree bit-for-bit."""
-    lit = jnp.asarray(RNG.integers(0, 2**32, (21, 4), dtype=np.uint32))
-    inc = jnp.asarray(_sparse_includes(70, 4))
-    votes = jnp.asarray(RNG.integers(-2, 3, (70, 5), dtype=np.int32))
-    ne = jnp.asarray(RNG.integers(0, 2, (70,), dtype=np.uint8))
-    fused = ops.tm_forward_packed(lit, inc, votes, ne, fuse=True, **KW)
-    unfused = ops.tm_forward_packed(lit, inc, votes, ne, fuse=False, **KW)
-    np.testing.assert_array_equal(np.asarray(fused), np.asarray(unfused))
 
 
 def test_fused_infer_randomized_property():
@@ -177,7 +128,7 @@ def test_fused_infer_randomized_property():
         votes = jnp.asarray(prng.integers(-9, 10, (C, K), dtype=np.int32))
         ne = jnp.asarray(prng.integers(0, 2, (C,), dtype=np.uint8))
         expect = _fused_expect(lit, inc, votes, ne)
-        got = ops.tm_forward_packed(lit, inc, votes, ne, fuse=True, **KW)
+        got = ops.tm_forward_packed(lit, inc, votes, ne, **KW)
         np.testing.assert_array_equal(np.asarray(expect), np.asarray(got))
 
 
@@ -201,7 +152,7 @@ def test_autotuner_cache_roundtrip(tmp_path, monkeypatch):
     inc = jnp.asarray(_sparse_includes(39, 5))
     votes = jnp.asarray(RNG.integers(-3, 4, (39, 3), dtype=np.int32))
     expect = _fused_expect(lit, inc, votes, None)
-    got = ops.tm_forward_packed(lit, inc, votes, None, fuse=True, **KW, **blocks)
+    got = ops.tm_forward_packed(lit, inc, votes, None, **KW, **blocks)
     np.testing.assert_array_equal(np.asarray(expect), np.asarray(got))
 
 

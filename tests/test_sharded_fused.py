@@ -59,7 +59,6 @@ ta_ref, _ = ops.tm_train_step_kernel(cfg, state.ta_state, X, y, seed,
 for shape, axes in (((4,), ("model",)), ((2, 2), ("data", "model"))):
     mesh = jax.make_mesh(shape, axes)
     for kw in (dict(use_kernel=True, interpret=True),       # fused Pallas
-               dict(use_kernel=True, interpret=True, fuse=False),
                dict(use_kernel=False,)):                    # oracle engine
         step = sharding.sharded_train_step_fn(cfg, mesh, engine="kernel", **kw)
         ta_sh = np.asarray(step(state.ta_state, X, y, seed))

@@ -128,7 +128,7 @@ def test_empty_clause_only_model():
                                                       dtype=np.uint8))
     sums = compiler.run_compiled(
         comp, packetizer.pack_literals(x),
-        engine=compiler.EngineSpec(use_kernel=True), interpret=True)
+        engine="sparse", interpret=True)
     np.testing.assert_array_equal(np.asarray(sums), 0)
 
 

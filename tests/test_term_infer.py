@@ -238,9 +238,9 @@ def test_ops_dispatch_kernel_equals_oracle():
 
 
 def test_run_compiled_heuristic_default():
-    """factorize=None serves the factorized schedule exactly when the
-    artifact's partial_term_sharing clears the threshold (both routes stay
-    bit-identical, so the check is on the memoized schedule tables)."""
+    """The kernel ladder's head serves the factorized schedule exactly when
+    the artifact's partial_term_sharing clears the threshold (both routes
+    stay bit-identical, so the check is on the memoized schedule tables)."""
     # high-sharing artifact: every clause carries the same two-word core
     cfg = tm.TMConfig(n_features=64, n_classes=2, clauses_per_class=8)
     C, L = 16, 128
@@ -256,7 +256,7 @@ def test_run_compiled_heuristic_default():
                                                       dtype=np.uint8))
     xp = packetizer.pack_literals(x)
     compiler.run_compiled(comp, xp,
-                          engine=compiler.EngineSpec(use_kernel=True),
+                          engine=ops.engine_levels(comp, use_kernel=True)[0],
                           interpret=True)
     assert comp._fschedules, "heuristic should have built the factorized " \
         "schedule"
@@ -269,19 +269,18 @@ def test_run_compiled_heuristic_default():
                                                        dtype=np.uint8))
     xp2 = packetizer.pack_literals(x2)
     compiler.run_compiled(comp2, xp2,
-                          engine=compiler.EngineSpec(use_kernel=True),
+                          engine=ops.engine_levels(comp2, use_kernel=True)[0],
                           interpret=True)
     assert not comp2._fschedules
     assert comp2._schedules
-    # a factorized-only tiling key pins the factorized kernel even below
-    # the sharing threshold (a tuned config is never silently dropped)...
-    compiler.run_compiled(comp2, xp2,
-                          engine=compiler.EngineSpec(use_kernel=True),
-                          interpret=True, term_w=2)
+    # the factorized engine named below the sharing threshold takes its
+    # own tiling keys...
+    compiler.run_compiled(comp2, xp2, engine="factorized", interpret=True,
+                          term_w=2)
     assert comp2._fschedules
-    # ... and an explicitly non-factorized engine with such a key fails
-    # loudly
-    with pytest.raises(TypeError, match="factorized-only"):
+    # ... and another engine with such a key fails loudly (a tuned config
+    # is never silently dropped)
+    with pytest.raises(TypeError, match="takes no tiling"):
         compiler.run_compiled(comp2, xp2, engine="sparse", interpret=True,
                               block_t=16)
 

@@ -6,7 +6,7 @@ import numpy as np
 
 from repro.core import feedback, tm, train
 from repro.data import make_noisy_xor
-from repro.kernels import ops
+from repro.kernels import ops, ref
 
 
 def test_type2_only_increments_excluded_zero_literals():
@@ -16,7 +16,8 @@ def test_type2_only_increments_excluded_zero_literals():
     fire = jnp.ones((1, 4), jnp.uint8)
     ftype = jnp.full((1, 4), 2, jnp.uint8)          # all Type II
     d = np.asarray(
-        ops.ta_delta(ta, lits, fire, ftype, jnp.uint32(0), p_act=1.0, p_inact=0.1)
+        ref.ta_delta_ref(ta, lits, fire, ftype, jnp.uint32(0), p_act=1.0,
+                         p_inact=0.1)
     )
     assert (d >= 0).all()
     # literal=1 positions and included positions unchanged
@@ -33,7 +34,8 @@ def test_type1_rewards_matching_literals():
     fire = jnp.ones((1, 4), jnp.uint8)
     ftype = jnp.full((1, 4), 1, jnp.uint8)
     d = np.asarray(
-        ops.ta_delta(ta, lits, fire, ftype, jnp.uint32(3), p_act=1.0, p_inact=0.0)
+        ref.ta_delta_ref(ta, lits, fire, ftype, jnp.uint32(3), p_act=1.0,
+                         p_inact=0.0)
     )
     np.testing.assert_array_equal(d, np.tile([1, 1, 0, 0], (4, 1)))
 

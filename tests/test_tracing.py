@@ -269,12 +269,11 @@ def test_serve_tm_straggler_deadline_fires_through_the_builder_hook(
     monkeypatch.setattr(ops, "_DEFAULT_USE_KERNEL", True)
     args = build_parser().parse_args(
         ["--arch", "tm-tiny", "--requests", "640", "--bucket", "128",
-         "--epochs", "1", "--n-train", "256", "--factorize",
-         "--bucket-deadline", "3"])
+         "--epochs", "1", "--n-train", "256", "--bucket-deadline", "3"])
     with faults.injected("serve.slow_bucket@3:0.3"):
         out = serve_tm(args)
     h = out["serve"]
     assert any(s["step"] == 3 for s in h["stragglers"]), h["stragglers"]
     assert h["demotions"] and "deadline" in h["demotions"][0]["reason"]
-    assert h["demotions"][0]["frm"] == "factorized"
+    assert h["demotions"][0]["frm"] == h["ladder"][0]
     assert out["gateway"]["unaccounted"] == 0
